@@ -36,6 +36,13 @@ class Observer(Service):
                  genesis: Optional[Dict[Address20, sp.AccountState]] = None):
         if replay_engine not in ("python", "jax", "off"):
             raise ValueError(f"unknown replay engine {replay_engine!r}")
+        if replay_engine == "jax":
+            # the no-silent-CPU rule (ops/device.py): the device replay
+            # refuses an undeclared CPU fallback at construction, not at
+            # the first collation
+            from gethsharding_tpu.ops import device
+
+            device.device_record()
         super().__init__()
         self.client = client
         self.shard = shard

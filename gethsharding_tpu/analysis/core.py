@@ -122,13 +122,13 @@ class Corpus:
 
     ``root`` is the repo root; ``files`` covers every ``*.py`` under the
     scanned subtrees. Non-AST inputs the rules need (README.md, bench.py,
-    scripts/) are reachable through ``root``.
+    chip_smoke.py, scripts/) are reachable through ``root``.
     """
 
     # subtrees scanned for AST rules, relative to root
     DEFAULT_SUBTREES = ("gethsharding_tpu",)
     # extra single files / trees the flag rules also read for env knobs
-    DEFAULT_EXTRA = ("bench.py", "scripts")
+    DEFAULT_EXTRA = ("bench.py", "chip_smoke.py", "scripts")
 
     def __init__(self, root: Path, files: Sequence[SourceFile],
                  extra_files: Sequence[SourceFile] = ()):

@@ -8,8 +8,8 @@ mechanically.
 
 Code side:
 - env vars: every string literal (and f-string skeleton) shaped
-  `GETHSHARDING_[A-Z0-9_]*` anywhere in the package, bench.py and
-  scripts/ — call args, dict keys, comparisons — EXCEPT docstrings.
+  `GETHSHARDING_[A-Z0-9_]*` anywhere in the package, bench.py,
+  chip_smoke.py and scripts/ — call args, dict keys, comparisons — EXCEPT docstrings.
   Dynamic names (`f"GETHSHARDING_CLASS_{op}"`) become skeletons with
   `*` at the formatted holes.
 - CLI flags: `add_argument("--…")` literals. Flags of the package CLIs

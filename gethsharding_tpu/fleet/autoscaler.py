@@ -134,8 +134,16 @@ class ChainServerSpawner(ReplicaSpawner):
                                 stderr=subprocess.DEVNULL)
         line = self._read_banner(proc)
         if line is None:
+            rc = proc.poll()
             proc.kill()
             proc.wait()
+            if rc is not None:
+                # e.g. a second `jax` replica on a host whose chips the
+                # first process holds: the backend refuses the silent
+                # CPU fallback and the child exits (ops/device.py)
+                raise RuntimeError(
+                    f"spawned chain_server (--sigbackend {self.sigbackend})"
+                    f" exited {rc} before its address banner")
             raise RuntimeError("spawned chain_server printed no "
                                "address banner before the deadline")
         addr = json.loads(line)

@@ -587,13 +587,12 @@ def run_sharding_node(args) -> int:
         influx.start()
     profiling = False
     if args.profile:
-        try:
-            import jax
+        # asked for a profile: a profiler that cannot start is a failure,
+        # not a run that quietly records nothing
+        import jax
 
-            jax.profiler.start_trace(args.profile)
-            profiling = True
-        except Exception as exc:
-            log.warning("JAX profiler unavailable: %s", exc)
+        jax.profiler.start_trace(args.profile)
+        profiling = True
     fleettrace_export = args.fleettrace_export
     if fleettrace_export is None:
         fleettrace_export = os.environ.get(

@@ -44,7 +44,6 @@ program-logic bugs and Pallas-mechanics bugs isolate cleanly.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Any, NamedTuple
 
 import numpy as np
@@ -67,21 +66,6 @@ def block_lanes() -> int:
     it so a pipelined block never pads down to a partial lane
     block)."""
     return BLOCK_LANES
-
-# In-kernel schoolbook-column implementation (GETHSHARDING_TPU_MEGA_CONV):
-# - "shift" (default): 25 shifted-concatenate MACs per conv — each step
-#   materializes a zero-padded copy of the full column block (the
-#   original form, measured at 45.5k sigs/sec composed into the r4
-#   champion).
-# - "slices": accumulate step l into columns [l, l+25) of a persistent
-#   accumulator via static-offset dynamic_update_slice — the in-kernel
-#   analog of ops/limb.py CONV=slices (the XLA-land sweep winner at
-#   31.2k): minimal working set, no concat copies. Value-identical;
-#   differential tests cover both (tests/test_pallas_finalexp.py).
-MEGA_CONV = os.environ.get("GETHSHARDING_TPU_MEGA_CONV", "shift")
-if MEGA_CONV not in ("shift", "slices"):
-    raise ValueError(f"GETHSHARDING_TPU_MEGA_CONV must be 'shift' or "
-                     f"'slices', got {MEGA_CONV!r}")
 
 # == self-contained wide-relaxed limb constants ============================
 # The kernel always computes in the 25-limb wide form with relaxed
@@ -229,17 +213,17 @@ def _normalize(z, C: Consts):
     return _round(_round(_round(acc))).reshape(lead + (KNL, z.shape[-1]))
 
 
-def _conv(u, v, impl: "str | None" = None):
+def _conv(u, v):
     """Schoolbook columns: (..., 25, B) x (..., 25, B) -> (..., 49, B),
     leading dims broadcast — the stacked-plane form of pallas_conv's
-    shift-MAC loop (25 full-tile MACs for ALL planes at once).
+    shift-MAC loop (25 full-tile MACs for ALL planes at once): each
+    step lands in its column window through a zero-padded concatenate,
+    the window update Mosaic lowers (`dynamic_slice` /
+    `dynamic_update_slice`, even at static offsets, it does not).
 
     Leading dims are FLATTENED around the loop (free reshapes — minor
     dims untouched): the fp12 paths otherwise build rank-7 arrays,
-    which interpret mode accepts but real Mosaic may not.
-
-    `impl` overrides GETHSHARDING_TPU_MEGA_CONV per call (tests)."""
-    impl = impl or MEGA_CONV
+    which interpret mode accepts but real Mosaic may not."""
     lead = jnp.broadcast_shapes(u.shape[:-2], v.shape[:-2])
     n = 1
     for d in lead:
@@ -248,18 +232,8 @@ def _conv(u, v, impl: "str | None" = None):
         (n,) + u.shape[-2:])
     vf = jnp.broadcast_to(v, lead + v.shape[-2:]).reshape(
         (n,) + v.shape[-2:])
-    # the LANE dim broadcasts too (e.g. a B=1 constant against a batch)
-    (b,) = jnp.broadcast_shapes(u.shape[-1:], v.shape[-1:])
-    if impl == "slices":
-        # step l lands in columns [l, l+25): read-modify-write that
-        # window with STATIC offsets (lowers to vector moves, no
-        # zero-padded concat copy per step)
-        acc = jnp.zeros((n, KNCOLS, b), jnp.int32)
-        for l in range(KNL):
-            term = uf[:, l:l + 1, :] * vf              # (n, 25, B)
-            window = lax.dynamic_slice(acc, (0, l, 0), (n, KNL, b))
-            acc = lax.dynamic_update_slice(acc, window + term, (0, l, 0))
-        return acc.reshape(lead + (KNCOLS, b))
+    # the LANE dim broadcasts too (e.g. a B=1 constant against a batch):
+    # the elementwise product below carries it
     acc = None
     for l in range(KNL):
         term = uf[:, l:l + 1, :] * vf
@@ -949,6 +923,15 @@ def miller_f(sig, hx, hy, pk, *, interpret: bool = False):
 # With FINALEXP/MILLER/AGG all mega, the audit dispatch is 4 launches.
 
 AGG_LANES = 64  # smaller lane block: level-0 conv temporaries dominate VMEM
+# The in-kernel tree is fully unrolled, so its VMEM stack, its Mosaic
+# lowering and its compile time all grow with the committee width it
+# reduces: at the audit's 144 slots (padded to 256) the G1 kernel asked
+# for 29.7 MB of scoped VMEM against Mosaic's 16 MB and took ~10 minutes
+# to say so (v5e, PR 21). The kernel therefore reduces chunks of
+# AGG_CHUNK slots (a second grid axis; wider committees pad up to a
+# chunk multiple, and 8 divides the audit's 144) and the chunk sums
+# fold through the XLA complete-addition tree.
+AGG_CHUNK = 8
 
 
 def _fp_mul_rows(x, y, C: Consts):
@@ -1007,14 +990,14 @@ def _agg_kernel(xs_ref, ys_ref, mask_ref, b3_ref,
     C = Consts(fold_t=c_fold[:], lift=c_lift[:], mulpad=c_mulpad[:],
                fp2pad=c_fp2pad[:], negpad=c_negpad[:], gamma=c_gamma[:],
                linepad=c_linepad[:], one12=c_one12[:])
-    # data refs carry a leading size-1 lane-group axis (the grid axis):
-    # Mosaic requires a block's LANE dim to be 128-divisible or equal
-    # the array's, so lanes are pre-split host-side into (groups, 64)
-    # and the grid walks groups (r4 TPU probe: block 64 over a 128-lane
-    # array is rejected)
-    xs = xs_ref[0]                     # (Cp, [2,] 25, B)
-    ys = ys_ref[0]
-    m = mask_ref[0]                    # (Cp, 1, B) | (Cp, 1, 1, B)
+    # data refs carry two leading size-1 axes (the grid axes): the lane
+    # group and the committee chunk. Mosaic requires a block's LANE dim
+    # to be 128-divisible or equal the array's, so lanes are pre-split
+    # host-side into (groups, 64) and the grid walks groups (block 64
+    # over a 128-lane array is rejected)
+    xs = xs_ref[0, 0]                  # (Cp, [2,] 25, B)
+    ys = ys_ref[0, 0]
+    m = mask_ref[0, 0]                 # (Cp, 1, B) | (Cp, 1, 1, B)
     one_limb = (C.one12[0] if fp2 else C.one12[0, 0])  # (2,25,1)|(25,1)
     one = jnp.broadcast_to(one_limb, xs.shape[1:]).astype(jnp.int32)
     px = jnp.where(m != 0, xs, 0)
@@ -1022,9 +1005,9 @@ def _agg_kernel(xs_ref, ys_ref, mask_ref, b3_ref,
     pz = jnp.where(m != 0, one, jnp.zeros_like(one))
     b3 = b3_ref[:] if fp2 else g1_b3
     X, Y, Z = _agg_tree(px, py, pz, C, fp2=fp2, b3=b3)
-    ox_ref[0] = X
-    oy_ref[0] = Y
-    oz_ref[0] = Z
+    ox_ref[0, 0] = X
+    oy_ref[0, 0] = Y
+    oz_ref[0, 0] = Z
 
 
 @functools.lru_cache(maxsize=16)
@@ -1042,21 +1025,22 @@ def _agg_compiled(cp: int, fp2: bool, interpret: bool):
 
     @jax.jit
     def run(xs, ys, mask):
-        # data arrays arrive as (groups, ..., AGG_LANES): the lane axis
-        # is pre-split so each block's lane dim EQUALS the array's (the
-        # Mosaic block-shape rule), and the grid walks the group axis
-        g = xs.shape[0]
-        grid = (g,)
-        from jax.experimental.pallas import tpu as pltpu
+        # data arrays arrive as (groups, chunks, ..., AGG_LANES): the
+        # lane axis is pre-split so each block's lane dim EQUALS the
+        # array's (the Mosaic block-shape rule), and the grid walks the
+        # lane groups and the committee chunks
+        g, nc = xs.shape[:2]
+        grid = (g, nc)
 
         def whole(shape):
             rank = len(shape)
-            return pl.BlockSpec(shape, lambda i, _r=rank: (0,) * _r)
+            return pl.BlockSpec(shape, lambda i, j, _r=rank: (0,) * _r)
 
         def data(shape):
-            rank = len(shape) + 2
-            return pl.BlockSpec((1,) + shape + (AGG_LANES,),
-                                lambda i, _r=rank: (i,) + (0,) * (_r - 1))
+            rank = len(shape) + 3
+            return pl.BlockSpec(
+                (1, 1) + shape + (AGG_LANES,),
+                lambda i, j, _r=rank: (i, j) + (0,) * (_r - 2))
 
         out_specs = [data(out_shape)] * 3
         return pl.pallas_call(
@@ -1066,8 +1050,8 @@ def _agg_compiled(cp: int, fp2: bool, interpret: bool):
                       data(mask_shape), whole(b3g2.shape)]
             + [whole(np.asarray(c).shape) for c in _NP_CONSTS],
             out_specs=out_specs,
-            out_shape=[jax.ShapeDtypeStruct((g,) + out_shape + (AGG_LANES,),
-                                            jnp.int32)] * 3,
+            out_shape=[jax.ShapeDtypeStruct(
+                (g, nc) + out_shape + (AGG_LANES,), jnp.int32)] * 3,
             interpret=interpret,
         )(xs, ys, mask, jnp.asarray(b3g2),
           *(jnp.asarray(c) for c in _NP_CONSTS))
@@ -1085,22 +1069,28 @@ def aggregate_proj(xs, ys, mask, *, fp2: bool, interpret: bool = False):
     point_rank = 3 if fp2 else 2
     lead = xs.shape[:-point_rank]
     cdim = xs.shape[len(lead)]
-    cp = 1 << max(1, (cdim - 1).bit_length())   # pad committee to pow2
+    if cdim <= AGG_CHUNK:
+        cp = 1 << max(1, (cdim - 1).bit_length())   # one pow2 chunk
+        nc = 1
+    else:
+        cp = AGG_CHUNK
+        nc = -(-cdim // cp)
     n = 1
     for dim in lead:
         n *= dim
 
-    def prep(v, extra_dims):
+    def prep(v, widen: bool):
         v = v.reshape((n,) + v.shape[len(lead):])
-        if v.shape[-1] < KNL and extra_dims >= 0:
+        if widen and v.shape[-1] < KNL:
             v = jnp.concatenate(
                 [v, jnp.zeros(v.shape[:-1] + (KNL - v.shape[-1],),
                               v.dtype)], axis=-1)
-        pad_c = cp - cdim
+        pad_c = nc * cp - cdim
         if pad_c:
             v = jnp.concatenate(
                 [v, jnp.zeros((n, pad_c) + v.shape[2:], v.dtype)], axis=1)
-        v = jnp.moveaxis(v, 0, -1)              # (Cp, ..., n)
+        v = v.reshape((n, nc, cp) + v.shape[2:])
+        v = jnp.moveaxis(v, 0, -1)              # (nc, Cp, ..., n)
         pad = (-n) % AGG_LANES
         if pad:
             v = jnp.concatenate(
@@ -1110,19 +1100,23 @@ def aggregate_proj(xs, ys, mask, *, fp2: bool, interpret: bool = False):
         # lane dim (Mosaic's block-shape rule; see _agg_compiled)
         groups = v.shape[-1] // AGG_LANES
         v = v.reshape(v.shape[:-1] + (groups, AGG_LANES))
-        return jnp.moveaxis(v, -2, 0)           # (g, Cp, ..., 64)
+        return jnp.moveaxis(v, -2, 0)           # (g, nc, Cp, ..., 64)
 
-    xs_t = prep(jnp.asarray(xs), 0)
-    ys_t = prep(jnp.asarray(ys), 0)
+    xs_t = prep(jnp.asarray(xs), True)
+    ys_t = prep(jnp.asarray(ys), True)
     m = mask[..., None, None] if fp2 else mask[..., None]
-    m_t = prep(jnp.asarray(m, jnp.int32), -1)
+    m_t = prep(jnp.asarray(m, jnp.int32), False)
     out = _agg_compiled(cp, fp2, interpret)(xs_t, ys_t, m_t)
     res = []
-    for v in out:
-        v = jnp.moveaxis(v, 0, -2)              # (out..., g, 64)
+    for v in out:                               # (g, nc, out..., 64)
+        v = jnp.moveaxis(v, 0, -2)              # (nc, out..., g, 64)
         v = v.reshape(v.shape[:-2] + (v.shape[-2] * AGG_LANES,))
         if (-n) % AGG_LANES:
             v = v[..., :n]
-        v = jnp.moveaxis(v, -1, 0).reshape(lead + v.shape[:-1])
+        v = jnp.moveaxis(v, -1, 0)              # (n, nc, out...)
+        v = v.reshape(lead + v.shape[1:])
         res.append(k.FP.normalize(v))
-    return tuple(res)
+    # fold the chunk sums (axis right after the batch dims) through the
+    # XLA complete-addition tree; one chunk squeezes straight through
+    return k._tree_reduce(tuple(res), -point_rank,
+                          k._g2_proj_add if fp2 else k._g1_proj_add)

@@ -31,10 +31,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-try:  # re-exported at top level on newer jax; experimental on 0.4.x
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as PS
 
 from gethsharding_tpu.ops import bn256_jax as bn

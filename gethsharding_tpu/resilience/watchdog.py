@@ -3,7 +3,7 @@
 The serving tier funnels every device call through ONE dispatch thread
 (`serving/pipeline.PipelinedDispatcher`). That thread is a single
 point of failure the reference never had: a wedged accelerator call
-(driver stall, tunnel drop, chaos-injected hang) blocks the thread
+(driver stall, lost device, chaos-injected hang) blocks the thread
 forever, every queued batch behind it, and every caller parked on a
 `VerdictFuture` — the notary silently stops voting.
 

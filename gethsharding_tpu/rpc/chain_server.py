@@ -6,8 +6,11 @@ processes). Hosts a SimulatedMainchain behind an RPCServer; block
 production is either timed (--blocktime) or driven remotely via the
 shard_commit / shard_fastForward dev methods.
 
-Prints one JSON line {"host": ..., "port": ...} on stdout once listening,
-so a parent process (test harness, orchestrator) can dial it.
+Prints one JSON line {"host": ..., "port": ..., "sigbackend": ...,
+"device": ...} on stdout once listening, so a parent process (test
+harness, orchestrator) can dial it and read which device answers:
+"device" is the jax backend's record (platform / device_kind / count),
+null for the scalar backend.
 """
 
 from __future__ import annotations
@@ -210,7 +213,11 @@ def main(argv=None) -> int:
         leader_host, leader_port = args.follow.rsplit(":", 1)
         follower = ChainFollower(backend, leader_host, int(leader_port))
         follower.start()
-    print(json.dumps({"host": server.address[0], "port": server.address[1]}),
+    from gethsharding_tpu.sigbackend import device_record_of
+
+    print(json.dumps({"host": server.address[0], "port": server.address[1],
+                      "sigbackend": args.sigbackend,
+                      "device": device_record_of(sig_backend)}),
           flush=True)
 
     deadline = time.monotonic() + args.runtime if args.runtime else None

@@ -582,14 +582,18 @@ class RPCServer:
     def rpc_health(self):
         """The replica-health surface a fleet router sweeps: the drain
         flag, the failover breaker's state (if the injected backend
-        composes one), and the serving tier's per-class queue depths.
-        One round trip, cheap enough for sub-second polling."""
+        composes one), the device record of the backend that answers
+        (platform / device_kind / count; None for a scalar backend),
+        and the serving tier's per-class queue depths. One round trip,
+        cheap enough for sub-second polling."""
         from gethsharding_tpu.fleet.router import breaker_of
+        from gethsharding_tpu.sigbackend import device_record_of
 
         payload = {"draining": self.draining,
                    # minus one: this health request is itself in flight
                    "inflight": max(0, self._inflight - 1),
-                   "breaker": None, "serving": None}
+                   "breaker": None, "serving": None,
+                   "device": device_record_of(self._sig_backend)}
         backend = self._sig_backend
         if backend is not None:
             breaker = breaker_of(backend)

@@ -291,6 +291,22 @@ def get_backend(name: str = "python") -> SigBackend:
     return _cache[name]
 
 
+def device_record_of(backend) -> Optional[dict]:
+    """The device record (platform / device_kind / count, as JAX reported
+    them to `JaxSigBackend`) of the accelerated backend under `backend`,
+    found by walking the wrapper chain (`.inner` hops through the
+    serving / soundness / chaos / failover faces). None when the
+    composition bottoms out in a scalar backend — which is how a client
+    tells a replica that answers from a device from one that does not."""
+    probe, hops = backend, 0
+    while probe is not None and hops < 8:
+        record = getattr(probe, "device_record", None)
+        if record is not None:
+            return dict(record)
+        probe, hops = getattr(probe, "inner", None), hops + 1
+    return None
+
+
 def __getattr__(name: str):
     # PEP 562: `from gethsharding_tpu.sigbackend import JaxSigBackend`
     # keeps working without this package eagerly importing dispatch.py

@@ -39,16 +39,6 @@ def mesh_devices_requested(explicit: Optional[int] = None) -> int:
     return max(1, int(raw)) if raw else 1
 
 
-def get_shard_map():
-    """`shard_map` across jax versions: re-exported at top level on
-    newer releases, under `jax.experimental` on 0.4.x."""
-    try:
-        from jax import shard_map  # type: ignore[attr-defined]
-    except ImportError:  # pragma: no cover - version-dependent
-        from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 def count_collectives(hlo_text: str) -> int:
     """Cross-device collective ops in a compiled HLO module — the
     transfer-ledger check behind the mesh audit's acceptance bar

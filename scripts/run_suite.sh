@@ -1,6 +1,6 @@
 #!/bin/bash
 # Maximally isolated full-suite run: one short-lived pytest process per
-# test file, each with the host-keyed persistent compile cache enabled.
+# test file, each with the persistent compile cache enabled.
 #
 # Since r3 a plain one-process `pytest tests/` is ALSO green (conftest
 # bounds XLA:CPU's executable-count pressure with jax.clear_caches()
@@ -161,10 +161,8 @@ PYEOF
 # mesh — ONE audit through scalar / single-device / mesh (bench.py
 # --mesh asserts bit-identity, exactly one cross-device collective,
 # sharded verdicts and disjoint per-device cache shards), emitting the
-# multichip_audit record into a THROWAWAY ledger that the probe
-# acceptance gate (scripts/probe_ledger_check.py) must then pass.
-# The virtual-mesh dryrun used to be driver-only; this is its suite
-# home. Compile-heavy (two audit executables, XLA:CPU): the host-keyed
+# multichip_audit record into a THROWAWAY ledger that must then hold
+# one valid record. Compile-heavy (two audit executables, XLA:CPU): the
 # persistent compile cache makes repeats fast, the timeout covers cold.
 echo "== mesh smoke (2-device virtual mesh: one audit, bit-identity)"
 mesh_tmp=$(mktemp -d)
@@ -181,9 +179,8 @@ grep -q '"collectives_per_step": 1' "$mesh_tmp/mesh.json" || {
 grep -q '"n_devices": 2' "$mesh_tmp/mesh.json" || {
     echo "mesh smoke FAILED: audit did not run on the 2-device mesh"
     fail=1; }
-GETHSHARDING_PERFWATCH_LEDGER="$mesh_tmp/ledger.jsonl" JAX_PLATFORMS=cpu \
-    python scripts/probe_ledger_check.py multichip_audit \
-    --max-age 3600 || {
+grep '"workload": "multichip_audit"' "$mesh_tmp/ledger.jsonl" \
+    | grep -q '"valid": true' || {
     echo "mesh smoke FAILED: no valid multichip_audit ledger record"
     fail=1; }
 rm -rf "$mesh_tmp"

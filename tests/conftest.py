@@ -52,7 +52,7 @@ if "GETHSHARDING_PERFWATCH_LEDGER" not in _os.environ:
         _tempfile.mkdtemp(prefix="perfwatch_ledger_"), "ledger.jsonl")
 
 # XLA:CPU deterministically segfaults once a process holds too many
-# compiled programs (~150): r3 faulthandler runs place the crash at the
+# compiled programs (~150): faulthandler runs place the crash at the
 # SAME test/program both inside the persistent-cache deserializer
 # (compilation_cache.get_executable_and_time) AND, with the cache off,
 # inside plain backend_compile_and_load — i.e. executable-COUNT pressure
@@ -60,16 +60,18 @@ if "GETHSHARDING_PERFWATCH_LEDGER" not in _os.environ:
 # runs green in a short-lived process). The fix is to keep the live
 # executable count low: `jax.clear_caches()` after every test module
 # (autouse fixture below). With pressure bounded, the persistent cache
-# is safe again and stays ENABLED — one-process `pytest tests/` runs
-# green AND takes cache hits. GETHSHARDING_CACHE_OFF=1 disables the
-# cache for debugging; `scripts/run_suite.sh` (one process per file)
-# remains an equivalent, maximally isolated entry.
+# (force_virtual_cpu_devices placed it: JAX_COMPILATION_CACHE_DIR, else
+# <checkout>/.jax_cache) is safe and stays ENABLED — one-process
+# `pytest tests/` runs green AND takes cache hits.
+# GETHSHARDING_CACHE_OFF=1 disables the cache for debugging;
+# `scripts/run_suite.sh` (one process per file) remains an equivalent,
+# maximally isolated entry.
 import gc as _gc
 
-from gethsharding_tpu.parallel.virtual import configure_compile_cache
-
 if _os.environ.get("GETHSHARDING_CACHE_OFF") == "1":
-    configure_compile_cache(enabled=False)
+    import jax as _jax
+
+    _jax.config.update("jax_enable_compilation_cache", False)
 
 # GETHSHARDING_LOCKCHECK=1: wrap threading.Lock/RLock with the runtime
 # lock-order recorder (analysis/lockcheck.py) for the whole session and

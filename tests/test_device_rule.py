@@ -1,0 +1,219 @@
+"""The device rule, the cache rule and the chip smoke's rehearsal.
+
+- no silent CPU: an accelerated backend resolves its devices once,
+  records platform / device_kind / count, and refuses a CPU that
+  ``JAX_PLATFORMS`` did not name; the chain_server banner and
+  ``shard_health`` carry the record; a requested Pallas knob never gets
+  the XLA path in its place off the CPU;
+- one compile cache: ``JAX_COMPILATION_CACHE_DIR`` where set, else the
+  fixed ``<checkout>/.jax_cache``, for every entry point;
+- ``chip_smoke.py --rehearsal`` passes on the CPU and the same command
+  without the flag fails off the chip.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+from gethsharding_tpu.ops import device
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _run(code, env_update, timeout=180):
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_PLATFORMS", "JAX_COMPILATION_CACHE_DIR",
+                        "XLA_FLAGS")}
+    env.update(env_update)
+    env["PYTHONPATH"] = REPO
+    return subprocess.run([sys.executable, "-c", code], cwd=REPO, env=env,
+                          capture_output=True, text=True, timeout=timeout)
+
+
+# == the device rule ========================================================
+
+
+def test_undeclared_cpu_is_refused_naming_jax_platforms():
+    """With JAX_PLATFORMS unset and no chip JAX quietly hands back the
+    CPU; `JaxSigBackend()` must raise, and say how to ask for the CPU."""
+    proc = _run("from gethsharding_tpu.sigbackend.dispatch import "
+                "JaxSigBackend; JaxSigBackend()", {})
+    assert proc.returncode != 0
+    assert "NoAcceleratorError" in proc.stderr
+    assert "JAX_PLATFORMS=cpu" in proc.stderr
+
+
+def test_declared_cpu_constructs_and_exposes_the_record():
+    from gethsharding_tpu.sigbackend import device_record_of, get_backend
+    from gethsharding_tpu.serving import ServingSigBackend
+
+    backend = get_backend("jax")
+    record = backend.device_record
+    assert record["platform"] == "cpu"
+    assert record["device_kind"] and record["count"] == 8  # conftest's mesh
+    assert record["compile_cache_dir"] == device.compile_cache_dir()
+    # found through the wrapper chain; absent under a scalar composition
+    serving = ServingSigBackend(backend)
+    try:
+        assert device_record_of(serving) == record
+    finally:
+        serving.close()
+    assert device_record_of(get_backend("python")) is None
+
+
+def test_cpu_declared_reads_env_and_config(monkeypatch):
+    import jax
+
+    assert device.cpu_declared()  # conftest forced the cpu platform
+    monkeypatch.delenv("JAX_PLATFORMS")
+    monkeypatch.setattr(type(jax.config), "jax_platforms",
+                        property(lambda self: "tpu,cpu"), raising=False)
+    assert device.cpu_declared()
+    monkeypatch.setattr(type(jax.config), "jax_platforms",
+                        property(lambda self: None), raising=False)
+    assert not device.cpu_declared()
+    monkeypatch.setenv("JAX_PLATFORMS", "CPU")
+    assert device.cpu_declared()
+
+
+def test_observer_device_replay_obeys_the_rule(monkeypatch):
+    from gethsharding_tpu.actors.observer import Observer
+
+    monkeypatch.setattr(device, "_resolved", None)
+    monkeypatch.setattr(device, "cpu_declared", lambda: False)
+    with pytest.raises(device.NoAcceleratorError, match="JAX_PLATFORMS"):
+        Observer(client=None, shard=None, replay_engine="jax")
+
+
+def test_health_and_banner_carry_the_record():
+    """`shard_health` and the chain_server's one-line banner name the
+    device that answers (null for the scalar backend)."""
+    from gethsharding_tpu.rpc.server import RPCServer
+    from gethsharding_tpu.sigbackend import get_backend
+    from gethsharding_tpu.smc.chain import SimulatedMainchain
+
+    server = RPCServer(SimulatedMainchain(), sig_backend=get_backend("jax"))
+    assert server.rpc_health()["device"] == get_backend("jax").device_record
+    scalar = RPCServer(SimulatedMainchain(),
+                       sig_backend=get_backend("python"))
+    assert scalar.rpc_health()["device"] is None
+
+    proc = subprocess.Popen(
+        [sys.executable, "-m", "gethsharding_tpu.rpc.chain_server",
+         "--sigbackend", "jax", "--runtime", "0.2"],
+        cwd=REPO, stdout=subprocess.PIPE, text=True,
+        env={**os.environ, "PYTHONPATH": REPO, "JAX_PLATFORMS": "cpu"})
+    try:
+        banner = json.loads(proc.stdout.readline())
+    finally:
+        assert proc.wait(timeout=60) == 0
+    assert banner["sigbackend"] == "jax"
+    assert banner["device"]["platform"] == "cpu"
+    assert banner["device"]["count"] >= 1
+    assert banner["device"]["compile_cache_dir"]
+
+
+def test_requested_pallas_kernel_never_gets_the_xla_path(monkeypatch):
+    """The selector, not the chip: off the CPU a requested Pallas kernel
+    runs or raises — a failure to resolve the backend or to compile the
+    kernel propagates instead of selecting the XLA path."""
+    import jax
+    import jax.numpy as jnp
+
+    from gethsharding_tpu.ops import limb, pallas_norm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert limb._pallas_wanted() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert limb._pallas_wanted() is False
+
+    def no_backend():
+        raise RuntimeError("backend init failed")
+
+    monkeypatch.setattr(jax, "default_backend", no_backend)
+    with pytest.raises(RuntimeError, match="backend init failed"):
+        limb._pallas_wanted()
+
+    def refused(arith, z):
+        raise NotImplementedError("Mosaic refused the kernel")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(limb, "PALLAS_NORM", True)
+    monkeypatch.setattr(pallas_norm, "normalize_pallas", refused)
+    arith = limb.ModArith(21888242871839275222246405745257275088696311157297823662689037894645226208583)
+    with pytest.raises(NotImplementedError, match="Mosaic refused"):
+        arith.normalize(jnp.zeros((2, 49), jnp.int32))
+
+
+# == the cache rule =========================================================
+
+_ENTRY_POINTS = ("gethsharding_tpu.rpc.chain_server", "gethsharding_tpu.cli",
+                 "gethsharding_tpu.node.cli", "gethsharding_tpu.fleet.frontend",
+                 "bench", "chip_smoke")
+_CACHE_PROBE = (
+    "import importlib, jax\n"
+    "from gethsharding_tpu.ops.device import configure_compile_cache\n"
+    f"for name in {_ENTRY_POINTS!r}:\n"
+    "    importlib.import_module(name)\n"
+    "print(jax.config.jax_compilation_cache_dir)\n"
+    "print(configure_compile_cache())\n"
+    "print(jax.config.jax_compilation_cache_dir)\n")
+
+
+def test_cache_dir_follows_the_environment(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, importing every entry point
+    and configuring the cache leaves JAX's directory at that value."""
+    want = str(tmp_path / "placed-from-outside")
+    proc = _run(_CACHE_PROBE, {"JAX_COMPILATION_CACHE_DIR": want,
+                               "JAX_PLATFORMS": "cpu"})
+    assert proc.returncode == 0, proc.stderr
+    assert proc.stdout.split() == [want, want, want]
+
+
+def test_cache_dir_defaults_to_the_fixed_checkout_path():
+    """Unset, every process gets <checkout>/.jax_cache: a constant of
+    the checkout, no host, pid, time or tempfile component."""
+    want = os.path.join(REPO, ".jax_cache")
+    proc = _run(_CACHE_PROBE, {"JAX_PLATFORMS": "cpu"})
+    assert proc.returncode == 0, proc.stderr
+    assert proc.stdout.split() == ["None", want, want]
+    assert device.compile_cache_dir() == os.environ.get(
+        "JAX_COMPILATION_CACHE_DIR", want)
+
+
+def test_this_process_uses_the_one_cache():
+    import jax
+
+    assert jax.config.jax_compilation_cache_dir == device.compile_cache_dir()
+
+
+# == chip_smoke.py ==========================================================
+
+
+def test_chip_smoke_rehearsal_passes_and_the_real_run_fails_off_chip():
+    """`chip_smoke.py --rehearsal` drives the served path at 2x3 on the
+    CPU (leg A: the chain_server child, the period three ways, one
+    request per other kernel family, every verdict against the scalar
+    reference) and exits 0 saying `rehearsal` and `cpu` on every line;
+    the same command without the flag exits non-zero off the chip and
+    prints no result."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    cmd = [sys.executable, os.path.join(REPO, "chip_smoke.py"), "--legs", "A"]
+    proc = subprocess.run(cmd + ["--rehearsal"], cwd=REPO, env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    lines = proc.stdout.strip().splitlines()
+    result = json.loads(lines[-1])
+    assert result["ok"] is True and result["rehearsal"] is True
+    assert result["device"]["platform"] == "cpu"
+    assert all("rehearsal" in ln and "cpu" in ln for ln in lines)
+    assert any("period x3 ok" in ln for ln in lines)
+
+    real = subprocess.run(cmd, cwd=REPO, env=env, capture_output=True,
+                          text=True, timeout=300)
+    assert real.returncode != 0
+    assert "not 'tpu'" in real.stderr
+    assert '"ok"' not in real.stdout

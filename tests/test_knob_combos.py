@@ -3,8 +3,7 @@
 Each case runs the committee-verify kernel end to end (good + tampered
 rows) in a subprocess with the knob env set — the knobs are read at
 import, so a fresh interpreter is the only honest way to exercise a
-configuration exactly as the bench probes deploy it
-(scripts/tpu_experiments/*_cfg_*.sh)."""
+configuration exactly as the bench's sweep children deploy it."""
 
 import os
 import subprocess
@@ -41,7 +40,7 @@ print("combo-ok")
 """
 
 COMBOS = [
-    # the round's prime probe candidates (scripts/tpu_experiments/)
+    # the sweep's prime candidates
     {"GETHSHARDING_TPU_LIMB_FORM": "wide", "GETHSHARDING_TPU_NORM": "relaxed",
      "GETHSHARDING_TPU_PAIR_UNROLL": "finalexp"},
     # mega finalexp on CPU exercises the knob wiring + XLA fallback (the

@@ -267,25 +267,3 @@ def test_node_runs_a_state_mirror():
         assert mirror.period() == backend.current_period()
     finally:
         node.stop()
-
-
-def test_compile_cache_disable_is_sticky():
-    """A multi-file run pins the compile cache OFF; later default
-    enables (force_virtual_cpu_devices mid-suite) must not resurrect it
-    — only an explicit force may (the single-module fast path)."""
-    import jax
-
-    from gethsharding_tpu.parallel import virtual
-
-    before_sticky = virtual._cache_off_sticky
-    before_dir = jax.config.jax_compilation_cache_dir
-    try:
-        virtual.configure_compile_cache(enabled=False)
-        assert jax.config.jax_compilation_cache_dir is None
-        virtual.configure_compile_cache()  # default enable: ignored
-        assert jax.config.jax_compilation_cache_dir is None
-        virtual.configure_compile_cache(force=True)  # explicit: wins
-        assert jax.config.jax_compilation_cache_dir is not None
-    finally:
-        virtual._cache_off_sticky = before_sticky
-        jax.config.update("jax_compilation_cache_dir", before_dir)

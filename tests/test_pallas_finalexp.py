@@ -9,8 +9,8 @@ XLA path, layer by layer:
    `pairing_is_one` bit-for-bit on real Miller products;
 3. the Pallas kernel in interpreter mode must match the oracle.
 
-All CPU (conftest forces virtual devices); on TPU the queued probe
-(scripts/tpu_experiments) runs the same checks compiled."""
+All CPU (conftest forces virtual devices); on the chip `chip_smoke.py`'s
+kernel leg runs the same checks compiled."""
 
 import os
 
@@ -79,28 +79,27 @@ def test_conv_matches_schoolbook():
     rng = np.random.default_rng(52)
     a = rng.integers(0, 1 << 12, (3, m.KNL)).astype(np.int32)
     b = rng.integers(0, 1 << 12, (3, m.KNL)).astype(np.int32)
-    for impl in ("shift", "slices"):
-        got = np.asarray(m._conv(_to_rows(a), _to_rows(b),
-                                 impl=impl))[..., 0]
-        for i in range(3):
-            va = limbs_to_int(a[i])
-            vb = limbs_to_int(b[i])
-            assert limbs_to_int(got[i].astype(object)) == va * vb, impl
+    got = np.asarray(m._conv(_to_rows(a), _to_rows(b)))[..., 0]
+    for i in range(3):
+        va = limbs_to_int(a[i])
+        vb = limbs_to_int(b[i])
+        assert limbs_to_int(got[i].astype(object)) == va * vb
 
 
-def test_conv_impls_bit_identical():
-    """Every MEGA_CONV implementation produces the SAME columns on
-    quasi-canonical inputs (incl. the -1 limbs relaxed normalize can
-    leave) and with broadcast leading dims — the shapes the fp12 paths
-    actually use."""
+def test_conv_broadcast_dims_and_signed_limbs():
+    """The columns are the plain schoolbook sums on quasi-canonical
+    inputs (incl. the -1 limbs relaxed normalize can leave) and with
+    broadcast leading dims — the shapes the fp12 paths actually use."""
     rng = np.random.default_rng(57)
     u = rng.integers(-1, (1 << 12) + 65, (2, 3, m.KNL, 4)).astype(np.int32)
     v = rng.integers(-1, (1 << 12) + 65, (3, m.KNL, 4)).astype(np.int32)
-    ref_cols = np.asarray(m._conv(jnp.asarray(u), jnp.asarray(v),
-                                  impl="shift"))
-    got = np.asarray(m._conv(jnp.asarray(u), jnp.asarray(v), impl="slices"))
-    assert (got == ref_cols).all()
+    want = np.zeros((2, 3, m.KNCOLS, 4), np.int64)
+    for i in range(m.KNL):
+        for j in range(m.KNL):
+            want[..., i + j, :] += u[..., i, :].astype(np.int64) * v[..., j, :]
+    got = np.asarray(m._conv(jnp.asarray(u), jnp.asarray(v)))
     assert got.shape == (2, 3, m.KNCOLS, 4)
+    assert (got == want).all()
 
 
 def test_mul_xi_value_parity():
@@ -221,39 +220,6 @@ def test_mega_kernel_interpret_matches_pairing_is_one():
     assert (got == wants).all()
 
 
-class _mega_conv:
-    """Flip the trace-time MEGA_CONV knob and drop every compiled-kernel
-    cache (finalexp, miller, agg) so the next call re-traces under it."""
-
-    def __init__(self, impl):
-        self.impl = impl
-
-    @staticmethod
-    def _clear():
-        m._compiled.cache_clear()
-        m._miller_compiled.cache_clear()
-        m._agg_compiled.cache_clear()
-
-    def __enter__(self):
-        self.old = m.MEGA_CONV
-        m.MEGA_CONV = self.impl
-        self._clear()
-
-    def __exit__(self, *exc):
-        m.MEGA_CONV = self.old
-        self._clear()
-
-
-@slow
-def test_mega_kernel_interpret_slices_conv():
-    """The whole final-exp kernel under MEGA_CONV=slices agrees with the
-    pairing oracle."""
-    fs, wants = _miller_products(1, 1)
-    with _mega_conv("slices"):
-        got = np.asarray(m.finalexp_is_one(jnp.asarray(fs), interpret=True))
-    assert (got == wants).all()
-
-
 # == the Miller mega-kernel (same module) ==================================
 
 
@@ -313,35 +279,6 @@ def test_miller_mega_kernel_interpret_matches_xla():
 
 
 @slow
-def test_miller_and_agg_kernels_interpret_slices_conv():
-    """MEGA_CONV=slices switches _conv inside the Miller AND aggregation
-    kernels too (the line-eval and tree-reduction shapes the unit
-    bit-identity test can't reach) — both must stay value-identical to
-    the XLA path under the knob, and the whole two-kernel pairing must
-    still separate valid from tampered."""
-    sig, (hx, hy), pk = _committee_workload()
-    want = np.asarray(k._bls_miller_opt(sig, hx, hy, pk))
-    tag = b"agg-mega-slices"
-    keys = [ref.bls_keygen(tag + bytes([j])) for j in range(4)]
-    sigs = [ref.bls_sign(tag, sk) for sk, _ in keys]
-    sx, sy, sm = k.g1_committee_to_limbs([sigs, sigs[:2]], 4)
-    want_g1 = k.aggregate_g1_proj(jnp.asarray(sx), jnp.asarray(sy),
-                                  jnp.asarray(sm))
-    with _mega_conv("slices"):
-        got = np.asarray(m.miller_f(sig, hx, hy, pk, interpret=True))
-        got_g1 = m.aggregate_proj(jnp.asarray(sx), jnp.asarray(sy),
-                                  jnp.asarray(sm), fp2=False,
-                                  interpret=True)
-    assert (_f_vals(want) == _f_vals(got)).all()
-    assert list(np.asarray(k.pairing_is_one(jnp.asarray(got)))) == \
-        [True, False]
-    assert np.asarray(k.FP.eq(k.FP.mul(want_g1[0], got_g1[2]),
-                              k.FP.mul(got_g1[0], want_g1[2]))).all()
-    assert np.asarray(k.FP.eq(k.FP.mul(want_g1[1], got_g1[2]),
-                              k.FP.mul(got_g1[1], want_g1[2]))).all()
-
-
-@slow
 def test_aggregation_mega_kernel_interpret_matches_xla():
     """The tree-reduction kernels reproduce the XLA masked projective
     sums (same rational point: affine cross-multiplication equality),
@@ -397,3 +334,42 @@ def test_aggregation_mega_kernel_multi_group_batch():
                               k.FP.mul(got[0], want[2]))).all()
     assert np.asarray(k.FP.eq(k.FP.mul(want[1], got[2]),
                               k.FP.mul(got[1], want[2]))).all()
+
+
+@slow
+def test_aggregation_mega_kernel_chunked_committee():
+    """Committees wider than AGG_CHUNK reduce chunk by chunk in the
+    kernel (a second grid axis) and fold the chunk sums through the XLA
+    addition tree — the audit's 144-slot shape in small: a width that is
+    not a chunk multiple (pad tail), ragged masks, one empty chunk."""
+    tag = b"agg-mega-chunks"
+    keys = [ref.bls_keygen(tag + bytes([j])) for j in range(4)]
+    sigs = [ref.bls_sign(tag, sk) for sk, _ in keys]
+    pks = [pk for _, pk in keys]
+    width = 2 * m.AGG_CHUNK + 3            # three chunks, padded tail
+    rows_s = [(sigs * width)[:width], sigs[:3]]
+    rows_p = [(pks * width)[:width], pks[:3]]
+    sx, sy, sm = k.g1_committee_to_limbs(rows_s, width)
+    gx, gy, gm = k.g2_committee_to_limbs(rows_p, width)
+    want_g1 = k.aggregate_g1_proj(jnp.asarray(sx), jnp.asarray(sy),
+                                  jnp.asarray(sm))
+    got_g1 = m.aggregate_proj(jnp.asarray(sx), jnp.asarray(sy),
+                              jnp.asarray(sm), fp2=False, interpret=True)
+    want_g2 = k.aggregate_g2_proj(jnp.asarray(gx), jnp.asarray(gy),
+                                  jnp.asarray(gm))
+    got_g2 = m.aggregate_proj(jnp.asarray(gx), jnp.asarray(gy),
+                              jnp.asarray(gm), fp2=True, interpret=True)
+    assert got_g1[0].shape == want_g1[0].shape
+    assert got_g2[0].shape == want_g2[0].shape
+    assert not np.asarray(k.FP.is_zero(got_g1[2])).any()
+    assert np.asarray(k.FP.eq(k.FP.mul(want_g1[0], got_g1[2]),
+                              k.FP.mul(got_g1[0], want_g1[2]))).all()
+    assert np.asarray(k.FP.eq(k.FP.mul(want_g1[1], got_g1[2]),
+                              k.FP.mul(got_g1[1], want_g1[2]))).all()
+    assert np.asarray(k.fp2_eq(k.fp2_mul(want_g2[0], got_g2[2]),
+                               k.fp2_mul(got_g2[0], want_g2[2]))).all()
+    assert np.asarray(k.fp2_eq(k.fp2_mul(want_g2[1], got_g2[2]),
+                               k.fp2_mul(got_g2[1], want_g2[2]))).all()
+    hx, hy, _ = k.g1_to_limbs([ref.hash_to_g1(tag)] * 2)
+    f = k._bls_miller_opt(got_g1, jnp.asarray(hx), jnp.asarray(hy), got_g2)
+    assert list(np.asarray(k.pairing_is_one(f))) == [True, True]
