@@ -324,10 +324,6 @@ def measure_single() -> dict:
         "sig_rate": round(sig_rate, 1),
         "dispatch_s": round(dispatch, 4),
         "audit_wall_s": round(wall, 4),
-        # GETHSHARDING_SIG_TIMING=1: host-marshal / transfer / device
-        # split of the last dispatch (see sigbackend.last_timing)
-        **({"sig_timing": notary.sig_backend.last_timing}
-           if os.environ.get("GETHSHARDING_SIG_TIMING") == "1" else {}),
         # the per-dispatch wire ledger rides in EVERY config's extras so
         # probe-42 transfer attribution is comparable across rounds
         # instead of living only in one-off probe artifacts

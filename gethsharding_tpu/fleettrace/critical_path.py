@@ -51,6 +51,11 @@ def segment_for(name: str) -> str:
         return "batch_assembly"
     if name.endswith("/device_dispatch"):
         return "device_dispatch"
+    if name.startswith(("sig/", "jax/")) or (
+            name.startswith("serving/") and name.endswith("/dispatch")):
+        # what lies under a request's device_dispatch span: the batch's
+        # dispatch span, the backend's dispatch span and its stages
+        return "device_dispatch"
     if name.endswith("/future_wake"):
         return "future_wake"
     if name == "fleet/hedge_wasted":

@@ -612,6 +612,10 @@ def run_sharding_node(args) -> int:
     from gethsharding_tpu import slo
 
     slo.tracker()
+    # the collector's clock: runtime/gc/pause_us beside them
+    from gethsharding_tpu.tracing import GC_CLOCK
+
+    GC_CLOCK.install()
     # boot the device introspection plane (gethsharding_tpu/devscope):
     # the HBM memory poller starts publishing devscope/mem/* gauges and
     # the near-OOM census trigger arms; the compile watch and the

@@ -1281,11 +1281,19 @@ def bls_aggregate_verify_committee_batch(hx, hy, sigx, sigy, sig_mask,
     are rejected, matching the scalar `bls_verify_aggregate`.
     Returns (B,) bool.
     """
-    sX, sY, sZ = aggregate_g1_proj(sigx, sigy, sig_mask)
-    pX, pY, pZ = aggregate_g2_proj(pkx, pky, pk_mask)
+    # the scopes are metadata only: they name the four stages in the
+    # lowered program, and in the op names of a device trace, so that a
+    # reduction of the trace finds each after a refactor
+    with jax.named_scope("bls/g1_aggregate"):
+        sX, sY, sZ = aggregate_g1_proj(sigx, sigy, sig_mask)
+    with jax.named_scope("bls/g2_aggregate"):
+        pX, pY, pZ = aggregate_g2_proj(pkx, pky, pk_mask)
     inf = FP.is_zero(sZ) | fp2_is_zero(pZ)
-    f = _bls_miller_opt((sX, sY, sZ), hx, hy, (pX, pY, pZ))
-    return pairing_is_one(f) & valid & ~inf
+    with jax.named_scope("bls/miller"):
+        f = _bls_miller_opt((sX, sY, sZ), hx, hy, (pX, pY, pZ))
+    with jax.named_scope("bls/final_exp"):
+        one = pairing_is_one(f)
+    return one & valid & ~inf
 
 
 # == Fixed-base pairing precomputation =====================================

@@ -20,6 +20,13 @@ primitive every dispatch site in `sigbackend/`, `serving/` and
   existing ``sig/marshal_time`` / ``sig/device_time`` registry timers
   (the fleet federation's "which replica's chip is slow" feed), so
   adopting the timer is not a second bookkeeping scheme.
+- **The parts of the device phase are stages.** `pull()` takes the
+  wait (``sig/block_time``) and the device-to-host copy
+  (``sig/pull_time``) as `tracing.stage`s; a dispatch site takes its
+  launch (``sig/launch_time``) the same way under `span_ctx`, and
+  `record_span()` closes the dispatch's own span over them, so
+  ``launch + block + pull <= sig/device_time`` in the registry and in
+  a trace alike.
 
 Thresholds: a pull under ``GETHSHARDING_PERFWATCH_SUSPECT_FLOOR_S``
 (default 0.25 s) is never suspect; above it, the block must have
@@ -40,7 +47,7 @@ from typing import Optional
 
 import numpy as np
 
-from gethsharding_tpu import metrics
+from gethsharding_tpu import metrics, tracing
 
 # registered at import: the /metrics?format=prom row exists from the
 # first scrape, not the first suspect
@@ -48,6 +55,8 @@ _M_SUSPECT = metrics.counter("perfwatch/timer_suspect")
 _M_PULLS = metrics.counter("perfwatch/pulls")
 _T_MARSHAL = metrics.timer("sig/marshal_time")
 _T_DEVICE = metrics.timer("sig/device_time")
+_T_BLOCK = metrics.timer("sig/block_time")
+_T_PULL = metrics.timer("sig/pull_time")
 
 
 def _suspect_floor_s() -> float:
@@ -77,8 +86,15 @@ def _checked_materialize(value, op: str):
     arr = np.asarray(value)
     t2 = time.monotonic()
     block_s, pull_s = t1 - t0, t2 - t1
+    return arr, block_s, pull_s, _verdict(op, block is not None,
+                                          block_s, pull_s)
+
+
+def _verdict(op: str, blocked: bool, block_s: float, pull_s: float) -> bool:
+    """Count one pull; True, counted and recorded, where the block did
+    not wait for what the pull then paid."""
     _M_PULLS.inc()
-    suspect = (block is not None
+    suspect = (blocked
                and pull_s > _suspect_floor_s()
                and block_s < pull_s * _suspect_ratio())
     if suspect:
@@ -90,7 +106,7 @@ def _checked_materialize(value, op: str):
         RECORDER.record("timer_suspect", op=op,
                         block_s=round(block_s, 6),
                         pull_s=round(pull_s, 6))
-    return arr, block_s, pull_s, suspect
+    return suspect
 
 
 def checked_pull(value, op: str = "pull") -> np.ndarray:
@@ -134,14 +150,29 @@ class DeviceTimer:
         dt.done()                           # device closes, rollups fed
 
     `marshal_s` / `device_s` / `block_s` / `pull_s` / `suspect` are
-    readable afterwards; `t_dispatch` / `t_done` are the monotonic
-    bounds tracer spans should use so span and rollup agree."""
+    readable afterwards; `record_span()` records the dispatch's span
+    with `t_dispatch` / `t_done` as its bounds, so span and rollup
+    agree. With the tracer on, that span's id is taken at construction
+    (`span_ctx`), under whatever span the constructing thread has open:
+    the stages of the device phase parent under it, wherever `pull()`
+    later runs."""
 
     __slots__ = ("op", "t_start", "t_dispatch", "t_done", "marshal_s",
-                 "device_s", "block_s", "pull_s", "suspect", "_observed")
+                 "device_s", "block_s", "pull_s", "suspect", "_observed",
+                 "span_ctx", "_span_parent")
 
     def __init__(self, op: str):
         self.op = op
+        # (trace_id, span_id) of this dispatch's span, None with the
+        # tracer off
+        self.span_ctx = None
+        self._span_parent = None
+        tracer = tracing.TRACER
+        if tracer.enabled:
+            outer = tracer.current()
+            self.span_ctx = (outer[0] if outer else tracer.new_trace_id(),
+                             tracer.new_trace_id())
+            self._span_parent = outer[1] if outer else None
         self.t_start = time.monotonic()
         self.t_dispatch: Optional[float] = None
         self.t_done: Optional[float] = None
@@ -167,10 +198,18 @@ class DeviceTimer:
         phase."""
         if self.t_dispatch is None:
             self.dispatched()
-        arr, block_s, pull_s, suspect = _checked_materialize(value, self.op)
-        self.block_s += block_s
-        self.pull_s += pull_s
-        self.suspect = self.suspect or suspect
+        block = getattr(value, "block_until_ready", None)
+        with tracing.stage("sig/block_time", _T_BLOCK,
+                           ctx=self.span_ctx) as blocked:
+            if block is not None:
+                block()
+        with tracing.stage("sig/pull_time", _T_PULL,
+                           ctx=self.span_ctx) as pulled:
+            arr = np.asarray(value)
+        self.block_s += blocked.seconds
+        self.pull_s += pulled.seconds
+        self.suspect = _verdict(self.op, block is not None, blocked.seconds,
+                                pulled.seconds) or self.suspect
         self.t_done = time.monotonic()
         return arr
 
@@ -186,3 +225,20 @@ class DeviceTimer:
         _T_DEVICE.observe(self.device_s)
         self._observed = True
         return self
+
+    def record_span(self, name: str, **tags) -> None:
+        """Record the dispatch's span, `t_dispatch` to `t_done`, in the
+        trace of the span that was open when this timer was made, with
+        the timer's own readings among its tags: `marshal_ms` is
+        `marshal_s`, everything before `dispatched()`, so on the
+        committee spans host marshal plus transfer staging. Nothing
+        where the tracer was off then."""
+        if self.span_ctx is None:
+            return
+        tracing.TRACER.record(
+            name, self.t_dispatch, self.t_done,
+            trace_id=self.span_ctx[0], parent_id=self._span_parent,
+            span_id=self.span_ctx[1],
+            tags={**tags, "suspect": self.suspect,
+                  "marshal_ms": round(self.marshal_s * 1e3, 3),
+                  "device_ms": round(self.device_s * 1e3, 3)})

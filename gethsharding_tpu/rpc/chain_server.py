@@ -188,6 +188,8 @@ def main(argv=None) -> int:
     from gethsharding_tpu import slo
 
     slo.tracker()
+    # the collector's clock: runtime/gc/pause_us in the same snapshot
+    tracing.GC_CLOCK.install()
     # device introspection plane: HBM poller + the devscope/* rows this
     # replica's shard_metrics snapshot federates; shard_profileStart /
     # shard_profileStop toggle on-demand profiling over the RPC below

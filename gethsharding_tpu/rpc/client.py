@@ -21,6 +21,7 @@ import logging
 import queue
 import socket
 import threading
+import time
 from dataclasses import dataclass
 from typing import Callable, List, Optional
 
@@ -51,6 +52,27 @@ class RemoteReceipt:
     tx_hash: Hash32
     status: int
     block_number: int
+
+
+@contextlib.contextmanager
+def _roundtrip(ctx, span_id):
+    """``rpc/client/roundtrip`` of a traced call, write -> reply event,
+    under the call's span `ctx`. Its `span_id` was taken before the
+    request was encoded, because the envelope names it: the remote
+    handler runs inside the roundtrip, so it is the roundtrip's child,
+    and a self-time walk of the stitched trace (fleettrace) books the
+    wire once. Recorded also where the write or the wait fails, so that
+    what the server recorded under it is not orphaned."""
+    if span_id is None:
+        yield
+        return
+    start = time.monotonic()
+    try:
+        yield
+    finally:
+        tracing.TRACER.record("rpc/client/roundtrip", start,
+                              time.monotonic(), trace_id=ctx[0],
+                              parent_id=ctx[1], span_id=span_id)
 
 
 class RPCClient:
@@ -113,7 +135,14 @@ class RPCClient:
         # dispatch spans. Extra envelope keys are legal JSON-RPC.
         # Trace-plane methods get NO span and NO envelope: a span per
         # shipped batch re-enters the export buffer it ships (see
-        # codec.TRACE_PLANE_METHODS).
+        # codec.TRACE_PLANE_METHODS). A traced call splits into leaves
+        # under its span: `rpc/client/encode` (json.dumps; a caller's
+        # codec work is its own span of that name, RpcReplicaBackend),
+        # `rpc/client/roundtrip` (write -> reply event) and, inside it
+        # from the reader thread, `rpc/client/decode` (the reply's
+        # json.loads). The envelope carries the ROUNDTRIP's span id:
+        # the server's spans are its children, beside the decode.
+        # Tracer only: nobody reads a client's registry.
         span_cm = (contextlib.nullcontext()
                    if method in codec.TRACE_PLANE_METHODS
                    else tracing.span(f"rpc/client/{method}"))
@@ -122,26 +151,35 @@ class RPCClient:
                        "params": list(params)}
             ctx = (tracing.current_context()
                    if client_span is not None else None)
+            roundtrip_id = None
             if ctx is not None:
-                request["trace"] = {"trace_id": ctx[0], "span_id": ctx[1]}
-            payload = (json.dumps(request) + "\n").encode()
-            try:
-                with self._write_lock:
-                    self._file.write(payload)
-                    self._file.flush()
-            except (OSError, ValueError):
-                # dead socket (the server was killed/restarted): the
-                # reply will never come — reclaim the pending slot
-                # instead of leaking it, and let the caller's
-                # transport-error handling (e.g. RpcReplicaBackend's
-                # redial) classify the failure
-                with self._pending_lock:
-                    self._pending.pop(rid, None)
-                raise
-            if not event.wait(self._timeout):
-                with self._pending_lock:
-                    self._pending.pop(rid, None)
-                raise TimeoutError(f"rpc call {method} timed out")
+                roundtrip_id = tracing.TRACER.new_trace_id()
+                request["trace"] = {"trace_id": ctx[0],
+                                    "span_id": roundtrip_id}
+                slot["decode_ctx"] = (ctx[0], roundtrip_id)
+            # no span for an untraced call: tracing off, or the trace
+            # plane, which must stay invisible
+            with (tracing.span("rpc/client/encode") if ctx is not None
+                  else tracing.NOOP_SPAN):
+                payload = (json.dumps(request) + "\n").encode()
+            with _roundtrip(ctx, roundtrip_id):
+                try:
+                    with self._write_lock:
+                        self._file.write(payload)
+                        self._file.flush()
+                except (OSError, ValueError):
+                    # dead socket (the server was killed/restarted): the
+                    # reply will never come — reclaim the pending slot
+                    # instead of leaking it, and let the caller's
+                    # transport-error handling (e.g. RpcReplicaBackend's
+                    # redial) classify the failure
+                    with self._pending_lock:
+                        self._pending.pop(rid, None)
+                    raise
+                if not event.wait(self._timeout):
+                    with self._pending_lock:
+                        self._pending.pop(rid, None)
+                    raise TimeoutError(f"rpc call {method} timed out")
             if "trace" in slot and client_span is not None:
                 # the server's handler trace id: equal to ours once the
                 # server stitches, the REMOTE id against an older server
@@ -184,10 +222,15 @@ class RPCClient:
     def _read_loop(self) -> None:
         try:
             for raw in self._file:
+                # the reply's decode is a span of a traced call only:
+                # with the tracer off, one attribute read
+                timed = tracing.TRACER.enabled
+                t_decode = time.monotonic() if timed else 0.0
                 try:
                     msg = json.loads(raw)
                 except json.JSONDecodeError:
                     continue
+                t_decoded = time.monotonic() if timed else 0.0
                 method = msg.get("method")
                 if method == "shard_subscription":
                     self._notifications.put(
@@ -200,6 +243,11 @@ class RPCClient:
                 with self._pending_lock:
                     slot = self._pending.pop(rid, None)
                 if slot is not None:
+                    if timed and "decode_ctx" in slot:
+                        trace_id, parent_id = slot["decode_ctx"]
+                        tracing.TRACER.record(
+                            "rpc/client/decode", t_decode, t_decoded,
+                            trace_id=trace_id, parent_id=parent_id)
                     if "trace" in msg:
                         # the handler-span trace id the server returns
                         # on the envelope — surfaced as the caller

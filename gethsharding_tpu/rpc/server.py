@@ -22,7 +22,7 @@ import threading
 import time
 from typing import Optional
 
-from gethsharding_tpu import tracing
+from gethsharding_tpu import metrics, tracing
 from gethsharding_tpu.rpc import codec
 from gethsharding_tpu.p2p.service import (
     PROTOCOL_NAME as P2P_PROTOCOL_NAME,
@@ -48,6 +48,36 @@ INTERNAL_ERROR = -32603
 # itself the backpressure once a connection has this many in flight.
 CONN_CONCURRENCY = int(os.environ.get(
     "GETHSHARDING_RPC_CONN_CONCURRENCY", "64"))
+
+
+class _Marks:
+    """One request's clock readings on its way through the server: the
+    request line read off the socket, the JSON parse, the handler's
+    return. `stem` is the method without ``shard_`` once a handler was
+    found for it (None for a bad frame, an unknown method, the built-in
+    subscribe / p2p methods and the trace plane: none of those is
+    booked). For a traced request, `span_id` is the id its
+    ``rpc/<m>/server_time`` span will get, taken before the handler
+    span that names it as parent; `trace_id` / `parent_id` place that
+    span in the caller's trace (or make it the root of its own)."""
+
+    __slots__ = ("t_read", "t_parse", "t_parsed", "t_handled", "stem",
+                 "trace_id", "span_id", "parent_id")
+
+    def __init__(self, t_read: float):
+        self.t_read = t_read
+        self.t_parse = self.t_parsed = self.t_handled = t_read
+        self.stem: Optional[str] = None
+        self.trace_id: Optional[int] = None
+        self.span_id: Optional[int] = None
+        self.parent_id: Optional[int] = None
+
+
+def _decode_stage(stem: str):
+    """The stage a verification-plane handler decodes its arguments in:
+    ``rpc/<m>/decode_time``."""
+    return tracing.stage(f"rpc/{stem}/decode_time",
+                         metrics.timer(f"rpc/{stem}/decode_time"))
 
 
 class RPCServer:
@@ -188,10 +218,12 @@ class RPCServer:
         slots = threading.BoundedSemaphore(max(1, CONN_CONCURRENCY))
         workers = []
 
-        def serve_one(raw: bytes) -> None:
+        def serve_one(raw: bytes, t_read: float) -> None:
+            marks = _Marks(t_read)
             try:
                 try:
-                    response = self._dispatch(raw, handler, write_lock)
+                    response = self._dispatch(raw, handler, write_lock,
+                                              marks)
                 finally:
                     with self._sub_lock:
                         self._inflight -= 1
@@ -204,9 +236,11 @@ class RPCServer:
                 pass  # peer gone mid-response: its client already knows
             finally:
                 slots.release()
+                self._book(marks, time.monotonic())
 
         try:
             for raw in handler.rfile:
+                t_read = time.monotonic()
                 raw = raw.strip()
                 if not raw:
                     continue
@@ -217,8 +251,8 @@ class RPCServer:
                 # once CONN_CONCURRENCY requests are in flight the read
                 # loop blocks here — TCP backpressure to the sender
                 slots.acquire()
-                worker = threading.Thread(target=serve_one, args=(raw,),
-                                          daemon=True,
+                worker = threading.Thread(target=serve_one,
+                                          args=(raw, t_read), daemon=True,
                                           name="rpc-conn-worker")
                 workers.append(worker)
                 worker.start()
@@ -242,12 +276,48 @@ class RPCServer:
                     self._p2p_peers.pop(pid, None)
                     self._p2p_meta.pop(pid, None)
 
-    def _dispatch(self, raw: bytes, handler, write_lock) -> Optional[dict]:
+    def _book(self, marks: _Marks, t_flushed: float) -> None:
+        """Close a request's server-side clocks once its response is
+        flushed (or its peer was found gone): ``rpc/<m>/server_time``
+        (request line read -> response flushed: slot wait, thread start,
+        parse, handler, response) and its part ``rpc/<m>/parse_time``,
+        always. For a traced request ``server_time`` is also the span
+        that encloses the handler span and the leaves around it:
+        ``admit`` (read -> parse), ``parse_time``, ``respond`` (handler
+        returned -> flushed). The four children do not overlap, so a
+        self-time walk of the trace (fleettrace) adds up, and with an
+        untraced caller the enclosing span is the trace's one root."""
+        stem = marks.stem
+        if stem is None:
+            return
+        metrics.timer(f"rpc/{stem}/parse_time").observe(
+            marks.t_parsed - marks.t_parse)
+        if marks.span_id is not None:
+            record = tracing.TRACER.record
+            record(f"rpc/{stem}/server_time", marks.t_read, t_flushed,
+                   trace_id=marks.trace_id, parent_id=marks.parent_id,
+                   span_id=marks.span_id)
+            for name, start, end in (
+                    ("admit", marks.t_read, marks.t_parse),
+                    ("parse_time", marks.t_parse, marks.t_parsed),
+                    ("respond", marks.t_handled, t_flushed)):
+                record(f"rpc/{stem}/{name}", start, end,
+                       trace_id=marks.trace_id, parent_id=marks.span_id)
+        # last: who waits for a request to be booked watches this count
+        metrics.timer(f"rpc/{stem}/server_time").observe(
+            t_flushed - marks.t_read)
+
+    def _dispatch(self, raw: bytes, handler, write_lock,
+                  marks: _Marks) -> Optional[dict]:
+        # annotated under a fixed name: the method is inside the frame
+        marks.t_parse = time.monotonic()
         try:
-            req = json.loads(raw)
+            with tracing.annotation("rpc/parse_time"):
+                req = json.loads(raw)
         except json.JSONDecodeError:
             return {"jsonrpc": "2.0", "id": None,
                     "error": {"code": INVALID_REQUEST, "message": "bad json"}}
+        marks.t_parsed = time.monotonic()
         rid = req.get("id")
         method = req.get("method", "")
         params = req.get("params", [])
@@ -295,30 +365,45 @@ class RPCServer:
                     return {"jsonrpc": "2.0", "id": rid,
                             "error": {"code": METHOD_NOT_FOUND,
                                       "message": f"unknown method {method}"}}
+                stem = fn.__name__[len("rpc_"):]
                 # per-request handler span: parents any serving-tier
                 # request spans the handler submits (the cross-process
                 # attribution seam), and its trace id rides back to the
                 # client on the response envelope. Extra envelope keys
                 # are legal JSON-RPC: clients read `result`/`error` only.
                 # An inbound `trace` envelope (RPCClient.call attaches
-                # the caller's span context) is ADOPTED: the handler
-                # span joins the remote trace and parents under the
-                # remote span, stitching a router-traced request into
-                # this replica's spans.
+                # the caller's span context) is ADOPTED: the request
+                # joins the remote trace under the remote span,
+                # stitching a router-traced request into this replica's
+                # spans. Between the two lies ``rpc/<m>/server_time``
+                # (`_book`), whose id is taken here.
                 if method in codec.TRACE_PLANE_METHODS:
                     # the trace plane is invisible to tracing (see
                     # codec.TRACE_PLANE_METHODS): no handler span, no
                     # trace fields on the response envelope
                     result = fn(*params)
                 else:
-                    inbound = req.get("trace")
+                    marks.stem = stem
                     ctx = None
-                    if isinstance(inbound, dict):
-                        ctx = (inbound.get("trace_id"),
-                               inbound.get("span_id"))
-                    with tracing.span(f"rpc/{method}",
-                                      ctx=ctx) as handler_span:
-                        result = fn(*params)
+                    tracer = tracing.TRACER
+                    if tracer.enabled:
+                        inbound = req.get("trace")
+                        if (isinstance(inbound, dict)
+                                and inbound.get("trace_id") is not None):
+                            marks.trace_id = int(inbound["trace_id"])
+                            parent_id = inbound.get("span_id")
+                            marks.parent_id = (None if parent_id is None
+                                               else int(parent_id))
+                        else:
+                            marks.trace_id = tracer.new_trace_id()
+                        marks.span_id = tracer.new_trace_id()
+                        ctx = (marks.trace_id, marks.span_id)
+                    handler_span = tracing.span(f"rpc/{method}", ctx=ctx)
+                    try:
+                        with handler_span:
+                            result = fn(*params)
+                    finally:
+                        marks.t_handled = time.monotonic()
                     trace_id = handler_span.trace_id
                     handler_span_id = handler_span.span_id
         except SMCRevert as exc:
@@ -472,8 +557,9 @@ class RPCServer:
         from gethsharding_tpu.serving.classes import admission_class
 
         serving = self._serving()
-        digests = [codec.dec_bytes(d) for d in digests]
-        sigs = [codec.dec_bytes(s) for s in sigs]
+        with _decode_stage("ecrecover"):
+            digests = [codec.dec_bytes(d) for d in digests]
+            sigs = [codec.dec_bytes(s) for s in sigs]
         if klass is not None or tenant is not None:
             # tenant without class still enters the context: the quota
             # must charge the tenant even when the caller says nothing
@@ -495,9 +581,10 @@ class RPCServer:
         from gethsharding_tpu.serving.classes import admission_class
 
         serving = self._serving()
-        args = ([codec.dec_bytes(m) for m in messages],
-                [codec.dec_g1(s) for s in agg_sigs],
-                [codec.dec_g2(p) for p in agg_pks])
+        with _decode_stage("verifyAggregates"):
+            args = ([codec.dec_bytes(m) for m in messages],
+                    [codec.dec_g1(s) for s in agg_sigs],
+                    [codec.dec_g2(p) for p in agg_pks])
         if klass is not None or tenant is not None:
             # see shard_ecrecover: a tenant tag alone still charges the
             # quota under this op's default class
@@ -525,11 +612,12 @@ class RPCServer:
         from gethsharding_tpu.serving.classes import admission_class
 
         serving = self._serving()
-        args = ([codec.dec_bytes(m) for m in messages],
-                codec.dec_g1_rows(sig_rows),
-                codec.dec_g2_rows(pk_rows))
-        keys = None if pk_row_keys is None else [
-            None if k is None else str(k) for k in pk_row_keys]
+        with _decode_stage("verifyCommittees"):
+            args = ([codec.dec_bytes(m) for m in messages],
+                    codec.dec_g1_rows(sig_rows),
+                    codec.dec_g2_rows(pk_rows))
+            keys = None if pk_row_keys is None else [
+                None if k is None else str(k) for k in pk_row_keys]
         if klass is not None or tenant is not None:
             with admission_class(klass or "interactive", tenant):
                 out = serving.bls_verify_committees(*args,
@@ -549,7 +637,8 @@ class RPCServer:
         from gethsharding_tpu.serving.classes import admission_class
 
         serving = self._serving()
-        args = codec.dec_das_call(chunks, indices, proofs, roots)
+        with _decode_stage("dasVerify"):
+            args = codec.dec_das_call(chunks, indices, proofs, roots)
         if klass is not None or tenant is not None:
             with admission_class(klass or "bulk_audit", tenant):
                 out = serving.das_verify_samples(*args)
@@ -570,8 +659,9 @@ class RPCServer:
         from gethsharding_tpu.serving.classes import admission_class
 
         serving = self._serving()
-        args = codec.dec_das_poly_call(commitments, index_rows, eval_rows,
-                                       proofs, ns)
+        with _decode_stage("dasPolyVerify"):
+            args = codec.dec_das_poly_call(commitments, index_rows,
+                                           eval_rows, proofs, ns)
         if klass is not None or tenant is not None:
             with admission_class(klass or "bulk_audit", tenant):
                 out = serving.das_verify_multiproofs(*args)

@@ -35,7 +35,12 @@ emits a span tree: ``serving/<op>/request`` decomposing into contiguous
 ``queue_wait`` / ``batch_assembly`` / ``device_dispatch`` children (the
 per-request latency attribution the aggregate timers cannot give), plus
 a ``future_wake`` phase recorded by the caller on resume; the dispatch
-child carries ``device_ms``/``marshal_ms``/``wire_bytes`` tags. When
+child carries ``device_ms``/``marshal_ms``/``wire_bytes`` tags. One
+``serving/<op>/dispatch`` span a batch lies on the dispatch thread's
+context stack around the inner backend's call, as a child of the
+``device_dispatch`` span of the batch's first request (the others name
+it by a ``dispatch_span`` tag), so whatever the backend records there
+(``sig/*`` stages, ``jax/*_dispatch``) joins that request's trace. When
 tracing is off the hot path pays one attribute read per request.
 
 Every completed request additionally records one per-class SLO event
@@ -301,7 +306,8 @@ class MicroBatcher:
         met = self._metrics[op]
         traced = tracing.TRACER.enabled
         try:
-            with met.dispatch_latency.time():
+            with met.dispatch_latency.time(), \
+                    self._dispatch_span(op, batch, rows, traced):
                 # ensure_host: the dispatch-latency clock must close
                 # over a HOST value — a backend handing back a lazy
                 # device buffer gets the perfwatch checked pull here, so
@@ -352,6 +358,28 @@ class MicroBatcher:
                 slo.record(request.klass, ok=True,
                            latency_s=t_done - request.enqueued_at)
             offset += request.rows
+
+    def _dispatch_span(self, op: str, batch: List[Request], rows: int,
+                       traced: bool):
+        """The ``serving/<label>/dispatch`` span of one batch, opened on
+        the dispatch thread's context stack: an enclosing span, so not a
+        `tracing.stage` (`dispatch_latency` is its timer already). The
+        ids of every request's trace and of its ``device_dispatch`` span
+        are taken here, before the spans themselves can be recorded, so
+        that this span can name the first request's as its parent."""
+        if not traced:
+            return tracing.NOOP_SPAN
+        tracer = tracing.TRACER
+        ids = [(request.trace_ctx[0] if request.trace_ctx
+                else tracer.new_trace_id(), tracer.new_trace_id())
+               for request in batch]
+        span = tracing.span(
+            f"serving/{_OP_LABELS[op]}/dispatch", ctx=ids[0], rows=rows,
+            requests=len(batch),
+            request_traces=[trace_id for trace_id, _ in ids])
+        for request, (trace_id, dispatch_id) in zip(batch, ids):
+            request.span_ids = (trace_id, dispatch_id, span.span_id)
+        return span
 
     def _fail_batch(self, batch: List[Request],
                     exc: BaseException) -> None:
@@ -408,7 +436,11 @@ class MicroBatcher:
         tracer = tracing.TRACER
         label = _OP_LABELS[op]
         ctx = request.trace_ctx
-        trace_id = ctx[0] if ctx else tracer.new_trace_id()
+        # taken by `_dispatch_span` where the batch was dispatched
+        # traced: (trace id, device_dispatch span id, dispatch span id)
+        trace_id, dispatch_id, batch_span = (
+            request.span_ids
+            or (ctx[0] if ctx else tracer.new_trace_id(), None, None))
         parent = ctx[1] if ctx else None
         # device-time attribution rides the spans: device_ms is the
         # dispatch phase of THIS request, wire_bytes/batch_rows the
@@ -433,10 +465,13 @@ class MicroBatcher:
                               "wire_bytes": wire_bytes,
                               "marshal_ms": round(
                                   (request.t_dispatch - request.t_taken)
-                                  * 1e3, 3)}
+                                  * 1e3, 3),
+                              "dispatch_span": batch_span}
             tracer.record(f"serving/{label}/{name}", start, end,
                           trace_id=trace_id, parent_id=root, tid=trace_id,
-                          tags=phase_tags)
+                          tags=phase_tags,
+                          span_id=(dispatch_id if name == "device_dispatch"
+                                   else None))
         request.trace_ids = (trace_id, root, label)
 
     def _dispatch(self, op: str, cols: tuple):

@@ -105,14 +105,17 @@ class Request:
     and `t_taken`/`t_dispatch`/`t_done` are the phase boundaries the
     batcher stamps as the request crosses threads — queue wait ends at
     `t_taken`, batch assembly at `t_dispatch`, device execution at
-    `t_done`. `trace_ids` is set once the request's spans are emitted
-    so the caller-side future wake can attach to the same trace.
+    `t_done`. `span_ids` is set where a traced batch is dispatched:
+    (trace id, the id its ``device_dispatch`` span will get, the id of
+    the batch's ``serving/<label>/dispatch`` span). `trace_ids` is set
+    once the request's spans are emitted so the caller-side future wake
+    can attach to the same trace.
     """
 
     __slots__ = ("op", "args", "rows", "future", "enqueued_at",
                  "klass", "tenant",
                  "trace_ctx", "t_taken", "t_dispatch", "t_done",
-                 "trace_ids")
+                 "span_ids", "trace_ids")
 
     def __init__(self, op: str, args: tuple, rows: int,
                  klass: str = CLASS_INTERACTIVE, tenant: str = ""):
@@ -127,6 +130,7 @@ class Request:
         self.t_taken = 0.0
         self.t_dispatch = 0.0
         self.t_done = 0.0
+        self.span_ids = None
         self.trace_ids = None
 
     def wait_s(self, now: Optional[float] = None) -> float:

@@ -39,6 +39,13 @@ from gethsharding_tpu.sigbackend import marshal
 from gethsharding_tpu.sigbackend.cache import ResidentPkCache
 from gethsharding_tpu.sigbackend.marshal import bucket_size
 
+# the committee dispatch's host stages, the parts of DeviceTimer's
+# sig/marshal_time (host_marshal + transfer) and sig/device_time (launch,
+# then perfwatch/timer.py's block and pull)
+_T_HOST_MARSHAL = metrics.timer("sig/host_marshal_time")
+_T_TRANSFER = metrics.timer("sig/transfer_time")
+_T_LAUNCH = metrics.timer("sig/launch_time")
+
 
 class JaxSigBackend(ResidentPkCache, SigBackend):
     """Batched accelerator kernels; one dispatch per batch."""
@@ -341,7 +348,6 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         e = self._sec.hashes_to_limbs(
             [bytes(d) for d in digests] + [b"\x00" * 32] * pad)
         r, s, v = self._sec.sigs_to_limbs(sigs)
-        tracer = tracing.TRACER
         dt.dispatched()
         # compile_span: a fresh shape's launch wall (trace + XLA compile
         # + enqueue) lands in the devscope compile ledger; on hits this
@@ -359,13 +365,8 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         ok_host = dt.pull(ok)
         pubs = self._sec.limbs_to_pubkeys(qx, qy, ok_host)[:n]
         dt.done()
-        if tracer.enabled:
-            tracer.record("jax/ecrecover_dispatch", dt.t_dispatch, dt.t_done,
-                          tags={"rows": n, "bucket": bucket,
-                                "compile": "miss" if fresh else "hit",
-                                "suspect": dt.suspect,
-                                "marshal_ms": round(dt.marshal_s * 1e3, 3),
-                                "device_ms": round(dt.device_s * 1e3, 3)})
+        dt.record_span("jax/ecrecover_dispatch", rows=n, bucket=bucket,
+                       compile="miss" if fresh else "hit")
         out = [ecdsa.pubkey_to_address(p) if p is not None else None
                for p in pubs]
         for i in host_rows:
@@ -392,7 +393,6 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         pkx, pky, pok = self._bn.g2_to_limbs(list(agg_pks) + [None] * pad)
         # infinity signature/key is an outright rejection (scalar parity)
         valid = hok & sok & pok
-        tracer = tracing.TRACER
         dt.dispatched()
         with self._compiles.compile_span("bls_aggregate", (bucket,), fresh):
             out = self._bls(
@@ -401,14 +401,8 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
                 jnp.asarray(valid))
         res = [bool(b) for b in dt.pull(out)[:n]]
         dt.done()
-        if tracer.enabled:
-            tracer.record("jax/bls_aggregate_dispatch", dt.t_dispatch,
-                          dt.t_done,
-                          tags={"rows": n, "bucket": bucket,
-                                "compile": "miss" if fresh else "hit",
-                                "suspect": dt.suspect,
-                                "marshal_ms": round(dt.marshal_s * 1e3, 3),
-                                "device_ms": round(dt.device_s * 1e3, 3)})
+        dt.record_span("jax/bls_aggregate_dispatch", rows=n, bucket=bucket,
+                       compile="miss" if fresh else "hit")
         return res
 
     def bls_verify_committees(self, messages, sig_rows, pk_rows,
@@ -457,22 +451,15 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         self._m_wire_bytes.inc(sample_bytes)
         tracing.tag_current_add(wire_bytes=sample_bytes,
                                 sample_wire_bytes=sample_bytes)
-        tracer = tracing.TRACER
         dt.dispatched()
         with self._compiles.compile_span("das_verify", (bucket,), fresh):
             out = das_proofs.batch_verifier()(
                 *(jnp.asarray(p) for p in planes))
         res = [bool(b) for b in dt.pull(out)[:n]]
         dt.done()
-        if tracer.enabled:
-            tracer.record("jax/das_verify_dispatch", dt.t_dispatch,
-                          dt.t_done,
-                          tags={"rows": n, "bucket": bucket,
-                                "compile": "miss" if fresh else "hit",
-                                "sample_wire_bytes": sample_bytes,
-                                "suspect": dt.suspect,
-                                "marshal_ms": round(dt.marshal_s * 1e3, 3),
-                                "device_ms": round(dt.device_s * 1e3, 3)})
+        dt.record_span("jax/das_verify_dispatch", rows=n, bucket=bucket,
+                       compile="miss" if fresh else "hit",
+                       sample_wire_bytes=sample_bytes)
         return res
 
     def das_verify_multiproofs(self, commitments, index_rows, eval_rows,
@@ -522,7 +509,6 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         self._m_wire_bytes.inc(proof_bytes)
         tracing.tag_current_add(wire_bytes=proof_bytes,
                                 sample_wire_bytes=proof_bytes)
-        tracer = tracing.TRACER
         ship = lay.place if lay.is_mesh else jnp.asarray
         dt.dispatched()
         with self._compiles.compile_span("das_poly_verify", shape, fresh):
@@ -537,67 +523,41 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
             }
         res = [bool(b) for b in dt.pull(out)[:n]]
         dt.done()
-        if tracer.enabled:
-            tracer.record("jax/das_poly_verify_dispatch", dt.t_dispatch,
-                          dt.t_done,
-                          tags={"rows": n, "bucket": bucket,
-                                "compile": "miss" if fresh else "hit",
-                                "sample_wire_bytes": proof_bytes,
-                                "suspect": dt.suspect,
-                                "marshal_ms": round(dt.marshal_s * 1e3, 3),
-                                "device_ms": round(dt.device_s * 1e3, 3)})
+        dt.record_span("jax/das_poly_verify_dispatch", rows=n,
+                       bucket=bucket, compile="miss" if fresh else "hit",
+                       sample_wire_bytes=proof_bytes)
         return res
 
     # -- the staged committee path -----------------------------------------
     # marshal (host limbs + cache resolution) -> transfer (host->device)
-    # -> dispatch (device, async) -> pull (result()). Explicit stages so
-    # the async form overlaps host staging of batch N+1 with batch N's
-    # device execution, and so the SIG_TIMING ledger can attribute every
-    # boundary.
+    # -> launch (device, async) -> block, pull (result()). Explicit
+    # stages so the async form overlaps host staging of batch N+1 with
+    # batch N's device execution, each one a `tracing.stage`: a registry
+    # timer always (sig/host_marshal_time, sig/transfer_time,
+    # sig/launch_time here, sig/block_time and sig/pull_time in
+    # `DeviceTimer.pull`), a span and a profiler annotation besides.
 
     def _committee_submit(self, messages, sig_rows, pk_rows,
                           pk_row_keys) -> VerdictFuture:
         if self._layout.is_mesh:
             return self._committee_submit_mesh(messages, sig_rows,
                                                pk_rows, pk_row_keys)
-        import time
-
-        import numpy as np
-
-        timing = os.environ.get("GETHSHARDING_SIG_TIMING") == "1"
-        if timing:
-            # the split must belong to THIS dispatch: a caller that skips
-            # the jax committee path (e.g. an empty batch) must read None,
-            # not a stale split from a prior audit in the same process
-            self.last_timing = None
         dt = DeviceTimer("bls_committee")
-        t0 = time.perf_counter()
-        jnp = self._jnp
         n = len(messages)
         if n == 0:
             self.last_wire = None
             future = VerdictFuture(lambda: [])
             future.result()
             return future
-        st = self._committee_marshal(messages, sig_rows, pk_rows,
-                                     pk_row_keys)
-        t1 = time.perf_counter()
-        args, wire = self._committee_transfer(st)
-        if timing:
-            # force EVERY host->device transfer to completion before
-            # timing the dispatch. ONE fused pull: stacking a scalar
-            # from each buffer into a single device array and pulling
-            # that once waits on all nine transfers with a single host
-            # round-trip, so transfer_s reflects transfer bandwidth — a
-            # per-buffer pull would add 9 sequential round trips the
-            # untimed production path never pays
-            probe = jnp.stack(
-                [a.ravel()[0].astype(jnp.int32) for a in args])
-            np.asarray(probe)
-            t2 = time.perf_counter()
+        with tracing.stage("sig/host_marshal_time", _T_HOST_MARSHAL):
+            st = self._committee_marshal(messages, sig_rows, pk_rows,
+                                         pk_row_keys)
+        # the staging and the enqueue of the copies, not their
+        # completion: the launch below waits for no transfer
+        with tracing.stage("sig/transfer_time", _T_TRANSFER):
+            args, wire = self._committee_transfer(st)
         # the per-dispatch wire ledger is always on (pure nbytes
-        # arithmetic, no device sync) — probe-42 transfer attribution
-        # must not require the sync-forcing timing mode
+        # arithmetic, no device sync)
         self.last_wire = wire
         RECORDER.record_wire("bls_verify_committees", wire)
         self._m_wire_bytes.inc(wire["wire_bytes"])
@@ -606,14 +566,14 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         # SUMMED, so a multi-dispatch span reports total bytes
         tracing.tag_current_add(wire_bytes=wire["wire_bytes"],
                                 pk_hit_bytes=wire["pk_hit_bytes"])
-        tracer = tracing.TRACER
-        marshal_s = t1 - t0  # host marshal: limb planes + cache resolve
         dt.dispatched()  # marshal (incl. transfer staging) closes here
+        launch = tracing.stage("sig/launch_time", _T_LAUNCH,
+                               ctx=dt.span_ctx)
         if st["precomp"]:
             with self._compiles.compile_span(
                     "bls_committee_precomp",
                     (st["bucket"], st["width"], self._wire,
-                     st["blocks"]), st["fresh"]):
+                     st["blocks"]), st["fresh"]), launch:
                 # async launch(es): the pipelined form enqueues Miller
                 # block k+1 before finalexp block k
                 out = self._precomp_launch(args, st["bucket"],
@@ -623,7 +583,8 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
                   else self._bls_committee)
             with self._compiles.compile_span(
                     "bls_committee",
-                    (st["bucket"], st["width"], self._wire), st["fresh"]):
+                    (st["bucket"], st["width"], self._wire),
+                    st["fresh"]), launch:
                 out = fn(*args)  # async dispatch: returns pre-execution
         # finalize must close over SCALARS, not the marshal dict: `st`
         # pins every host limb plane (MBs per dispatch) until result(),
@@ -635,31 +596,15 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
             # lands on perfwatch/timer_suspect
             res = [bool(b) for b in dt.pull(out)[:n]]
             dt.done()
-            if tracer.enabled:
-                # the checked pull above means the span closes only
-                # after the dispatch actually executed; on the async
-                # path it additionally covers the overlapped wait
-                tracer.record(
-                    "jax/bls_committee_dispatch", dt.t_dispatch, dt.t_done,
-                    tags={"rows": n, "bucket": bucket,
-                          "width": width, "wire": self._wire,
-                          "compile": "miss" if fresh else "hit",
-                          "suspect": dt.suspect,
-                          "wire_bytes": wire["wire_bytes"],
-                          "pk_hit_bytes": wire["pk_hit_bytes"],
-                          "marshal_ms": round(marshal_s * 1e3, 3),
-                          "device_ms": round(dt.device_s * 1e3, 3)})
-            if timing:
-                t3 = time.perf_counter()
-                # per-instance: two backends in one process must not
-                # clobber each other's split
-                self.last_timing = {
-                    "prep_s": round(t1 - t0, 4),
-                    "transfer_s": round(t2 - t1, 4),
-                    "dispatch_s": round(t3 - t2, 4),
-                    "rows": n, "width": width,
-                    **wire,
-                }
+            # the checked pull above means the span closes only after
+            # the dispatch actually executed; on the async path it
+            # additionally covers the overlapped wait
+            dt.record_span(
+                "jax/bls_committee_dispatch", rows=n, bucket=bucket,
+                width=width, wire=self._wire,
+                compile="miss" if fresh else "hit",
+                wire_bytes=wire["wire_bytes"],
+                pk_hit_bytes=wire["pk_hit_bytes"])
             return res
 
         return VerdictFuture(finalize)
@@ -675,15 +620,9 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         AOT HLO into `last_mesh["collectives"]`. Verdicts are
         bit-identical to the single-device path: same kernels, same
         padding semantics, only placement differs."""
-        import time
-
         import numpy as np
 
-        timing = os.environ.get("GETHSHARDING_SIG_TIMING") == "1"
-        if timing:
-            self.last_timing = None
         dt = DeviceTimer("bls_committee_mesh")
-        t0 = time.perf_counter()
         lay = self._layout
         n = len(messages)
         if n == 0:
@@ -692,60 +631,59 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
             future = VerdictFuture(lambda: [])
             future.result()
             return future
-        bucket = lay.mesh_bucket(n)
-        pad = bucket - n
-        width = marshal.committee_width(sig_rows, pk_rows)
-        rows = list(pk_rows) + [[]] * pad
-        keys = marshal.normalize_row_keys(pk_row_keys, len(rows))
-        resident = self._resident and keys is not None
-        precomp = self._precomp and resident
-        # the compile-cache key includes the device count: re-laying the
-        # same process over a different mesh is a fresh XLA program (and
-        # the precomp step is its own program again)
-        fresh = self._note_shape(
-            "bls_committee_mesh_precomp" if precomp
-            else "bls_committee_mesh",
-            bucket, width, self._wire, lay.n_devices)
-        check = os.environ.get("GETHSHARDING_CHECK") == "1"
-        host = marshal.committee_host_planes(
-            self._bn, messages, sig_rows, pad, width,
-            marshal.wire_dtype(self._wire_u16, check))
-        st = {"n": n, "bucket": bucket, "pad": pad, "width": width,
-              "fresh": fresh, "check": check,
-              "pk_rows": sum(1 for r in rows if r),
-              "hit_rows": 0, "hit_bytes": 0}
-        conv = marshal.wire_converter(self._wire_u16, check)
-        hx, hy = conv(host["hx"]), conv(host["hy"])
-        sx, sy = conv(host["sx"]), conv(host["sy"])
-        sm, hok = host["sm"], host["hok"]
-        wire_bytes = (hx.nbytes + hy.nbytes + sx.nbytes + sy.nbytes
-                      + sm.nbytes + hok.nbytes)
-        if precomp:
-            tab, inf, g2_bytes = self._mesh_line_tables(st, rows, keys,
-                                                        lay)
-        elif resident:
-            px, py, pm, g2_bytes = self._mesh_pk_planes(st, rows, keys,
-                                                        lay)
-        else:
-            pxh, pyh, pmh = self._pk_rows_to_limbs(rows, width,
-                                                   row_keys=keys)
-            pxh, pyh = conv(pxh), conv(pyh)
-            g2_bytes = pxh.nbytes + pyh.nbytes + pmh.nbytes
-            px, py, pm = lay.place(pxh), lay.place(pyh), lay.place(pmh)
-        wire_bytes += g2_bytes
-        t1 = time.perf_counter()
-        if precomp:
-            args = (lay.place(hx), lay.place(hy), lay.place(sx),
-                    lay.place(sy), lay.place(sm), tab, inf,
-                    lay.place(hok), self._gen_lines_mesh)
-        else:
-            args = (lay.place(hx), lay.place(hy), lay.place(sx),
-                    lay.place(sy), lay.place(sm), px, py, pm,
-                    lay.place(hok))
-        if timing:
-            for a in args:
-                a.block_until_ready()
-            t2 = time.perf_counter()
+        # the mesh twin's stages: the pk planes of a resident or precomp
+        # row are placed as they are resolved, inside the marshal stage
+        with tracing.stage("sig/host_marshal_time", _T_HOST_MARSHAL):
+            bucket = lay.mesh_bucket(n)
+            pad = bucket - n
+            width = marshal.committee_width(sig_rows, pk_rows)
+            rows = list(pk_rows) + [[]] * pad
+            keys = marshal.normalize_row_keys(pk_row_keys, len(rows))
+            resident = self._resident and keys is not None
+            precomp = self._precomp and resident
+            # the compile-cache key includes the device count: re-laying the
+            # same process over a different mesh is a fresh XLA program (and
+            # the precomp step is its own program again)
+            fresh = self._note_shape(
+                "bls_committee_mesh_precomp" if precomp
+                else "bls_committee_mesh",
+                bucket, width, self._wire, lay.n_devices)
+            check = os.environ.get("GETHSHARDING_CHECK") == "1"
+            host = marshal.committee_host_planes(
+                self._bn, messages, sig_rows, pad, width,
+                marshal.wire_dtype(self._wire_u16, check))
+            st = {"n": n, "bucket": bucket, "pad": pad, "width": width,
+                  "fresh": fresh, "check": check,
+                  "pk_rows": sum(1 for r in rows if r),
+                  "hit_rows": 0, "hit_bytes": 0}
+            conv = marshal.wire_converter(self._wire_u16, check)
+            hx, hy = conv(host["hx"]), conv(host["hy"])
+            sx, sy = conv(host["sx"]), conv(host["sy"])
+            sm, hok = host["sm"], host["hok"]
+            wire_bytes = (hx.nbytes + hy.nbytes + sx.nbytes + sy.nbytes
+                          + sm.nbytes + hok.nbytes)
+            if precomp:
+                tab, inf, g2_bytes = self._mesh_line_tables(st, rows, keys,
+                                                            lay)
+            elif resident:
+                px, py, pm, g2_bytes = self._mesh_pk_planes(st, rows, keys,
+                                                            lay)
+            else:
+                pxh, pyh, pmh = self._pk_rows_to_limbs(rows, width,
+                                                       row_keys=keys)
+                pxh, pyh = conv(pxh), conv(pyh)
+                g2_bytes = pxh.nbytes + pyh.nbytes + pmh.nbytes
+                px, py, pm = lay.place(pxh), lay.place(pyh), lay.place(pmh)
+            wire_bytes += g2_bytes
+        with tracing.stage("sig/transfer_time", _T_TRANSFER):
+            if precomp:
+                args = (lay.place(hx), lay.place(hy), lay.place(sx),
+                        lay.place(sy), lay.place(sm), tab, inf,
+                        lay.place(hok), self._gen_lines_mesh)
+            else:
+                args = (lay.place(hx), lay.place(hy), lay.place(sx),
+                        lay.place(sy), lay.place(sm), px, py, pm,
+                        lay.place(hok))
         wire = {"wire_bytes": int(wire_bytes),
                 "g2_wire_bytes": int(g2_bytes),
                 "pk_hit_bytes": int(st["hit_bytes"]),
@@ -759,8 +697,6 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         self._m_pk_hit_bytes.inc(wire["pk_hit_bytes"])
         tracing.tag_current_add(wire_bytes=wire["wire_bytes"],
                                 pk_hit_bytes=wire["pk_hit_bytes"])
-        tracer = tracing.TRACER
-        marshal_s = t1 - t0
         exe_key = (bucket, width, self._wire,
                    "precomp" if precomp else "recompute")
         mesh_fn = (self._bls_committee_mesh_precomp if precomp
@@ -769,7 +705,9 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         with self._compiles.compile_span(
                 "bls_committee_mesh_precomp" if precomp
                 else "bls_committee_mesh",
-                (bucket, width, self._wire, lay.n_devices), fresh):
+                (bucket, width, self._wire, lay.n_devices), fresh), \
+                tracing.stage("sig/launch_time", _T_LAUNCH,
+                              ctx=dt.span_ctx):
             exe = self._mesh_exec.get(exe_key)
             if exe is None:
                 # AOT: one .lower().compile() gives the executable AND
@@ -798,29 +736,13 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
             mesh_rec["verdict_devices"] = len(out.sharding.device_set)
             mesh_rec["vote_total"] = int(np.asarray(votes))
             dt.done()
-            if tracer.enabled:
-                tracer.record(
-                    "jax/bls_committee_mesh_dispatch", dt.t_dispatch,
-                    dt.t_done,
-                    tags={"rows": n, "bucket": bucket, "width": width,
-                          "wire": self._wire,
-                          "n_devices": lay.n_devices,
-                          "collectives": collectives,
-                          "compile": "miss" if fresh else "hit",
-                          "suspect": dt.suspect,
-                          "wire_bytes": wire["wire_bytes"],
-                          "pk_hit_bytes": wire["pk_hit_bytes"],
-                          "marshal_ms": round(marshal_s * 1e3, 3),
-                          "device_ms": round(dt.device_s * 1e3, 3)})
-            if timing:
-                t3 = time.perf_counter()
-                self.last_timing = {
-                    "prep_s": round(t1 - t0, 4),
-                    "transfer_s": round(t2 - t1, 4),
-                    "dispatch_s": round(t3 - t2, 4),
-                    "rows": n, "width": width,
-                    **wire,
-                }
+            dt.record_span(
+                "jax/bls_committee_mesh_dispatch", rows=n, bucket=bucket,
+                width=width, wire=self._wire, n_devices=lay.n_devices,
+                collectives=collectives,
+                compile="miss" if fresh else "hit",
+                wire_bytes=wire["wire_bytes"],
+                pk_hit_bytes=wire["pk_hit_bytes"])
             return res
 
         return VerdictFuture(finalize)
@@ -918,12 +840,6 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
                 "blocks": (int(st["blocks"]) if st["precomp"] else None),
                 "wire": self._wire}
         return args, wire
-
-    # populated by bls_verify_committees under GETHSHARDING_SIG_TIMING=1:
-    # host marshalling vs host->device transfer vs device dispatch of
-    # the LAST audit call (+ the wire ledger) — the split that decides
-    # which side of the dispatch boundary the next optimization belongs to
-    last_timing: dict | None = None
 
     # populated by EVERY committee dispatch (no sync, pure nbytes
     # arithmetic): {wire_bytes, g2_wire_bytes, pk_hit_bytes, pk_rows,

@@ -8,6 +8,10 @@ around it:
   monotonic-clock spans with tags, a bounded ring of finished spans,
   span-duration timers folded into the metrics registry, and an
   off-means-one-attribute-read enable gate.
+- ``stage.py`` — the stage clock: one timed leaf of a request as a
+  registry timer (always), a span (tracer on) and a
+  ``jax.profiler.TraceAnnotation`` (JAX imported), plus the collector's
+  pause counter (``GC_CLOCK``).
 - ``export.py`` — Chrome ``trace_event`` JSON export
   (Perfetto-loadable; the ``--trace-out`` / ``bench.py --trace``
   artifact).
@@ -22,6 +26,7 @@ from gethsharding_tpu.tracing.export import (
     clock_offset_us,
     write_chrome_trace,
 )
+from gethsharding_tpu.tracing.stage import GC_CLOCK, annotation, stage
 from gethsharding_tpu.tracing.tracer import (
     LOG_FILTER,
     NOOP_SPAN,
@@ -40,12 +45,14 @@ from gethsharding_tpu.tracing.tracer import (
 )
 
 __all__ = [
+    "GC_CLOCK",
     "LOG_FILTER",
     "NOOP_SPAN",
     "Span",
     "TRACER",
     "TraceContextFilter",
     "Tracer",
+    "annotation",
     "chrome_trace_events",
     "clock_offset_us",
     "current_context",
@@ -54,6 +61,7 @@ __all__ = [
     "install_log_correlation",
     "request_context",
     "span",
+    "stage",
     "tag_current",
     "tag_current_add",
     "write_chrome_trace",

@@ -228,14 +228,16 @@ class Tracer:
         span._token = _SPAN_STACK.set(stack + (span,))
         return span
 
-    def finish(self, span: Span) -> None:
+    def finish(self, span: Span, end: Optional[float] = None) -> None:
+        """Close `span` now, or at `end` where the caller read the clock
+        itself (`tracing.stage`: span and timer share one reading)."""
         if span._token is not None:
             try:
                 _SPAN_STACK.reset(span._token)
             except ValueError:
                 pass  # finished from another context: keep the record
             span._token = None
-        span.end = time.monotonic()
+        span.end = time.monotonic() if end is None else end
         self._record(span.name, span.trace_id, span.span_id, span.parent_id,
                      span.start, span.end, span.tags, span.tid)
 
@@ -243,14 +245,19 @@ class Tracer:
                trace_id: Optional[int] = None,
                parent_id: Optional[int] = None,
                tags: Optional[dict] = None,
-               tid: Optional[int] = None) -> Optional[int]:
+               tid: Optional[int] = None,
+               span_id: Optional[int] = None) -> Optional[int]:
         """Record a completed span from explicit monotonic timestamps —
         the cross-thread form the serving pipeline uses (a request's
         lifecycle spans caller, flusher and dispatch threads; no one
-        context owns it). Returns the span id (None when disabled)."""
+        context owns it). Returns the span id (None when disabled).
+        `span_id` is an id taken from `new_trace_id()` beforehand, by a
+        producer whose children had to name their parent before the
+        parent's end was known."""
         if not self.enabled:
             return None
-        span_id = self.new_trace_id()
+        if span_id is None:
+            span_id = self.new_trace_id()
         self._record(name, trace_id or self.new_trace_id(), span_id,
                      parent_id, start, end, dict(tags) if tags else {},
                      threading.get_ident() if tid is None else tid)

@@ -121,7 +121,13 @@ def test_routed_request_stitches_one_trace_end_to_end(tracer, fresh_slo):
     finally:
         _close_fleet(router, replicas, servers)
 
-    spans = tracer.recent_spans()
+    # the replica closes its enclosing span after it has flushed
+    deadline = time.monotonic() + 10.0
+    while time.monotonic() < deadline:
+        spans = tracer.recent_spans()
+        if any(s["name"] == "rpc/ecrecover/server_time" for s in spans):
+            break
+        time.sleep(0.001)
     routes = [s for s in spans if s["name"] == "fleet/route"]
     assert len(routes) == 1
     trace_id = routes[0]["trace"]
@@ -142,8 +148,14 @@ def test_routed_request_stitches_one_trace_end_to_end(tracer, fresh_slo):
     assert attempt["tags"]["attempt"] == 1
     client = by_name["rpc/client/shard_ecrecover"][0]
     assert client["parent"] == attempt["span"]
+    # ... through the client's roundtrip, whose span id the envelope
+    # carries, and the server's enclosing server_time span
     handler = by_name["rpc/shard_ecrecover"][0]
-    assert handler["parent"] == client["span"]
+    served = by_name["rpc/ecrecover/server_time"][0]
+    roundtrip = by_name["rpc/client/roundtrip"][0]
+    assert handler["parent"] == served["span"]
+    assert served["parent"] == roundtrip["span"]
+    assert roundtrip["parent"] == client["span"]
     # the client-side correlation tag points at the stitched trace
     assert client["tags"]["remote_trace"] == trace_id
     # the serving request hangs off the handler; its dispatch span

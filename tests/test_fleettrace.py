@@ -27,6 +27,8 @@ Contracts:
   `shutdown` unwinds every hook.
 """
 
+import time
+
 import pytest
 
 from gethsharding_tpu import metrics, tracing
@@ -425,15 +427,28 @@ def test_rpc_response_envelope_links_client_span_to_handler_span():
     server.start()
     client = RPCClient(*server.address)
     try:
+        booked = metrics.timer("rpc/blockNumber/server_time")
+        count = booked.count
         client.call("shard_blockNumber")
+        # the server closes its enclosing span after it has flushed
+        deadline = time.monotonic() + 10.0
+        while booked.count == count and time.monotonic() < deadline:
+            time.sleep(0.001)
         spans = tracing.TRACER.recent_spans()
         handler = next(s for s in spans
                        if s["name"] == "rpc/shard_blockNumber")
         client_span = next(s for s in spans
                            if s["name"] == "rpc/client/shard_blockNumber")
-        # the server adopted the caller's trace and parented under it
+        # the server adopted the caller's trace and parented under it:
+        # handler < server_time < the client's roundtrip < its call
         assert handler["trace"] == client_span["trace"]
-        assert handler["parent"] == client_span["span"]
+        by_id = {s["span"]: s for s in spans}
+        chain = [handler]
+        while chain[-1]["parent"] in by_id:
+            chain.append(by_id[chain[-1]["parent"]])
+        assert [s["name"] for s in chain[1:]] == [
+            "rpc/blockNumber/server_time", "rpc/client/roundtrip",
+            "rpc/client/shard_blockNumber"]
         # ... and the response envelope told the caller which span
         assert client_span["tags"]["remote_trace"] == handler["trace"]
         assert client_span["tags"]["remote_span"] == handler["span"]

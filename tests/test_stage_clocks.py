@@ -1,0 +1,406 @@
+"""Stage clocks (ISSUE 26): one `shard_verifyCommittees` request through
+an in-process RPC server over the jax backend, at a tiny shape on the
+CPU, read three ways: the registry timers (always on), the tracer's
+spans (one trace id from the client's call to the device pull), and the
+benchmark's per-layer metric files over two `shard_metrics` snapshots.
+Plus the primitive alone, the collector's clock and the kernels' names.
+
+Counts and containment only: no time measured here means anything.
+"""
+
+import gc
+import json
+import os
+import socket
+import sys
+import time
+
+import pytest
+
+from gethsharding_tpu import metrics, tracing
+from gethsharding_tpu.crypto import bn256 as bls
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+OP = "bls_committee"
+RPC = "rpc/verifyCommittees/"
+STAGE_TIMERS = (RPC + "server_time", RPC + "parse_time", RPC + "decode_time",
+                "sig/host_marshal_time", "sig/transfer_time",
+                "sig/launch_time", "sig/block_time", "sig/pull_time")
+SIG_SPANS = STAGE_TIMERS[3:]
+# each whole covers its parts
+WHOLES = {
+    "sig/marshal_time": ("sig/host_marshal_time", "sig/transfer_time"),
+    "sig/device_time": ("sig/launch_time", "sig/block_time",
+                        "sig/pull_time"),
+    RPC + "server_time": (RPC + "parse_time", RPC + "decode_time",
+                          f"serving/{OP}/wait_time",
+                          f"serving/{OP}/dispatch_latency"),
+}
+
+
+def _committees():
+    """Two rows x two votes, one row with a vote on another message."""
+    keys = [bls.bls_keygen(b"stage-clock-%d" % i) for i in range(2)]
+    msgs = [b"stage-header-0", b"stage-header-1"]
+    sig_rows = [[bls.bls_sign(m, sk) for sk, _ in keys] for m in msgs]
+    sig_rows[1][0] = bls.bls_sign(b"another header", keys[0][0])
+    pk_rows = [[pk for _, pk in keys] for _ in msgs]
+    return msgs, sig_rows, pk_rows, [True, False]
+
+
+class Served:
+    """The in-process server, its client and one request's arguments."""
+
+    def __init__(self):
+        from gethsharding_tpu.fleet.router import RpcReplicaBackend
+        from gethsharding_tpu.rpc.server import RPCServer
+        from gethsharding_tpu.serving import ServingSigBackend
+        from gethsharding_tpu.sigbackend import JaxSigBackend
+        from gethsharding_tpu.smc.chain import SimulatedMainchain
+
+        *self.args, self.want = _committees()
+        self.serving = ServingSigBackend(JaxSigBackend())
+        self.server = RPCServer(SimulatedMainchain(),
+                                sig_backend=self.serving)
+        self.server.start()
+        self.client = RpcReplicaBackend.dial(*self.server.address,
+                                             timeout=600.0)
+
+    def request(self):
+        """One keyless request, its verdicts checked; returns once the
+        server has booked it (it books after it flushes the response)."""
+        booked = metrics.timer(RPC + "server_time")
+        count = booked.count
+        t0 = time.monotonic()
+        assert self.client.bls_verify_committees(*self.args) == self.want
+        latency = time.monotonic() - t0
+        deadline = time.monotonic() + 10.0
+        while booked.count == count and time.monotonic() < deadline:
+            time.sleep(0.001)
+        assert booked.count == count + 1
+        return latency
+
+    def close(self):
+        self.client.close()
+        self.server.stop()
+        self.serving.close()
+
+
+@pytest.fixture(scope="module")
+def served():
+    tracing.GC_CLOCK.install()
+    box = Served()
+    try:
+        box.request()   # the first compiles, or reads the compile cache
+        yield box
+    finally:
+        box.close()
+
+
+@pytest.fixture(scope="module")
+def untraced(served):
+    """One request with the tracer off, between two `shard_metrics`."""
+    assert not tracing.TRACER.enabled
+    spans = tracing.TRACER.spans_recorded
+    before = served.client.metrics()
+    latency = served.request()
+    after = served.client.metrics()
+    return {"before": before, "after": after, "latency_s": latency,
+            "spans": tracing.TRACER.spans_recorded - spans}
+
+
+@pytest.fixture(scope="module")
+def traced(served, untraced):
+    """The spans of one request with the tracer on (client and server
+    share the process, so one ring holds both ends)."""
+    tracing.enable(ring_spans=4096)
+    tracing.TRACER.clear()
+    try:
+        served.request()
+        return tracing.TRACER.recent_spans()
+    finally:
+        tracing.disable()
+        tracing.TRACER.clear()
+
+
+@pytest.fixture(scope="module")
+def traced_server_alone(served, traced):
+    """The spans of one request whose caller is not traced, as the
+    benchmark's client is not: the frame goes over a bare socket with no
+    `trace` envelope, to a server with the tracer on."""
+    from gethsharding_tpu.rpc import codec
+
+    messages, sig_rows, pk_rows = served.args
+    frame = {"jsonrpc": "2.0", "id": 1, "method": "shard_verifyCommittees",
+             "params": [[codec.enc_bytes(m) for m in messages],
+                        codec.enc_g1_rows(sig_rows),
+                        codec.enc_g2_rows(pk_rows)]}
+    booked = metrics.timer(RPC + "server_time")
+    count = booked.count
+    tracing.enable(ring_spans=4096)
+    tracing.TRACER.clear()
+    try:
+        with socket.create_connection(served.server.address,
+                                      timeout=600.0) as sock:
+            sock.sendall((json.dumps(frame) + "\n").encode())
+            reply = json.loads(sock.makefile("rb").readline())
+        assert [bool(b) for b in reply["result"]] == served.want
+        deadline = time.monotonic() + 10.0
+        while booked.count == count and time.monotonic() < deadline:
+            time.sleep(0.001)
+        return tracing.TRACER.recent_spans()
+    finally:
+        tracing.disable()
+        tracing.TRACER.clear()
+
+
+def _delta(snap, name, field="count"):
+    def read(side):
+        row = snap[side].get(name) or {}
+        if field == "count":
+            return row.get("count", 0)
+        return row.get("mean_s", 0.0) * row.get("count", 0)
+    return read("after") - read("before")
+
+
+# == the registry: always on ================================================
+
+
+@pytest.mark.parametrize("name", STAGE_TIMERS)
+def test_one_request_counts_once_in_every_stage_timer(untraced, name):
+    assert _delta(untraced, name) == 1
+
+
+@pytest.mark.parametrize("whole", sorted(WHOLES))
+def test_each_whole_covers_its_parts(untraced, whole):
+    assert _delta(untraced, whole) == 1
+    parts = sum(_delta(untraced, part, "total") for part in WHOLES[whole])
+    # a snapshot rounds a mean to the microsecond
+    assert parts <= _delta(untraced, whole, "total") + 1e-5 * len(
+        WHOLES[whole])
+
+
+def test_tracer_off_records_no_span_and_stage_still_feeds_its_timer(
+        untraced):
+    assert untraced["spans"] == 0
+    timer = metrics.Timer()
+    recorded = tracing.TRACER.spans_recorded
+    with tracing.stage("sig/test_stage", timer) as clock:
+        time.sleep(0.001)
+    assert timer.count == 1 and clock.seconds >= 0.001
+    assert timer.mean() == clock.seconds
+    assert tracing.TRACER.spans_recorded == recorded
+
+
+# == the tracer: one trace id from the client to the pull ====================
+
+
+def _request_trace(spans):
+    handlers = [s for s in spans if s["name"] == "rpc/shard_verifyCommittees"]
+    assert len(handlers) == 1
+    trace_id = handlers[0]["trace"]
+    return trace_id, [s for s in spans if s["trace"] == trace_id]
+
+
+@pytest.mark.parametrize("name", [
+    "rpc/client/shard_verifyCommittees", "rpc/client/encode",
+    "rpc/client/roundtrip", "rpc/client/decode",
+    RPC + "server_time", "rpc/shard_verifyCommittees", RPC + "admit",
+    RPC + "parse_time", RPC + "decode_time", RPC + "respond",
+    f"serving/{OP}/request", f"serving/{OP}/device_dispatch",
+    f"serving/{OP}/dispatch", "jax/bls_committee_dispatch", *SIG_SPANS])
+def test_one_trace_id_holds_the_request_down_to_the_pull(traced, name):
+    _, mine = _request_trace(traced)
+    assert [s["name"] for s in mine].count(name) >= 1, sorted(
+        s["name"] for s in mine)
+
+
+def test_no_dispatch_span_is_a_trace_of_its_own(traced):
+    trace_id, _ = _request_trace(traced)
+    strays = [s["name"] for s in traced if s["trace"] != trace_id
+              and s["name"].startswith(("jax/", "sig/", "serving/"))]
+    assert strays == []
+
+
+def test_the_chain_of_parents_runs_from_the_client_to_the_stages(traced):
+    _, mine = _request_trace(traced)
+    by_id = {s["span"]: s for s in mine}
+    one = {s["name"]: s for s in mine}
+
+    def parent(name):
+        return by_id[one[name]["parent"]]["name"]
+
+    for name in ("rpc/client/encode", "rpc/client/roundtrip"):
+        assert parent(name) == "rpc/client/shard_verifyCommittees"
+    # the envelope names the roundtrip, not the call's span: the server
+    # works inside the roundtrip
+    for name in ("rpc/client/decode", RPC + "server_time"):
+        assert parent(name) == "rpc/client/roundtrip"
+    for name in ("rpc/shard_verifyCommittees", RPC + "admit",
+                 RPC + "parse_time", RPC + "respond"):
+        assert parent(name) == RPC + "server_time"
+    assert parent(RPC + "decode_time") == "rpc/shard_verifyCommittees"
+    assert parent(f"serving/{OP}/request") == "rpc/shard_verifyCommittees"
+    assert parent(f"serving/{OP}/device_dispatch") == f"serving/{OP}/request"
+    assert parent(f"serving/{OP}/dispatch") == f"serving/{OP}/device_dispatch"
+    assert one[f"serving/{OP}/device_dispatch"]["tags"]["dispatch_span"] \
+        == one[f"serving/{OP}/dispatch"]["span"]
+    for name in ("sig/host_marshal_time", "sig/transfer_time",
+                 "jax/bls_committee_dispatch"):
+        assert parent(name) == f"serving/{OP}/dispatch"
+    for name in ("sig/launch_time", "sig/block_time", "sig/pull_time"):
+        assert parent(name) == "jax/bls_committee_dispatch"
+
+
+def test_every_child_lies_inside_its_parents_interval(traced):
+    _, mine = _request_trace(traced)
+    by_id = {s["span"]: s for s in mine}
+    checked = 0
+    for span in mine:
+        # the wake is recorded after its request by design
+        if span["name"].endswith("/future_wake"):
+            continue
+        parent = by_id.get(span["parent"])
+        if parent is None:
+            continue
+        end = parent["end"]
+        if span["name"].endswith("/server_time"):
+            # it ends a clock read after the flush that lets the
+            # client's roundtrip end
+            end = span["end"]
+        assert parent["start"] <= span["start"] <= span["end"] <= end, (
+            span["name"], parent["name"])
+        checked += 1
+    assert checked >= 16
+
+
+@pytest.mark.parametrize("caller, root, spans", [
+    ("traced", "rpc/client/shard_verifyCommittees", 20),
+    ("traced_server_alone", RPC + "server_time", 16)])
+def test_the_self_times_of_a_real_request_add_up_to_its_root(
+        request, caller, root, spans):
+    """fleettrace's walk over the spans of a real request: every span
+    hangs in one tree under `root`, and no stretch of it is booked
+    twice (an enclosing span beside the span it encloses would be)."""
+    from gethsharding_tpu.fleettrace.critical_path import attribute
+
+    _, mine = _request_trace(request.getfixturevalue(caller))
+    attr = attribute(mine)
+    assert attr["root"] == root
+    assert attr["orphan_spans"] == 0 and attr["spans"] >= spans
+    booked = sum(attr["segments"].values())
+    # the wake overhangs its request (critical_path's docstring), the
+    # server's last clock read its roundtrip
+    assert attr["total_s"] - 1e-6 <= booked <= 1.02 * attr["total_s"] + 0.002
+    if caller == "traced":
+        assert attr["segments"]["wire"] < 0.5 * attr["total_s"]
+
+
+def test_a_stage_span_has_its_timers_bounds():
+    timer = metrics.Timer()
+    tracing.enable()
+    tracing.TRACER.clear()
+    try:
+        with tracing.span("outer") as outer:
+            with tracing.stage("sig/test_stage", timer) as clock:
+                time.sleep(0.001)
+        spans = {s["name"]: s for s in tracing.TRACER.recent_spans()}
+    finally:
+        tracing.disable()
+        tracing.TRACER.clear()
+    mine = spans["sig/test_stage"]
+    assert mine["end"] - mine["start"] == clock.seconds == timer.mean()
+    assert (mine["trace"], mine["parent"]) == (outer.trace_id, outer.span_id)
+
+
+def test_a_stage_is_a_profiler_annotation_where_jax_is_imported():
+    import jax
+
+    assert isinstance(tracing.annotation("sig/test_stage"),
+                      jax.profiler.TraceAnnotation)
+
+
+# == the collector ==========================================================
+
+
+def test_gc_clock_counts_every_collection_and_spans_the_full_ones(served):
+    counter = metrics.DEFAULT_REGISTRY.get(tracing.GC_CLOCK.COUNTER)
+    tracing.enable()
+    tracing.TRACER.clear()
+    try:
+        with tracing.span("outer") as outer:
+            junk = [[i] for i in range(20000)]
+            junk.append(junk)
+            del junk
+            before = counter.value
+            gc.collect(0)
+            assert counter.value > before     # a young collection counts
+            gc.collect()
+            with tracing.stage("sig/test_stage", metrics.Timer()):
+                pass    # a traced stage records what the callback put aside
+        spans = [s for s in tracing.TRACER.recent_spans()
+                 if s["name"] == "runtime/gc"]
+    finally:
+        tracing.disable()
+        tracing.TRACER.clear()
+    assert spans and all(s["tags"]["generation"] == 2 for s in spans)
+    assert (spans[-1]["trace"], spans[-1]["parent"]) \
+        == (outer.trace_id, outer.span_id)
+    # a snapshot may run the collector while it holds the counter's lock
+    assert metrics.DEFAULT_REGISTRY.snapshot()[
+        tracing.GC_CLOCK.COUNTER]["count"] == counter.value
+
+
+# == the benchmark's per-layer metric files =================================
+
+with open(os.path.join(REPO, "BENCHMARK.json")) as _src:
+    PER_LAYER = [m["name"] for m in json.load(_src)["per_layer"]]
+
+
+@pytest.mark.parametrize("name", PER_LAYER)
+def test_every_per_layer_metric_reads_a_number_from_two_snapshots(
+        untraced, name):
+    bench = os.path.join(REPO, "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import run
+
+    spec = run.read_json("layer_metrics", name + ".json")
+    assert spec["name"] == name
+    # the device trace is the chip's; its one metric reads this stand-in
+    trace = {"busy_s": 0.001, "counts": {f"serving/{OP}/dispatches": 1}}
+    value = run.layer_metric(spec, OP, untraced["before"], untraced["after"],
+                             client_mean_ms=1e3 * untraced["latency_s"],
+                             trace=trace)
+    assert isinstance(value, (int, float)) and value >= 0.0, (name, value)
+
+
+# == the kernels' names =====================================================
+
+
+@pytest.fixture(scope="module")
+def lowered_committee_kernel():
+    import jax
+    import jax.numpy as jnp
+
+    from gethsharding_tpu.ops import bn256_jax
+    from gethsharding_tpu.ops.limb import NLIMBS
+
+    def plane(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    rows, votes = 1, 1
+    return jax.jit(bn256_jax.bls_aggregate_verify_committee_batch).lower(
+        plane(rows, NLIMBS), plane(rows, NLIMBS),
+        plane(rows, votes, NLIMBS), plane(rows, votes, NLIMBS),
+        plane(rows, votes, dtype=jnp.bool_),
+        plane(rows, votes, 2, NLIMBS), plane(rows, votes, 2, NLIMBS),
+        plane(rows, votes, dtype=jnp.bool_), plane(rows, dtype=jnp.bool_),
+    ).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("scope", ["bls/g1_aggregate", "bls/g2_aggregate",
+                                   "bls/miller", "bls/final_exp"])
+def test_the_committee_kernels_stages_are_named_in_the_lowered_text(
+        lowered_committee_kernel, scope):
+    assert scope in lowered_committee_kernel
