@@ -13,7 +13,9 @@ that gap:
   time of that launch (trace + XLA compile + enqueue) lands here as
   that shape's compile cost. ``devscope/compile/{count,total_s}`` run
   as registry rows; per-shape detail rides ``describe()`` → the
-  /status ``devscope`` section.
+  /status ``devscope`` section. One listener (``after_compile``)
+  hears of each compile that succeeded: a serving process settles its
+  heap there.
 - **Recompile-storm detector.** Fresh-shape sightings feed a sliding
   window (``GETHSHARDING_DEVSCOPE_STORM_WINDOW_S``); when the window
   holds ``GETHSHARDING_DEVSCOPE_STORM_SHAPES`` or more, the detector
@@ -31,6 +33,7 @@ the bracket is free where it matters.
 from __future__ import annotations
 
 import contextlib
+import logging
 import os
 import threading
 import time
@@ -89,6 +92,10 @@ class CompileWatch:
         self.total_s = 0.0
         self.compiles = 0
         self.storms = 0
+        # called as after_compile(op, shape) once a compile_span's body
+        # has succeeded, on the thread that compiled and outside the
+        # lock; a composition root sets it (`GcClock.install`)
+        self.after_compile = None
 
     # -- producer API ------------------------------------------------------
 
@@ -156,7 +163,10 @@ class CompileWatch:
     def compile_span(self, op: str, shape: tuple, fresh: bool):
         """Bracket a kernel launch: on a fresh shape the body's wall
         time (trace + compile + enqueue) is booked as the compile cost;
-        on a cache hit this is one branch and a yield."""
+        on a cache hit this is one branch and a yield. A launch that
+        raised is booked too, but `after_compile` hears only of one
+        that succeeded, and what it raises is logged, not passed on:
+        the dispatch has its verdict by then."""
         if not fresh:
             yield
             return
@@ -165,6 +175,14 @@ class CompileWatch:
             yield
         finally:
             self.note_compile(op, shape, time.perf_counter() - t0)
+        after_compile = self.after_compile
+        if after_compile is None:
+            return
+        try:
+            after_compile(op, tuple(shape))
+        except Exception:  # noqa: BLE001 - never into the dispatch
+            logging.getLogger("devscope.compile").exception(
+                "after_compile failed for %s %s", op, shape)
 
     # -- consumers ---------------------------------------------------------
 

@@ -612,10 +612,6 @@ def run_sharding_node(args) -> int:
     from gethsharding_tpu import slo
 
     slo.tracker()
-    # the collector's clock: runtime/gc/pause_us beside them
-    from gethsharding_tpu.tracing import GC_CLOCK
-
-    GC_CLOCK.install()
     # boot the device introspection plane (gethsharding_tpu/devscope):
     # the HBM memory poller starts publishing devscope/mem/* gauges and
     # the near-OOM census trigger arms; the compile watch and the
@@ -624,6 +620,12 @@ def run_sharding_node(args) -> int:
     from gethsharding_tpu import devscope
 
     devscope.boot()
+    # the collector's clock: runtime/gc/* beside them. After each first
+    # compile (an in-process --sigbackend jax) it settles the heap, so
+    # that full collections stop walking what the compile left behind
+    from gethsharding_tpu.tracing import GC_CLOCK
+
+    GC_CLOCK.install(settle_after=devscope.COMPILES)
     # fleettrace: the collector assembles cross-process trace trees
     # (tail-sampled exemplars, critical-path attribution) out of this
     # node's spans plus any replica exporting to it; the exporter ships

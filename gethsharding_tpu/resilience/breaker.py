@@ -290,12 +290,12 @@ class _FailoverFuture:
         return self._inner.done()
 
     @property
-    def _serving_request(self):
+    def _serving_wake(self):
         # tracing passthrough: observe_future_wake attributes caller
-        # wake latency via the serving future's request record — hiding
+        # wake latency via the serving future's wake record — hiding
         # it here would silently drop the future_wake span whenever
         # failover wraps the serving tier
-        return getattr(self._inner, "_serving_request", None)
+        return getattr(self._inner, "_serving_wake", None)
 
 
 class FailoverSigBackend(SigBackend):

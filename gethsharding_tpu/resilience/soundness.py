@@ -213,12 +213,12 @@ class _SpotCheckFuture:
         return self._done or (bool(done()) if done is not None else False)
 
     @property
-    def _serving_request(self):
+    def _serving_wake(self):
         # tracing passthrough (same contract as _FailoverFuture):
         # observe_future_wake attributes caller wake latency via the
-        # serving future's request record — hiding it here would drop
+        # serving future's wake record — hiding it here would drop
         # the future_wake span whenever the spot-checker wraps serving
-        return getattr(self._inner, "_serving_request", None)
+        return getattr(self._inner, "_serving_wake", None)
 
 
 # == the wrapper ===========================================================

@@ -188,14 +188,16 @@ def main(argv=None) -> int:
     from gethsharding_tpu import slo
 
     slo.tracker()
-    # the collector's clock: runtime/gc/pause_us in the same snapshot
-    tracing.GC_CLOCK.install()
     # device introspection plane: HBM poller + the devscope/* rows this
     # replica's shard_metrics snapshot federates; shard_profileStart /
     # shard_profileStop toggle on-demand profiling over the RPC below
     from gethsharding_tpu import devscope
 
     devscope.boot()
+    # the collector's clock: runtime/gc/* in the same snapshot. After
+    # each first compile it settles the heap, so that a request's full
+    # collections stop walking what tracing and lowering left behind
+    tracing.GC_CLOCK.install(settle_after=devscope.COMPILES)
     # fleettrace export plane: a background exporter drains this
     # replica's finished spans to the fleet frontend's collector, which
     # rebases them onto the frontend clock (handshake-measured skew)

@@ -224,9 +224,6 @@ class MicroBatcher:
         # spans, recorded later from the flusher/dispatch threads. ONE
         # attribute read when tracing is off (the <2% overhead budget).
         request.trace_ctx = tracing.request_context()
-        if tracing.TRACER.enabled:
-            # let the caller-side wake observer find the request again
-            request.future._serving_request = request
         queue = self._queues[op]
         try:
             queue.put(request)
@@ -336,8 +333,8 @@ class MicroBatcher:
         met.dispatches.inc()
         t_done = time.monotonic()
         if traced:
-            # emit BEFORE resolving the futures so a waking caller reads
-            # complete trace_ids for its future_wake span
+            # emit BEFORE resolving the futures so a waking caller finds
+            # its future_wake span's ids on the future
             wire = self._wire_bytes(op, cols)
             for request in batch:
                 if request.t_taken and request.t_dispatch:
@@ -472,7 +469,12 @@ class MicroBatcher:
                           tags=phase_tags,
                           span_id=(dispatch_id if name == "device_dispatch"
                                    else None))
-        request.trace_ids = (trace_id, root, label)
+        # what the caller-side wake observer needs, and no more: the
+        # request itself holds the future, and a future that held the
+        # request back would keep the caller's whole batch until a full
+        # collection found the loop
+        request.future._serving_wake = (trace_id, root, label,
+                                        request.t_done, request.klass)
 
     def _dispatch(self, op: str, cols: tuple):
         if op == "bls_verify_committees":
@@ -538,14 +540,14 @@ def observe_future_wake(future) -> None:
     tracer = tracing.TRACER
     if not tracer.enabled:
         return
-    request = getattr(future, "_serving_request", None)
-    if request is None or request.trace_ids is None:
+    wake = getattr(future, "_serving_wake", None)
+    if wake is None:
         return
-    trace_id, root, label = request.trace_ids
-    tracer.record(f"serving/{label}/future_wake", request.t_done,
+    trace_id, root, label, t_done, klass = wake
+    tracer.record(f"serving/{label}/future_wake", t_done,
                   time.monotonic(), trace_id=trace_id, parent_id=root,
                   tid=trace_id,
                   # klass rides on the wake span too: the fleettrace
                   # per-class attribution tables must classify a trace
                   # even when only the serving subtree arrived
-                  tags={"klass": request.klass})
+                  tags={"klass": klass})

@@ -107,15 +107,16 @@ class Request:
     `t_taken`, batch assembly at `t_dispatch`, device execution at
     `t_done`. `span_ids` is set where a traced batch is dispatched:
     (trace id, the id its ``device_dispatch`` span will get, the id of
-    the batch's ``serving/<label>/dispatch`` span). `trace_ids` is set
-    once the request's spans are emitted so the caller-side future wake
-    can attach to the same trace.
+    the batch's ``serving/<label>/dispatch`` span). Once the request's
+    spans are emitted the future carries `_serving_wake`, so that the
+    caller-side future wake can attach to the same trace; the future
+    never names the request, which names it.
     """
 
     __slots__ = ("op", "args", "rows", "future", "enqueued_at",
                  "klass", "tenant",
                  "trace_ctx", "t_taken", "t_dispatch", "t_done",
-                 "span_ids", "trace_ids")
+                 "span_ids")
 
     def __init__(self, op: str, args: tuple, rows: int,
                  klass: str = CLASS_INTERACTIVE, tenant: str = ""):
@@ -131,7 +132,6 @@ class Request:
         self.t_dispatch = 0.0
         self.t_done = 0.0
         self.span_ids = None
-        self.trace_ids = None
 
     def wait_s(self, now: Optional[float] = None) -> float:
         """Seconds this request has been queued."""

@@ -22,7 +22,10 @@ host span overlapping it longest would hand every gap to the enclosure.
 
 The collector is the one stage no code enters: `GC_CLOCK.install()`
 hangs a callback on `gc.callbacks` that counts the microseconds the
-process spent inside collections.
+process spent inside collections. A serving process also hands it its
+compile watch (`settle_after=`): after each first compile the clock
+settles the heap, so that a full collection stops walking what the
+compile left behind.
 """
 
 from __future__ import annotations
@@ -112,17 +115,35 @@ class _PauseCounter(metrics.Counter):
 
 class GcClock:
     """Microseconds spent inside garbage collections, of every
-    generation, in the counter ``runtime/gc/pause_us``; with the tracer
-    on, a full (generation 2) collection also becomes a ``runtime/gc``
-    span under the span it interrupted. A collection stops every thread
-    of the process, so a server's count over a window, per request, is
-    what the collector cost each request."""
+    generation, in the counter ``runtime/gc/pause_us``; those of the
+    full (generation 2) collections alone in
+    ``runtime/gc/full_pause_us``, their number in
+    ``runtime/gc/full_collections``. With the tracer on, a full
+    collection also becomes a ``runtime/gc`` span under the span it
+    interrupted. A collection stops every thread of the process, so a
+    server's count over a window, per request, is what the collector
+    cost each request.
+
+    What a full collection costs is the heap it walks, and in a process
+    that has compiled a kernel nearly all of that heap is what tracing
+    and lowering left in JAX's caches: hundreds of thousands of objects
+    that never die. `settle` moves them out of the collector's sight
+    once a compile is over (``runtime/gc/settles``,
+    ``runtime/gc/frozen_objects``)."""
 
     COUNTER = "runtime/gc/pause_us"
+    FULL_COUNTER = "runtime/gc/full_pause_us"
+    FULL_COLLECTIONS = "runtime/gc/full_collections"
+    SETTLES = "runtime/gc/settles"
+    FROZEN = "runtime/gc/frozen_objects"
 
     def __init__(self) -> None:
         self._installed = False
         self._pause_us: Optional[metrics.Counter] = None
+        self._full_pause_us: Optional[metrics.Counter] = None
+        self._full_collections: Optional[metrics.Counter] = None
+        self._settles: Optional[metrics.Counter] = None
+        self._frozen: Optional[metrics.Gauge] = None
         self._started = 0.0
         # full collections seen while the tracer was on, waiting to be
         # recorded from a point that holds none of the tracer's locks
@@ -130,28 +151,67 @@ class GcClock:
         # (start, end, tid, context, collected)
         self._spans: deque = deque(maxlen=256)
 
-    def install(self, registry: metrics.Registry = metrics.DEFAULT_REGISTRY
-                ) -> None:
+    def install(self, registry: metrics.Registry = metrics.DEFAULT_REGISTRY,
+                settle_after=None) -> None:
         """Idempotent; the composition roots call it where they boot the
-        registry's other always-on series."""
-        if self._installed:
-            return
-        self._pause_us = registry._get_or_register(self.COUNTER,
-                                                   _PauseCounter)
-        gc.callbacks.append(self._on_gc)
-        self._installed = True
+        registry's other always-on series. `settle_after` is the
+        process's `devscope.CompileWatch`: the heap is settled after
+        every compile of its that succeeded (`settle` becomes the
+        watch's `after_compile`). Only a process that serves passes it. A
+        library import or a test must not: a frozen cycle is never
+        freed, and a test process holds thousands of objects that its
+        tests expect `gc.collect()` to free."""
+        if not self._installed:
+            def pause_counter(name):
+                return registry._get_or_register(name, _PauseCounter)
+
+            self._pause_us = pause_counter(self.COUNTER)
+            self._full_pause_us = pause_counter(self.FULL_COUNTER)
+            self._full_collections = pause_counter(self.FULL_COLLECTIONS)
+            self._settles = registry.counter(self.SETTLES)
+            self._frozen = registry.gauge(self.FROZEN)
+            gc.callbacks.append(self._on_gc)
+            self._installed = True
+        if settle_after is not None:
+            settle_after.after_compile = self.settle
 
     def _on_gc(self, phase: str, info: dict) -> None:
         if phase == "start":
             self._started = time.monotonic()
             return
         end = time.monotonic()
-        self._pause_us.inc(int((end - self._started) * 1e6))
-        if info.get("generation") == 2 and TRACER.enabled:
+        pause_us = int((end - self._started) * 1e6)
+        self._pause_us.inc(pause_us)
+        if info.get("generation") != 2:
+            return
+        self._full_pause_us.inc(pause_us)
+        self._full_collections.inc()
+        if TRACER.enabled:
             stack = _SPAN_STACK.get()
             ctx = (stack[-1].trace_id, stack[-1].span_id) if stack else None
             self._spans.append((self._started, end, threading.get_ident(),
                                 ctx, info.get("collected", 0)))
+
+    def settle(self, op: str, shape: tuple) -> None:
+        """Collect what is dead, then move everything that survived into
+        the collector's permanent generation. Later full collections
+        walk only what was allocated since; the collector stays enabled
+        and its thresholds stay as they were, so a cycle made after this
+        is found as soon as before. A frozen object is still freed when
+        its last reference goes; only a cycle that was alive when frozen
+        and dies later is kept for good, which is why nothing but a
+        serving process, after a compile, calls this. The compile
+        watch's `after_compile`: called after the first dispatch of `op`
+        at `shape` has launched, on the thread that launched it."""
+        with TRACER.start("runtime/gc/settle",
+                          {"op": op, "shape": list(shape)}) as span:
+            gc.collect()
+            gc.freeze()
+            frozen = gc.get_freeze_count()
+            span.tag(frozen_objects=frozen)
+        self._frozen.set(frozen)
+        self._settles.inc()
+        self.flush_spans()  # the collection above, under the settle
 
     def flush_spans(self) -> None:
         """Record the full collections the callback has put aside. Every

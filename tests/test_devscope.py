@@ -298,6 +298,9 @@ def test_near_oom_dumps_census_bundle(tmp_path, monkeypatch):
     poller = MemoryPoller(interval_s=60, devices_fn=lambda: [dev],
                           buffers_fn=lambda: list(bufs))
     before = metrics.counter("devscope/mem/near_oom").value
+    # an earlier test's dump may still be writing: a trigger that finds
+    # one pending is suppressed, and this test would wait for nothing
+    RECORDER.flush(timeout=30.0)
     poller.poll_once()
     assert metrics.counter("devscope/mem/near_oom").value == before + 1
     deadline = time.monotonic() + 10.0

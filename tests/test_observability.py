@@ -409,6 +409,64 @@ def test_serving_request_spans_decompose_to_parent(tracer, tmp_path):
     assert timer is not None and timer.count >= clients
 
 
+def _over_serving(serving, wrappers):
+    from gethsharding_tpu.resilience.breaker import FailoverSigBackend
+    from gethsharding_tpu.resilience.soundness import SpotCheckSigBackend
+
+    backend = serving
+    for wrapper in wrappers:
+        backend = (FailoverSigBackend(backend) if wrapper == "failover"
+                   else SpotCheckSigBackend(backend, rate=1.0, rows=1))
+    return backend
+
+
+@pytest.mark.parametrize("wrappers", [
+    (), ("failover",), ("spot_check",), ("spot_check", "failover")],
+    ids=lambda w: "+".join(w) or "bare")
+def test_future_wake_is_recorded_through_the_wrapped_futures(
+        tracer, wrappers):
+    """The notary's recover phase: `submit` on whatever `--serving
+    --sigbackend failover-* --soundness-rate` stacked over the serving
+    tier, `result()`, then `observe_future_wake` on the wrapper's own
+    future, which has `__slots__` and only reads through to the serving
+    future. The wake span lands under the request's, and nothing the
+    request held waits for the collector once the caller lets go."""
+    import gc
+
+    from gethsharding_tpu.serving.batcher import observe_future_wake
+    from gethsharding_tpu.serving.queue import Request
+
+    serving = _serving_backend()
+    backend = _over_serving(serving, wrappers)
+    gc.collect()
+    gc.set_debug(gc.DEBUG_SAVEALL)     # what a collection finds, it keeps
+    try:
+        for i in range(2):  # the tier's threads hold on to their last
+            with tracing.span("notary/recover"):
+                future = backend.submit("ecrecover_addresses",
+                                        *_garbage_rows(i))
+                assert len(future.result()) == 1
+                observe_future_wake(future)
+            del future
+        gc.collect()
+        loops = [o for o in gc.garbage if isinstance(o, Request)]
+    finally:
+        gc.set_debug(0)
+        gc.garbage.clear()
+        serving.close()
+    assert loops == []
+    spans = tracer.recent_spans()
+    requests = {s["span"]: s for s in spans
+                if s["name"] == "serving/ecrecover/request"}
+    wakes = [s for s in spans if s["name"] == "serving/ecrecover/future_wake"]
+    assert len(requests) == len(wakes) == 2
+    for wake in wakes:
+        request = requests[wake["parent"]]
+        assert wake["trace"] == request["trace"]
+        assert wake["start"] == request["end"]
+        assert wake["tags"]["klass"] == request["tags"]["klass"]
+
+
 def test_failed_dispatch_still_emits_error_tagged_spans(tracer):
     """Errored requests are the ones most worth attributing: a batch
     whose device call raises still emits its request span tree, tagged

@@ -652,7 +652,7 @@ def test_fail_current_min_age_spares_a_fresh_batch():
 
 def test_failover_future_proxies_serving_request():
     """observe_future_wake attributes wake latency via the serving
-    future's `_serving_request`; the failover wrapper must pass it
+    future's `_serving_wake`; the failover wrapper must pass it
     through or the future_wake span silently disappears under
     failover-* + --serving."""
     from concurrent.futures import Future
@@ -660,11 +660,11 @@ def test_failover_future_proxies_serving_request():
     from gethsharding_tpu.resilience.breaker import _FailoverFuture
 
     inner: Future = Future()
-    inner._serving_request = sentinel = object()
+    inner._serving_wake = sentinel = object()
     wrapped = _FailoverFuture(inner, lambda exc: None, lambda: None)
-    assert wrapped._serving_request is sentinel
+    assert wrapped._serving_wake is sentinel
     bare = _FailoverFuture(Future(), lambda exc: None, lambda: None)
-    assert bare._serving_request is None
+    assert bare._serving_wake is None
 
 
 # -- drain-and-fail dispatcher shutdown --------------------------------------

@@ -351,6 +351,29 @@ def test_gc_clock_counts_every_collection_and_spans_the_full_ones(served):
         tracing.GC_CLOCK.COUNTER]["count"] == counter.value
 
 
+def test_a_traced_request_dies_with_its_last_reference(served):
+    """With the tracer on the request's future carries what the wake
+    span needs and never the request, which names the future: such a
+    loop kept every decoded point of the batch until a full collection
+    (PR 27's first `--trace-out` window)."""
+    from gethsharding_tpu.serving.queue import Request
+
+    tracing.enable()
+    gc.collect()
+    gc.set_debug(gc.DEBUG_SAVEALL)     # what a collection finds, it keeps
+    try:
+        for _ in range(2):  # the server's threads hold on to their last
+            served.request()
+        gc.collect()
+        found = [o for o in gc.garbage if isinstance(o, (Request, bls.Fp2))]
+    finally:
+        gc.set_debug(0)
+        gc.garbage.clear()
+        tracing.disable()
+        tracing.TRACER.clear()
+    assert found == []
+
+
 # == the benchmark's per-layer metric files =================================
 
 with open(os.path.join(REPO, "BENCHMARK.json")) as _src:
