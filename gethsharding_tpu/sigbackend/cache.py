@@ -21,6 +21,10 @@ byte-budgeted device LRU: per-row Miller line-coefficient TABLES
 (`(key, "lines")` entries, `ops/bn256_jax.precompute_lines` output).
 A cold row pays one precompute dispatch; every warm audit then ships
 zero G2 bytes AND skips the fixed-argument point arithmetic entirely.
+The miss rows of a dispatch are precomputed together, padded to
+`marshal.bucket_size` of their number, so that the precompute is one
+compiled program per bucket, counted and settled like every other
+(`g2_line_precompute` in `jax/compile_cache/*` and the compile watch).
 Tables are keyed by `pk_row_key` alone — the on-device aggregate is a
 function of row content only, so one table serves every committee
 width and wire dtype. Entries are charged at their TRUE device byte
@@ -49,8 +53,13 @@ import os
 import threading
 from collections import OrderedDict
 
-from gethsharding_tpu import metrics
+from gethsharding_tpu import metrics, tracing
 from gethsharding_tpu.sigbackend import marshal
+
+# the two parts of sig/transfer_time that only a line-table miss pays
+# (a batch-memo hit enters neither)
+_T_LINE_PRECOMPUTE = metrics.timer("sig/line_precompute_time")
+_T_LINE_STACK = metrics.timer("sig/line_stack_time")
 
 
 class MeshCacheShard:
@@ -494,13 +503,30 @@ class ResidentPkCache:
         st["line_plan"] = plan
         st["hit_rows"], st["hit_bytes"] = hit_rows, hit_bytes
         if misses:
-            mx, my, mm = self._pk_rows_to_limbs(
-                [row for row, _ in misses], width,
-                row_keys=[key for _, key in misses])
-            st["line_miss"] = (mx, my, mm)
+            st["line_miss"] = self._miss_planes(misses, width)
             st["line_miss_keys"] = [key for _, key in misses]
         else:
             st["line_miss"] = None
+
+    def _miss_planes(self, misses, width: int):
+        """The host pk planes of a precompute's miss rows, padded with
+        empty rows (mask all False) to `bucket_size(len(misses))`: the
+        precompute compiles once per bucket, not once per miss count."""
+        pad = marshal.bucket_size(len(misses)) - len(misses)
+        return self._pk_rows_to_limbs(
+            [row for row, _ in misses] + [[]] * pad, width,
+            row_keys=[key for _, key in misses])
+
+    def _precompute_lines(self, planes, width: int, *shape_tail):
+        """Launch the precompute over padded miss planes already on
+        their device. A bucket this process has not precomputed before
+        is a compile like any other: counted by `_note_shape`, booked
+        and settled by `compile_span`."""
+        shape = (int(planes[0].shape[0]), width, self._wire) + shape_tail
+        fresh = self._note_shape("g2_line_precompute", *shape)
+        with self._compiles.compile_span("g2_line_precompute", shape,
+                                         fresh):
+            return self._precompute(*planes)
 
     def _line_tables(self, st: dict):
         """Device half of the precomp path: ONE precompute dispatch
@@ -515,40 +541,45 @@ class ResidentPkCache:
         miss_dev = []
         g2_bytes = 0
         if st["line_miss"] is not None:
-            mx, my, mm = st["line_miss"]
-            if st["check"] and self._wire_u16 and mx.size:
-                marshal.assert_canonical_limbs(mx, my)
-            dmx, dmy, dmm = (jnp.asarray(mx), jnp.asarray(my),
-                             jnp.asarray(mm))
-            g2_bytes = mx.nbytes + my.nbytes + mm.nbytes
-            tabs, infs = self._precompute(dmx, dmy, dmm)
-            for j, key in enumerate(st["line_miss_keys"]):
-                nbytes = int(tabs[j].nbytes) + int(infs[j].nbytes)
-                entry = (tabs[j], infs[j], None, nbytes)
-                if key is not None:
-                    self._pk_dev_insert((key, "lines"), entry)
-                miss_dev.append(entry)
-        zt, zi = self._zero_line_row()
-        ts, fs = [], []
-        for step in st["line_plan"]:
-            if step[0] == "zero":
-                entry = (zt, zi)
-            elif step[0] == "hit":
-                entry = step[1]
-            else:
-                entry = miss_dev[step[1]]
-            ts.append(entry[0])
-            fs.append(entry[1])
-        tab, inf = jnp.stack(ts), jnp.stack(fs)
-        if st["line_key"] is not None:
-            with self._pk_dev_lock:
-                self._pk_line_memo = (st["line_key"], (tab, inf),
-                                      st["hit_bytes"] + g2_bytes)
-                self._pk_line_memo_nbytes = (int(tab.nbytes)
-                                             + int(inf.nbytes))
-                self._g_dev_bytes.set(
-                    self._pk_dev_bytes + self._pk_batch_memo_nbytes
-                    + self._pk_line_memo_nbytes)
+            with tracing.stage("sig/line_precompute_time",
+                               _T_LINE_PRECOMPUTE):
+                mx, my, mm = st["line_miss"]
+                if st["check"] and self._wire_u16 and mx.size:
+                    marshal.assert_canonical_limbs(mx, my)
+                # what crosses the link, the bucket's empty rows too
+                g2_bytes = mx.nbytes + my.nbytes + mm.nbytes
+                tabs, infs = self._precompute_lines(
+                    (jnp.asarray(mx), jnp.asarray(my), jnp.asarray(mm)),
+                    st["width"])
+                # the tables of the bucket's empty rows are dropped
+                for j, key in enumerate(st["line_miss_keys"]):
+                    nbytes = int(tabs[j].nbytes) + int(infs[j].nbytes)
+                    entry = (tabs[j], infs[j], None, nbytes)
+                    if key is not None:
+                        self._pk_dev_insert((key, "lines"), entry)
+                    miss_dev.append(entry)
+        with tracing.stage("sig/line_stack_time", _T_LINE_STACK):
+            zt, zi = self._zero_line_row()
+            ts, fs = [], []
+            for step in st["line_plan"]:
+                if step[0] == "zero":
+                    entry = (zt, zi)
+                elif step[0] == "hit":
+                    entry = step[1]
+                else:
+                    entry = miss_dev[step[1]]
+                ts.append(entry[0])
+                fs.append(entry[1])
+            tab, inf = jnp.stack(ts), jnp.stack(fs)
+            if st["line_key"] is not None:
+                with self._pk_dev_lock:
+                    self._pk_line_memo = (st["line_key"], (tab, inf),
+                                          st["hit_bytes"] + g2_bytes)
+                    self._pk_line_memo_nbytes = (int(tab.nbytes)
+                                                 + int(inf.nbytes))
+                    self._g_dev_bytes.set(
+                        self._pk_dev_bytes + self._pk_batch_memo_nbytes
+                        + self._pk_line_memo_nbytes)
         return tab, inf, g2_bytes
 
     # -- per-device mesh shards --------------------------------------------
@@ -852,17 +883,17 @@ class ResidentPkCache:
                         shard.m_miss.inc()
             miss_dev = []
             if misses:
-                mx, my, mm = self._pk_rows_to_limbs(
-                    [row for row, _ in misses], width,
-                    row_keys=[key for _, key in misses])
+                mx, my, mm = self._miss_planes(misses, width)
                 if st["check"] and self._wire_u16 and mx.size:
                     marshal.assert_canonical_limbs(mx, my)
-                dmx = jax.device_put(mx, shard.device)
-                dmy = jax.device_put(my, shard.device)
-                dmm = jax.device_put(mm, shard.device)
                 g2_bytes += mx.nbytes + my.nbytes + mm.nbytes
                 miss_rows += len(misses)
-                tabs, infs = self._precompute(dmx, dmy, dmm)
+                # a jit over committed inputs compiles per device: the
+                # shard's index is part of the shape
+                tabs, infs = self._precompute_lines(
+                    tuple(jax.device_put(plane, shard.device)
+                          for plane in (mx, my, mm)),
+                    width, shard.index)
                 for j, (row, key) in enumerate(misses):
                     nbytes = int(tabs[j].nbytes) + int(infs[j].nbytes)
                     entry = (tabs[j], infs[j], None, nbytes)

@@ -150,6 +150,8 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         # are lock-guarded (cache.py)
         self._init_pk_caches()
         self._m_wire_bytes = metrics.counter("jax/wire/bytes")
+        # the G2 part of it: pk planes shipped cold (0 on a warm audit)
+        self._m_g2_bytes = metrics.counter("jax/wire/g2_bytes")
         self._m_pk_hit_bytes = metrics.counter("jax/wire/pk_device_hit_bytes")
         # device-time attribution rollups (sig/{marshal_time,
         # device_time}) are fed by the perfwatch DeviceTimer each
@@ -553,7 +555,9 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
             st = self._committee_marshal(messages, sig_rows, pk_rows,
                                          pk_row_keys)
         # the staging and the enqueue of the copies, not their
-        # completion: the launch below waits for no transfer
+        # completion: the launch below waits for no transfer. A line
+        # table miss adds two stages of its own inside it (cache.py):
+        # sig/line_precompute_time and sig/line_stack_time
         with tracing.stage("sig/transfer_time", _T_TRANSFER):
             args, wire = self._committee_transfer(st)
         # the per-dispatch wire ledger is always on (pure nbytes
@@ -561,6 +565,7 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         self.last_wire = wire
         RECORDER.record_wire("bls_verify_committees", wire)
         self._m_wire_bytes.inc(wire["wire_bytes"])
+        self._m_g2_bytes.inc(wire["g2_wire_bytes"])
         self._m_pk_hit_bytes.inc(wire["pk_hit_bytes"])
         # stamp the enclosing caller span (the notary's notary/audit);
         # SUMMED, so a multi-dispatch span reports total bytes
@@ -590,6 +595,9 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         # pins every host limb plane (MBs per dispatch) until result(),
         # and an overlapped K-period pipeline holds K of them at once
         bucket, width, fresh = st["bucket"], st["width"], st["fresh"]
+        # rows served from resident line tables, and rows precomputed
+        line_hit = wire["pk_hit_rows"] if st["precomp"] else 0
+        line_miss = (wire["pk_rows"] - line_hit) if st["precomp"] else 0
 
         def finalize():
             # the checked pull is the barrier: block-vs-pull divergence
@@ -604,7 +612,8 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
                 width=width, wire=self._wire,
                 compile="miss" if fresh else "hit",
                 wire_bytes=wire["wire_bytes"],
-                pk_hit_bytes=wire["pk_hit_bytes"])
+                pk_hit_bytes=wire["pk_hit_bytes"],
+                line_miss_rows=line_miss, line_hit_rows=line_hit)
             return res
 
         return VerdictFuture(finalize)
@@ -694,6 +703,7 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         self.last_wire = wire
         RECORDER.record_wire("bls_verify_committees", wire)
         self._m_wire_bytes.inc(wire["wire_bytes"])
+        self._m_g2_bytes.inc(wire["g2_wire_bytes"])
         self._m_pk_hit_bytes.inc(wire["pk_hit_bytes"])
         tracing.tag_current_add(wire_bytes=wire["wire_bytes"],
                                 pk_hit_bytes=wire["pk_hit_bytes"])

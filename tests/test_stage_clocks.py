@@ -48,6 +48,19 @@ def _committees():
     return msgs, sig_rows, pk_rows, [True, False]
 
 
+# a line-table miss alone enters these two, inside sig/transfer_time
+LINE_STAGES = ("sig/line_precompute_time", "sig/line_stack_time")
+
+
+def _keyed_committees():
+    """Four rows x three votes, all good: bucket 4 and width 4, the
+    shapes `tests/test_sigbackend_precomp.py` keeps warm."""
+    keys = [bls.bls_keygen(b"stage-clock-keyed-%d" % i) for i in range(3)]
+    msgs = [b"stage-keyed-header-%d" % i for i in range(4)]
+    sig_rows = [[bls.bls_sign(m, sk) for sk, _ in keys] for m in msgs]
+    return msgs, sig_rows, [[pk for _, pk in keys] for _ in msgs], [True] * 4
+
+
 class Served:
     """The in-process server, its client and one request's arguments."""
 
@@ -59,6 +72,7 @@ class Served:
         from gethsharding_tpu.smc.chain import SimulatedMainchain
 
         *self.args, self.want = _committees()
+        self.keyed, self.keyed_sent = _keyed_committees(), 0
         self.serving = ServingSigBackend(JaxSigBackend())
         self.server = RPCServer(SimulatedMainchain(),
                                 sig_backend=self.serving)
@@ -66,13 +80,19 @@ class Served:
         self.client = RpcReplicaBackend.dial(*self.server.address,
                                              timeout=600.0)
 
-    def request(self):
-        """One keyless request, its verdicts checked; returns once the
+    def request(self, keyed=False):
+        """One keyless request, or one of `_keyed_committees` under row
+        keys never sent before, its verdicts checked; returns once the
         server has booked it (it books after it flushes the response)."""
         booked = metrics.timer(RPC + "server_time")
         count = booked.count
+        args, want = self.args, self.want
+        if keyed:
+            self.keyed_sent += 1
+            *args, want = self.keyed
+            args.append([("stage", self.keyed_sent, r) for r in range(4)])
         t0 = time.monotonic()
-        assert self.client.bls_verify_committees(*self.args) == self.want
+        assert self.client.bls_verify_committees(*args) == want
         latency = time.monotonic() - t0
         deadline = time.monotonic() + 10.0
         while booked.count == count and time.monotonic() < deadline:
@@ -117,6 +137,30 @@ def traced(served, untraced):
     tracing.TRACER.clear()
     try:
         served.request()
+        return tracing.TRACER.recent_spans()
+    finally:
+        tracing.disable()
+        tracing.TRACER.clear()
+
+
+@pytest.fixture(scope="module")
+def keyed(served, untraced):
+    """One request whose four row keys are new, so that four line tables
+    miss, with the tracer off, between two `shard_metrics`."""
+    served.request(keyed=True)   # compiles the table-fed kernels' shapes
+    before = served.client.metrics()
+    latency = served.request(keyed=True)
+    after = served.client.metrics()
+    return {"before": before, "after": after, "latency_s": latency}
+
+
+@pytest.fixture(scope="module")
+def keyed_traced(served, keyed):
+    """The spans of one such request with the tracer on."""
+    tracing.enable(ring_spans=4096)
+    tracing.TRACER.clear()
+    try:
+        served.request(keyed=True)
         return tracing.TRACER.recent_spans()
     finally:
         tracing.disable()
@@ -178,6 +222,21 @@ def test_each_whole_covers_its_parts(untraced, whole):
     # a snapshot rounds a mean to the microsecond
     assert parts <= _delta(untraced, whole, "total") + 1e-5 * len(
         WHOLES[whole])
+
+
+@pytest.mark.parametrize("name", LINE_STAGES)
+def test_a_line_table_miss_enters_each_line_stage_once(untraced, keyed,
+                                                       name):
+    assert _delta(untraced, name) == 0      # no row keys: no line table
+    assert _delta(keyed, name) == 1
+
+
+def test_the_transfer_stage_covers_the_line_stages(keyed):
+    assert _delta(keyed, "sig/transfer_time") == 1
+    parts = sum(_delta(keyed, part, "total") for part in LINE_STAGES)
+    assert 0 < parts <= _delta(keyed, "sig/transfer_time", "total") + 2e-5
+    assert _delta(keyed, "jax/pk_device_cache/misses") == 4
+    assert _delta(keyed, "jax/wire/g2_bytes") > 0
 
 
 def test_tracer_off_records_no_span_and_stage_still_feeds_its_timer(
@@ -252,8 +311,20 @@ def test_the_chain_of_parents_runs_from_the_client_to_the_stages(traced):
         assert parent(name) == "jax/bls_committee_dispatch"
 
 
-def test_every_child_lies_inside_its_parents_interval(traced):
-    _, mine = _request_trace(traced)
+def test_the_line_stages_are_spans_under_the_transfer_stage(keyed_traced):
+    _, mine = _request_trace(keyed_traced)
+    by_id = {s["span"]: s for s in mine}
+    one = {s["name"]: s for s in mine}
+    for name in LINE_STAGES:
+        assert [s["name"] for s in mine].count(name) == 1
+        assert by_id[one[name]["parent"]]["name"] == "sig/transfer_time"
+    tags = one["jax/bls_committee_dispatch"]["tags"]
+    assert (tags["line_miss_rows"], tags["line_hit_rows"]) == (4, 0)
+
+
+@pytest.mark.parametrize("caller", ["traced", "keyed_traced"])
+def test_every_child_lies_inside_its_parents_interval(request, caller):
+    _, mine = _request_trace(request.getfixturevalue(caller))
     by_id = {s["span"]: s for s in mine}
     checked = 0
     for span in mine:
@@ -276,6 +347,7 @@ def test_every_child_lies_inside_its_parents_interval(traced):
 
 @pytest.mark.parametrize("caller, root, spans", [
     ("traced", "rpc/client/shard_verifyCommittees", 20),
+    ("keyed_traced", "rpc/client/shard_verifyCommittees", 22),
     ("traced_server_alone", RPC + "server_time", 16)])
 def test_the_self_times_of_a_real_request_add_up_to_its_root(
         request, caller, root, spans):
@@ -292,7 +364,7 @@ def test_the_self_times_of_a_real_request_add_up_to_its_root(
     # the wake overhangs its request (critical_path's docstring), the
     # server's last clock read its roundtrip
     assert attr["total_s"] - 1e-6 <= booked <= 1.02 * attr["total_s"] + 0.002
-    if caller == "traced":
+    if caller != "traced_server_alone":
         assert attr["segments"]["wire"] < 0.5 * attr["total_s"]
 
 
@@ -382,7 +454,10 @@ with open(os.path.join(REPO, "BENCHMARK.json")) as _src:
 
 @pytest.mark.parametrize("name", PER_LAYER)
 def test_every_per_layer_metric_reads_a_number_from_two_snapshots(
-        untraced, name):
+        request, name):
+    # what only a line-table miss writes is read over the keyed request
+    snap = request.getfixturevalue(
+        "keyed" if name.startswith("line_") else "untraced")
     bench = os.path.join(REPO, "benchmark")
     if bench not in sys.path:
         sys.path.insert(0, bench)
@@ -392,10 +467,12 @@ def test_every_per_layer_metric_reads_a_number_from_two_snapshots(
     assert spec["name"] == name
     # the device trace is the chip's; its one metric reads this stand-in
     trace = {"busy_s": 0.001, "counts": {f"serving/{OP}/dispatches": 1}}
-    value = run.layer_metric(spec, OP, untraced["before"], untraced["after"],
-                             client_mean_ms=1e3 * untraced["latency_s"],
+    value = run.layer_metric(spec, OP, snap["before"], snap["after"],
+                             client_mean_ms=1e3 * snap["latency_s"],
                              trace=trace)
     assert isinstance(value, (int, float)) and value >= 0.0, (name, value)
+    if name == "line_miss_rows":
+        assert value == 4.0
 
 
 # == the kernels' names =====================================================
