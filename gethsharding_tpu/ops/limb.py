@@ -179,20 +179,43 @@ if NORM_IMPL == "relaxed" and LIMB_FORM != "wide":
                      "GETHSHARDING_TPU_LIMB_FORM=wide (the exact 22-limb "
                      "ladder depends on canonical mid-stage limbs)")
 
-# The schoolbook column sum z[n] = sum_{l+m=n} x_l·y_m has four
-# implementations ($GETHSHARDING_TPU_CONV):
-# - "shift" (default): pad each row with L zeros, flatten, re-view at
-#   width M+L-1 — element (l, m) then sits at column l+m exactly — and
-#   sum rows. FOUR flat ops, working set ~2x the product tensor; wins
-#   on both the latency-bound pairing and the bandwidth-bound
-#   aggregation tree.
+# The schoolbook column sum z[n] = sum_{l+m=n} x_l·y_m has five
+# implementations ($GETHSHARDING_TPU_CONV). Two rankings exist and they
+# disagree, so each says whose it is. THE CHIP'S (one v5e, PR 29,
+# PERF.md section 6; in-process, ms for 16 table-fed Miller steps
+# (fp12 square + two line multiplies) at 56 / 112 / 1 rows, then the
+# masked G2 tree over 112 x 144 and 1 x 144 points):
+#   shift (this form)   5.2 /  5.1 / 1.59    17.1 /  2.06
+#   slices              5.2 /  5.2 / 7.87    14.2 / 12.62
+#   mxu8                7.2 /  7.1 / 1.52    22.1 /  1.97
+#   shift before PR 29 20.3 / 14.3 / 1.50    40.3 /  2.21
+# and in the benchmark's cells the period audit's device time fell
+# from 1,136 to 229 ms keyed and from 374 to 163 ms keyless. A CPU's
+# (r2, XLA:CPU): `slices` had the best dispatch and the heaviest
+# compile, `gather` and `onehot` lost; nothing below is a chip's
+# finding unless it says so.
+# - "shift" (default): row l of the product is padded with l zeros
+#   below and L-1-l above, and the L rows are added. Static pads, so
+#   XLA fuses slice, pad and add into one pass over the product. Until
+#   PR 29 this name held the re-viewing form (pad each row with L
+#   zeros, flatten, re-view at width M+L-1, sum rows): four graph
+#   nodes, but its two reshapes change the minor dimension of a tiled
+#   array, which on the chip re-lays every word of the padded product:
+#   507 ms of `reshape` a keyed period audit, 261 ms of `reduce_sum` +
+#   `pad` + `slice` a keyless one. The padded-row sum costs 74 nodes a
+#   product where that cost 4: tracing and lowering a pairing kernel
+#   takes up to a fifth longer on a CPU and its first verdict from a
+#   warm compile cache 14-27% longer on the chip's host (PERF.md
+#   section 6).
 # - "gather": a static gather aligns prod row l to an l-shifted view,
 #   then sums rows. Few graph nodes but materializes an (..., L, L+M-1)
 #   intermediate — ~L× the product tensor — catastrophically
 #   memory-bound on big batches (the r2 CPU bench regression).
 # - "slices": accumulate row l into out[l : l+M] with L static
 #   slice-adds — minimal working set (best dispatch on XLA:CPU), but L
-#   graph nodes per conv (heaviest compile).
+#   graph nodes per conv (heaviest compile). On the chip each of the L
+#   updates is an operation of its own: level with "shift" at 56-112
+#   rows, five times behind it at one row, where depth sets the pace.
 # - "onehot": contract the (..., L, M) product planes against a constant
 #   (L, M, L+M-1) one-hot via einsum. XLA lowers this to a DENSE integer
 #   matmul doing (L+M-1)× redundant multiply-accumulates on the VPU
@@ -204,7 +227,8 @@ if NORM_IMPL == "relaxed" and LIMB_FORM != "wide":
 #   layer is gfp_amd64.s scalar asm; this is the systolic-array answer).
 #   The column ACCUMULATION rides the MXU; the products stay on the VPU.
 #   Requires non-negative product entries (true for every limb-product
-#   call site: products of canonical <2^12 limbs).
+#   call site: products of canonical <2^12 limbs). On the chip 30-40%
+#   behind "shift" at 56-112 rows and 4% ahead at one row.
 CONV_IMPL = os.environ.get("GETHSHARDING_TPU_CONV", "shift")
 if CONV_IMPL not in ("shift", "slices", "gather", "onehot", "mxu8"):
     raise ValueError(f"GETHSHARDING_TPU_CONV must be 'shift', 'slices', "
@@ -254,14 +278,17 @@ def conv_cols(prod: jnp.ndarray, impl: "str | None" = None) -> jnp.ndarray:
             out = out.at[..., l:l + M].add(prod[..., l, :])
         return out
     if impl == "shift":
-        # row-major layout: (l, m) of the (..., L, M+L) padded rows sits
-        # at flat position l·(M+L) + m = l·(M+L-1) + (l+m); re-viewing at
-        # width M+L-1 makes the column index exactly n = l+m (always
-        # < M+L-1), so a row-sum IS the anti-diagonal sum.
-        batch = prod.shape[:-2]
-        padded = jnp.pad(prod, [(0, 0)] * (prod.ndim - 2) + [(0, 0), (0, L)])
-        flat = padded.reshape(batch + (L * (M + L),))[..., :L * (M + L - 1)]
-        return flat.reshape(batch + (L, M + L - 1)).sum(axis=-2)
+        # row l belongs to columns l .. l+M-1. lax.pad and not jnp.pad:
+        # a pairing kernel traces ~10^3 products, and jnp.pad's Python
+        # made that a quarter slower (33.9 s against 26.7 on XLA:CPU).
+        zero = jnp.zeros((), prod.dtype)
+        lead = [(0, 0, 0)] * (prod.ndim - 2)
+        out = None
+        for l in range(L):
+            row = lax.pad(lax.index_in_dim(prod, l, prod.ndim - 2, False),
+                          zero, lead + [(l, L - 1 - l, 0)])
+            out = row if out is None else out + row
+        return out
     prod_p = jnp.pad(prod, [(0, 0)] * (prod.ndim - 1) + [(0, 1)])
     idx = _conv_gather_idx(L, M)  # (L, ncols) static
     rows = jnp.take_along_axis(

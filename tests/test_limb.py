@@ -153,30 +153,29 @@ def test_assoc_carry_impl_matches_scan(monkeypatch):
     assert [int(v) for v in got_unroll] == expect_sub
 
 
-def test_conv_impls_agree():
+_CONV_SMALL = [(22, 22), (25, 49), (3, 7), (1, 5), (22, 43)]
+
+
+def _shape_id(shape):
+    return "x".join(map(str, shape))
+
+
+@pytest.mark.parametrize("shape", _CONV_SMALL, ids=_shape_id)
+@pytest.mark.parametrize("impl", ["shift", "slices", "gather", "mxu8"])
+def test_conv_impls_agree(impl, shape):
     """Every conv_cols implementation computes the same anti-diagonal
     sums (the autotune sweep may deploy any of them)."""
-    import numpy as np
-    import jax.numpy as jnp
-
-    from gethsharding_tpu.ops import limb
-
-    rng = np.random.default_rng(7)
-    for L, M in [(22, 22), (25, 49), (3, 7), (1, 5), (22, 43)]:
-        prod = rng.integers(-2**20, 2**20, size=(2, 3, L, M),
-                            dtype=np.int64).astype(np.int32)
-        want = limb.conv_cols(jnp.asarray(prod), impl="onehot")
-        for impl in ("shift", "slices", "gather"):
-            got = limb.conv_cols(jnp.asarray(prod), impl=impl)
-            assert np.array_equal(np.asarray(got), np.asarray(want)), (
-                L, M, impl)
+    L, M = shape
+    rng = np.random.default_rng(7 + 100 * L + M)
+    prod = rng.integers(-2**20, 2**20, size=(2, 3, L, M),
+                        dtype=np.int64).astype(np.int32)
+    if impl == "mxu8":
         # mxu8's int8-plane split assumes non-negative entries (the
         # limb-product contract: products of canonical <2^12 limbs)
-        pos = np.abs(prod)
-        want_pos = limb.conv_cols(jnp.asarray(pos), impl="shift")
-        got_pos = limb.conv_cols(jnp.asarray(pos), impl="mxu8")
-        assert np.array_equal(np.asarray(got_pos), np.asarray(want_pos)), (
-            L, M, "mxu8")
+        prod = np.abs(prod)
+    want = limb.conv_cols(jnp.asarray(prod), impl="onehot")
+    got = limb.conv_cols(jnp.asarray(prod), impl=impl)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_relaxed_norm_matches_exact(monkeypatch):

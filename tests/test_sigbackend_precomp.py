@@ -173,6 +173,43 @@ def test_randomized_precomp_parity_sync_async(monkeypatch, wire):
         assert backend.last_wire["precomp"] is True
 
 
+def _forged_empty_ragged_round():
+    """One batch inside the suite's compile bucket (4 rows, width <= 4)
+    with the three rows a period audit must not get wrong: a forged
+    signature under honest keys, an empty committee, and ragged rows
+    (1, 2 and 3 votes) whose pad slots the masks must hide."""
+    def row(tag, members, forge=False):
+        sigs = [bls.bls_sign(tag, KEYPOOL[i][0]) for i in members]
+        if forge:
+            sigs[-1] = bls.bls_sign(b"forged", KEYPOOL[members[-1]][0])
+        return (tag, sigs, [KEYPOOL[i][1] for i in members],
+                (tuple(members), "forged" if forge else "plain", ()))
+
+    rows = [row(b"fer-0", [0, 1, 2]), row(b"fer-1", [3, 4], forge=True),
+            (b"fer-2", [], [], None), row(b"fer-3", [5])]
+    return tuple(list(col) for col in zip(*rows))
+
+
+@pytest.mark.parametrize("path", ["recompute", "table_fed"])
+def test_forged_empty_ragged_rows_match_scalar(monkeypatch, path):
+    """Under the default limb product (the padded-row sum, PR 29) the
+    recompute kernel (no row keys sent) and the table-fed verify (row
+    keys sent) both return the scalar backend's verdicts on a forged,
+    an empty and three ragged rows: True, False, False, True."""
+    monkeypatch.delenv("GETHSHARDING_TPU_WIRE", raising=False)
+    monkeypatch.setenv("GETHSHARDING_PRECOMP", "1")
+    backend = JaxSigBackend()
+    msgs, sig_rows, pk_rows, keys = _forged_empty_ragged_round()
+    want = get_backend("python").bls_verify_committees(
+        msgs, sig_rows, pk_rows)
+    assert want == [True, False, False, True]
+    sent = keys if path == "table_fed" else None
+    got = backend.bls_verify_committees(msgs, sig_rows, pk_rows,
+                                        pk_row_keys=sent)
+    assert got == want
+    assert backend.last_wire["precomp"] is (path == "table_fed")
+
+
 def test_warm_line_tables_ship_zero_g2_bytes():
     """The steady-state precomp shape: cold pays ONE precompute
     dispatch and ships the miss rows' pk planes; warm ships ZERO G2
