@@ -516,10 +516,7 @@ def kernel_child(rehearsal: bool) -> int:
 
     from gethsharding_tpu.crypto import bn256 as ref
     from gethsharding_tpu.ops import bn256_jax as k
-    from gethsharding_tpu.ops import limb
     from gethsharding_tpu.ops import pallas_finalexp as mega
-    from gethsharding_tpu.ops.pallas_conv import pair_conv_combine
-    from gethsharding_tpu.ops.pallas_norm import BLOCK_ROWS, normalize_pallas
 
     interpret = rehearsal  # compiled on the chip, interpreted on the CPU
     # three committee chunks of the aggregation kernel's block (its block
@@ -538,36 +535,6 @@ def kernel_child(rehearsal: bool) -> int:
     g2 = tuple(map(jnp.asarray,
                    k.g2_committee_to_limbs([pks, pks], width)))
     hx, hy = jnp.asarray(hx), jnp.asarray(hy)
-    rng = np.random.default_rng(41)
-
-    def norm():
-        arith = limb.ModArith(ref.P)
-        z = jnp.asarray(rng.integers(0, 1 << 28, (BLOCK_ROWS, 49),
-                                     dtype=np.int32))
-        want = jax.jit(arith.normalize)(z)
-        got = normalize_pallas(arith, z, interpret=interpret)
-        return bool((np.asarray(want) == np.asarray(got)).all())
-
-    def conv():
-        ok = True
-        for comb in (k._COMB, k._LCOMB):
-            groups, a, b, _, _ = comb.shape
-            x = jnp.asarray(rng.integers(
-                0, 1 << 12, (8, groups, a, limb.NLIMBS), dtype=np.int32))
-            y = jnp.asarray(rng.integers(
-                0, 1 << 12, (8, groups, b, limb.NLIMBS), dtype=np.int32))
-
-            def twin(x, y, comb=comb):
-                prod = (x[..., :, :, None, :, None]
-                        * y[..., :, None, :, None, :])
-                return jnp.einsum("...iabn,iabcg->...cgn",
-                                  limb.conv_cols(prod), jnp.asarray(comb))
-
-            want = jax.jit(twin)(x, y)
-            got = pair_conv_combine(x, y, comb, interpret=interpret)
-            ok = ok and bool((np.asarray(want) == np.asarray(got)).all())
-        return ok
-
     agg_g1_twin = jax.jit(k.aggregate_g1_proj)
     agg_g2_twin = jax.jit(k.aggregate_g2_proj)
 
@@ -621,8 +588,6 @@ def kernel_child(rehearsal: bool) -> int:
             return want == got == [True, False]
 
         futures = {
-            "pallas_norm": timed(norm),
-            "pallas_conv": timed(conv),
             "aggregation G1": timed(agg_g1),
             "aggregation G2": timed(agg_g2),
             "Miller loop": timed(miller),

@@ -553,8 +553,7 @@ class Notary(Service):
         runs while period N executes on device. Verdicts are identical;
         pick batched for a latency-bound kernel (fewer dispatches),
         overlapped when host marshalling is the bottleneck or verdicts
-        should stream per period (``bench.py --overlap`` measures the
-        ratio).
+        should stream per period.
         """
         periods = list(periods)
         collected = {p: self._collect_audit_rows(p) for p in periods}

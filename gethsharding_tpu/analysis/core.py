@@ -121,14 +121,14 @@ class Corpus:
     """The parsed source tree the rules run over.
 
     ``root`` is the repo root; ``files`` covers every ``*.py`` under the
-    scanned subtrees. Non-AST inputs the rules need (README.md, bench.py,
-    chip_smoke.py, scripts/) are reachable through ``root``.
+    scanned subtrees. Non-AST inputs the rules need (README.md,
+    chip_smoke.py, scripts/, benchmark/) are reachable through ``root``.
     """
 
     # subtrees scanned for AST rules, relative to root
     DEFAULT_SUBTREES = ("gethsharding_tpu",)
     # extra single files / trees the flag rules also read for env knobs
-    DEFAULT_EXTRA = ("bench.py", "chip_smoke.py", "scripts")
+    DEFAULT_EXTRA = ("chip_smoke.py", "scripts", "benchmark")
 
     def __init__(self, root: Path, files: Sequence[SourceFile],
                  extra_files: Sequence[SourceFile] = ()):

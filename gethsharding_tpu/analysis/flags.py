@@ -8,12 +8,12 @@ mechanically.
 
 Code side:
 - env vars: every string literal (and f-string skeleton) shaped
-  `GETHSHARDING_[A-Z0-9_]*` anywhere in the package, bench.py,
-  chip_smoke.py and scripts/ — call args, dict keys, comparisons — EXCEPT docstrings.
+  `GETHSHARDING_[A-Z0-9_]*` anywhere in the package, chip_smoke.py,
+  scripts/ and benchmark/ — call args, dict keys, comparisons — EXCEPT docstrings.
   Dynamic names (`f"GETHSHARDING_CLASS_{op}"`) become skeletons with
   `*` at the formatted holes.
 - CLI flags: `add_argument("--…")` literals. Flags of the package CLIs
-  (gethsharding_tpu/**) must be documented; bench.py/scripts flags only
+  (gethsharding_tpu/**) must be documented; the other files' flags only
   feed the stale-doc direction (internal tools may keep private knobs).
 
 Doc side (README.md): `GETHSHARDING_…` tokens anywhere (placeholders
@@ -87,9 +87,6 @@ def _code_env_tokens(corpus: Corpus) -> Dict[str, Tuple[str, int]]:
     return out
 
 
-_FLAG_LIT_RE = re.compile(r"^--[a-z0-9][a-z0-9-]*$")
-
-
 def _code_flag_tokens(corpus: Corpus, package_only: bool) -> \
         Dict[str, Tuple[str, int]]:
     out: Dict[str, Tuple[str, int]] = {}
@@ -107,12 +104,6 @@ def _code_flag_tokens(corpus: Corpus, package_only: bool) -> \
                             isinstance(arg.value, str) and \
                             arg.value.startswith("--"):
                         out.setdefault(arg.value, (sf.rel, node.lineno))
-            elif not package_only and isinstance(node, ast.Constant) and \
-                    isinstance(node.value, str) and \
-                    _FLAG_LIT_RE.match(node.value):
-                # hand-rolled `"--das" in sys.argv` parsing (bench.py):
-                # counts as a defined flag for the stale-doc direction
-                out.setdefault(node.value, (sf.rel, node.lineno))
     return out
 
 

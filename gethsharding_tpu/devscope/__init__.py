@@ -23,8 +23,8 @@ go:
   stacks downloadable from ``/profile/stacks``.
 
 Surfaces: the ``devscope`` section on ``/status`` (`devscope_status`),
-``devscope/*`` rows on /metrics + the Prometheus exposition, and the
-``bench.py --devscope`` closed-loop acceptance run. ``boot()`` is the
+``devscope/*`` rows on /metrics + the Prometheus exposition
+(tests/test_devscope.py holds the acceptance assertions). ``boot()`` is the
 node/chain_server entry: start the background poller (off with
 ``GETHSHARDING_DEVSCOPE=0``) and return it.
 """
@@ -129,7 +129,7 @@ def ledger_fields() -> dict:
     baselines). Reads the device stats on demand (`observe_peaks` — no
     census, no gauges, no near-OOM side effects from inside the ledger
     writer) so a record written between two background ticks (or in a
-    process that booted with the thread off, like bench.py) still
+    process that booted with the thread off) still
     observes the device state it just measured."""
     mem = poller()
     peak = 0

@@ -20,7 +20,7 @@ bounded so leaving one on cannot fill a disk:
   folding every thread's stack into flamegraph-style collapsed lines
   (``frame;frame;frame count``) under a bounded unique-stack budget.
   ``/profile/stacks`` serves the text (feed it to any flamegraph
-  tool or ``scripts/tpu_breakdown.py --stacks``); a bounded ring of
+  tool); a bounded ring of
   raw samples exports as Chrome trace events with the same
   ``clock_offset_us`` wall anchor as ``tracing.write_chrome_trace``,
   so ``scripts/trace_merge.py`` folds device spans and host samples

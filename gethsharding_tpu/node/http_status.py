@@ -234,8 +234,7 @@ class StatusServer(Service):
                     return
                 if path == "/profile/stacks":
                     # the sampling profiler's collapsed stacks as plain
-                    # text: feed to a flamegraph tool or
-                    # scripts/tpu_breakdown.py --stacks
+                    # text: feed to a flamegraph tool
                     from gethsharding_tpu.devscope import PROFILER
 
                     try:

@@ -60,73 +60,27 @@ FP = ModArith(P)
 # products needs a multiple of p ≥ 2^547.
 _PAD530 = FP.pad_mult(2 * _limb.LAZY_BITS + 1)  # ≥ two subtracted products
 
-# GETHSHARDING_TPU_PAIRCONV=pallas routes the product-convolution+combine
-# of every Fp2/Fp12 multiply through the fused Pallas kernel
-# (ops/pallas_conv.py) on accelerator backends — the (..., G, 2, 2, NL,
-# NL) product tensor then never round-trips through HBM. Off by default;
-# bench.py probes it as an autotune config.
-PAIRCONV = os.environ.get("GETHSHARDING_TPU_PAIRCONV", "xla")
-if PAIRCONV not in ("xla", "pallas"):
-    raise ValueError(f"GETHSHARDING_TPU_PAIRCONV must be 'xla' or "
-                     f"'pallas', got {PAIRCONV!r}")
-
-# GETHSHARDING_TPU_PAIR_UNROLL=1 statically unrolls the three sequential
-# drivers of the pairing check — the Miller loop, x^u square-multiply
-# ladders and the final-exp hard-part register machine — into python
-# loops over their compile-time programs. This removes every lax.scan /
-# lax.cond / lax.switch / dynamic_index from the hot path, letting XLA
-# fuse across steps, and skips the dead work the traced form pays for
-# (both sides of every branchless select; muls on zero exponent bits).
-# The price is HLO size and compile time (~hundreds of fp12-op bodies
-# inlined; >35 min on XLA:CPU), so it is an autotune knob, not the
-# default. =finalexp unrolls ONLY the final-exponentiation drivers (the
-# ladders + hard part: ~66% of the dispatch, ~half the inlined HLO) and
-# keeps the Miller scan — the compile-cost hedge.
-_PAIR_UNROLL_RAW = os.environ.get("GETHSHARDING_TPU_PAIR_UNROLL", "0")
-if _PAIR_UNROLL_RAW not in ("0", "1", "finalexp"):
-    raise ValueError(f"GETHSHARDING_TPU_PAIR_UNROLL must be '0', '1' or "
-                     f"'finalexp', got {_PAIR_UNROLL_RAW!r}")
-PAIR_UNROLL = _PAIR_UNROLL_RAW == "1"            # miller drivers
-FE_UNROLL = _PAIR_UNROLL_RAW in ("1", "finalexp")  # ladders + hard part
-
-# GETHSHARDING_TPU_SCAN_UNROLL=N is the bounded middle ground: keep the
-# lax.scan drivers but let XLA unroll N steps per While iteration
-# (cross-step fusion with ~N× instead of ~90× HLO growth). Ignored when
-# PAIR_UNROLL=1.
-SCAN_UNROLL = int(os.environ.get("GETHSHARDING_TPU_SCAN_UNROLL", "1"))
-
 # GETHSHARDING_TPU_FINALEXP=mega routes the ENTIRE fraction-stacked final
 # exponentiation (easy part, x^u ladders, hard part — ~250 sequential
 # fp12 ops) through the single-dispatch Pallas mega-kernel
 # (ops/pallas_finalexp.py): one kernel launch, VMEM-resident register
 # file, zero HBM round-trips between steps. The kernel's arithmetic is
-# self-contained wide/relaxed, so the knob composes with any limb-form
-# config; it conflicts only with PAIR_UNROLL's finalexp unrolls (both
-# claim the same stage — a silent override would mislabel autotune
-# results, same policy as PALLAS×NORM in ops/limb.py).
+# self-contained wide/relaxed, so the knob composes with either limb
+# form.
 FINALEXP = os.environ.get("GETHSHARDING_TPU_FINALEXP", "xla")
 if FINALEXP not in ("xla", "mega"):
     raise ValueError(f"GETHSHARDING_TPU_FINALEXP must be 'xla' or 'mega', "
                      f"got {FINALEXP!r}")
-if FINALEXP == "mega" and FE_UNROLL:
-    raise ValueError("GETHSHARDING_TPU_FINALEXP=mega and "
-                     "GETHSHARDING_TPU_PAIR_UNROLL both rewrite the final "
-                     "exponentiation; set one")
 
 # GETHSHARDING_TPU_MILLER=mega routes the PROJECTIVE shared-accumulator
 # Miller walk (the BLS committee-verify hot path) through its own
 # single-launch Pallas register machine (ops/pallas_finalexp.miller_f).
 # With both knobs mega, the whole post-aggregation pairing check runs in
-# TWO kernel launches. Same conflict rule vs PAIR_UNROLL (which inlines
-# the Miller drivers).
+# TWO kernel launches.
 MILLER = os.environ.get("GETHSHARDING_TPU_MILLER", "xla")
 if MILLER not in ("xla", "mega"):
     raise ValueError(f"GETHSHARDING_TPU_MILLER must be 'xla' or 'mega', "
                      f"got {MILLER!r}")
-if MILLER == "mega" and PAIR_UNROLL:
-    raise ValueError("GETHSHARDING_TPU_MILLER=mega and "
-                     "GETHSHARDING_TPU_PAIR_UNROLL=1 both rewrite the "
-                     "Miller loop; set one")
 
 # GETHSHARDING_TPU_AGG=mega routes the masked committee tree reductions
 # through the single-launch aggregation kernels (ops/pallas_finalexp.
@@ -138,19 +92,10 @@ if AGG not in ("xla", "mega"):
                      f"got {AGG!r}")
 
 
-def _use_pallas_conv() -> bool:
-    return PAIRCONV == "pallas" and _limb._pallas_wanted()
-
-
 def _pair_conv_combine(x, y, comb: np.ndarray) -> jnp.ndarray:
     """cols[..., i, a, b, n] = sum_{l+m=n} x[i,a,l]·y[i,b,m], contracted
     against the static combine tensor -> (..., C, Gr, 2·NL-1) raw column
-    accumulators. One fused Pallas kernel on TPU, broadcast-multiply +
-    conv_cols + einsum under XLA."""
-    if _use_pallas_conv():
-        from gethsharding_tpu.ops.pallas_conv import pair_conv_combine
-
-        return pair_conv_combine(x, y, comb)
+    accumulators: broadcast-multiply + conv_cols + einsum."""
     prod = x[..., :, :, None, :, None] * y[..., :, None, :, None, :]
     cols = _limb.conv_cols(prod)
     return jnp.einsum("...iabn,iabcg->...cgn", cols, jnp.asarray(comb))
@@ -186,18 +131,13 @@ def fp2_neg(x):
     return FP.neg(x)
 
 
-# combine tensors for the (a+bi)(c+di) product planes: re = ac - bd,
-# im = ad + bc; the square variant folds im into ONE plane with coef 2
-# (conv(a,b) == conv(b,a)), so the fused kernel skips a whole plane
+# combine tensor for the (a+bi)(c+di) product planes: re = ac - bd,
+# im = ad + bc
 _COMB_FP2 = np.zeros((1, 2, 2, 2, 1), np.int32)
 _COMB_FP2[0, 0, 0, 0, 0] = 1
 _COMB_FP2[0, 1, 1, 0, 0] = -1
 _COMB_FP2[0, 0, 1, 1, 0] = 1
 _COMB_FP2[0, 1, 0, 1, 0] = 1
-_COMB_FP2_SQR = np.zeros((1, 2, 2, 2, 1), np.int32)
-_COMB_FP2_SQR[0, 0, 0, 0, 0] = 1
-_COMB_FP2_SQR[0, 1, 1, 0, 0] = -1
-_COMB_FP2_SQR[0, 0, 1, 1, 0] = 2
 
 _FP2_W = max(2 * NLIMBS - 1, _PAD530.shape[0])
 _FP2_PAD = np.zeros((2, _FP2_W), np.int32)  # pad only the subtracting re
@@ -214,10 +154,6 @@ def fp2_mul(x, y):
 
 @jax.jit
 def fp2_sqr(x):
-    if _use_pallas_conv():
-        acc = _pair_conv_combine(x[..., None, :, :], x[..., None, :, :],
-                                 _COMB_FP2_SQR)[..., 0, :]
-        return FP.normalize(_pad_to(acc, _FP2_W) + jnp.asarray(_FP2_PAD))
     a, b = x[..., 0, :], x[..., 1, :]
     rr = _red_sub(FP.mul_cols(a, a), FP.mul_cols(b, b))
     ii = _red(FP.mul_cols(a, b) * 2)
@@ -572,16 +508,6 @@ def miller_loop(px, py, qx, qy):
     # normalize broadcasts into concrete arrays for scan carry stability
     f, X, Y, Z = map(FP.normalize, (f, X, Y, Z))
 
-    if PAIR_UNROLL:
-        # static double-and-add: zero bits skip the chord entirely
-        for bit in ATE_BITS:
-            line, X, Y, Z = _dbl_step(X, Y, Z, px, py)
-            f = fp12_mul_line(fp12_sqr(f), line)
-            if bit:
-                line, X, Y, Z = _madd_step(X, Y, Z, qx, qy, px, py)
-                f = fp12_mul_line(f, line)
-        return f
-
     def step(carry, bit):
         f, X, Y, Z = carry
         line, X, Y, Z = _dbl_step(X, Y, Z, px, py)
@@ -593,8 +519,7 @@ def miller_loop(px, py, qx, qy):
         sel = lambda a, b: jnp.where(take[..., None, None], a, b)
         return (f, sel(Xa, X), sel(Ya, Y), sel(Za, Z)), None
 
-    (f, X, Y, Z), _ = lax.scan(step, (f, X, Y, Z), jnp.asarray(ATE_BITS),
-                               unroll=SCAN_UNROLL)
+    (f, X, Y, Z), _ = lax.scan(step, (f, X, Y, Z), jnp.asarray(ATE_BITS))
     return f
 
 
@@ -647,18 +572,6 @@ _U_NAF = np.asarray(ref._naf(U), np.int32)  # little-endian digits of u
 
 def _pow_u(x):
     """x^u (u = BN parameter, 63 static bits) via square-multiply scan."""
-    if FE_UNROLL:
-        # static ladder: zero bits cost nothing beyond the squaring, and
-        # the first set bit initializes the accumulator (no select pairs)
-        acc = None
-        base = x
-        for i, bit in enumerate(_U_BITS):
-            if bit:
-                acc = base if acc is None else fp12_mul(acc, base)
-            if i + 1 < len(_U_BITS):
-                base = fp12_sqr(base)
-        return acc  # u > 0, so at least one bit set
-
     def step(carry, bit):
         acc, base = carry
         take = jnp.broadcast_to(bit == 1, acc.shape[:-3])
@@ -667,8 +580,7 @@ def _pow_u(x):
 
     acc0 = FP.normalize(
         jnp.broadcast_to(jnp.asarray(FP12_ONE), x.shape) + x * 0)
-    (acc, _), _ = lax.scan(step, (acc0, x), jnp.asarray(_U_BITS),
-                           unroll=SCAN_UNROLL)
+    (acc, _), _ = lax.scan(step, (acc0, x), jnp.asarray(_U_BITS))
     return acc
 
 
@@ -676,25 +588,6 @@ def _run_hard_part(f, pow_u_fn, inv_fn):
     """The DSD hard-part register machine (see _HARD_PROGRAM), shared by
     the value path (inverse = cyclotomic conjugate) and the fraction path
     (inverse = component swap)."""
-    if FE_UNROLL:
-        # static register machine: python list, compile-time indices, the
-        # six ops dispatched at trace time — no switch, no dynamic slots
-        fu = pow_u_fn(f)
-        fu2 = pow_u_fn(fu)
-        slots: list = [f, fu, fu2, pow_u_fn(fu2)] + [None] * (_N_REGS - 4)
-        for op, a, b, d in _HARD_PROGRAM:
-            ra, rb = slots[a], slots[b]
-            if op == 0:
-                out = fp12_mul(ra, rb)
-            elif op == 1:
-                out = fp12_sqr(ra)
-            elif op == 2:
-                out = inv_fn(ra)
-            else:
-                out = fp12_frobenius(ra, int(op) - 2)
-            slots[d] = out
-        return slots[13]
-
     regs = jnp.broadcast_to(
         jnp.asarray(FP12_ONE), (_N_REGS,) + f.shape).astype(jnp.int32) + f * 0
     regs = FP.normalize(regs)
@@ -719,8 +612,7 @@ def _run_hard_part(f, pow_u_fn, inv_fn):
         ], ra, rb)
         return lax.dynamic_update_index_in_dim(regs, out, d, axis=0), None
 
-    regs, _ = lax.scan(step, regs, jnp.asarray(_HARD_PROGRAM),
-                       unroll=SCAN_UNROLL)
+    regs, _ = lax.scan(step, regs, jnp.asarray(_HARD_PROGRAM))
     return regs[13]
 
 
@@ -754,16 +646,6 @@ def _pow_u_fraction(x):
     xswap = x[::-1]
     digits = list(reversed(_U_NAF[:-1]))
 
-    if FE_UNROLL:
-        acc = x  # top digit
-        for d in digits:
-            acc = fp12_sqr(acc)
-            if d == 1:
-                acc = fp12_mul(acc, x)
-            elif d == -1:
-                acc = fp12_mul(acc, xswap)
-        return acc
-
     def step(acc, d):
         acc = fp12_sqr(acc)
         acc = lax.switch(d + 1, [
@@ -774,8 +656,7 @@ def _pow_u_fraction(x):
         return acc, None
 
     acc, _ = lax.scan(step, x,
-                      jnp.asarray(np.asarray(digits, np.int32)),
-                      unroll=SCAN_UNROLL)
+                      jnp.asarray(np.asarray(digits, np.int32)))
     return acc
 
 
@@ -1043,28 +924,6 @@ def _bls_miller_opt(sig, hx, hy, pk):
         f = fp12_mul_line(f, line1)
         return f, X, Y, Z
 
-    def add_branch_static(f, X, Y, Z, line_c, op):
-        idx = op - 1  # compile-time candidate choice
-        if affine:
-            line1, X, Y, Z = _madd_step(X, Y, Z, cand[0][idx], cand[1][idx],
-                                        hx, hy_neg)
-        else:
-            line1, X, Y, Z = _jadd_step(X, Y, Z,
-                                        tuple(c[idx] for c in cand),
-                                        hx, hy_neg)
-        f = fp12_mul_line(f, gen_line(line_c))
-        f = fp12_mul_line(f, line1)
-        return f, X, Y, Z
-
-    if PAIR_UNROLL:
-        for i, op in enumerate(_OPT_OPS):
-            line_c = jnp.asarray(_GEN_LINES[i])
-            if op == 0:
-                f, X, Y, Z = dbl_branch(f, X, Y, Z, line_c, op)
-            else:
-                f, X, Y, Z = add_branch_static(f, X, Y, Z, line_c, int(op))
-        return f
-
     def step(carry, xs):
         op, line_c = xs
         f, X, Y, Z = carry
@@ -1074,8 +933,7 @@ def _bls_miller_opt(sig, hx, hy, pk):
 
     (f, X, Y, Z), _ = lax.scan(
         step, (f, X, Y, Z),
-        (jnp.asarray(_OPT_OPS), jnp.asarray(_GEN_LINES)),
-        unroll=SCAN_UNROLL)
+        (jnp.asarray(_OPT_OPS), jnp.asarray(_GEN_LINES)))
     return f
 
 
@@ -1125,35 +983,11 @@ def _proj_add_impl(x1, y1, z1, x2, y2, z2, mul_many, add, sub, mul_b3):
     return sub(p1, p2), add(p3, p4), add(p5, p6)
 
 
-_MUL_MANY_COMBS: dict = {}
-
-
-def _mul_many_comb(n: int) -> np.ndarray:
-    """Identity combine (n,1,1,n,1): n independent Fp products through
-    the fused pair-conv kernel in ONE call."""
-    comb = _MUL_MANY_COMBS.get(n)
-    if comb is None:
-        comb = np.zeros((n, 1, 1, n, 1), np.int32)
-        for i in range(n):
-            comb[i, 0, 0, i, 0] = 1
-        _MUL_MANY_COMBS[n] = comb
-    return comb
-
-
 def _g1_proj_add(p1, p2):
     def mul_many(pairs):
         xs = jnp.stack([a for a, _ in pairs], axis=-2)
         ys = jnp.stack([b for _, b in pairs], axis=-2)
-        if _use_pallas_conv():
-            # the G1 aggregation tree is the committee pipeline's
-            # bandwidth hot spot: its stacked products ride the fused
-            # kernel too (identity combine), one normalize for all n
-            acc = _pair_conv_combine(xs[..., :, None, :],
-                                     ys[..., :, None, :],
-                                     _mul_many_comb(len(pairs)))
-            out = FP.normalize(acc[..., 0, :])
-        else:
-            out = FP.mul(xs, ys)
+        out = FP.mul(xs, ys)
         return [out[..., i, :] for i in range(len(pairs))]
 
     return _proj_add_impl(*p1, *p2, mul_many=mul_many, add=FP.add,
@@ -1308,7 +1142,7 @@ def bls_aggregate_verify_committee_batch(hx, hy, sigx, sigy, sig_mask,
 # stored coefficients are the EXACT limb arrays the recompute path feeds
 # to the same `fp2_mul_fp`/`fp12_mul_line` primitives in the same order,
 # so verdicts are bit-identical by construction (asserted against the
-# scalar twin in bench.py --precomp and tests/test_sigbackend_precomp.py).
+# scalar twin in tests/test_sigbackend_precomp.py).
 
 # line-coefficient table shape per batch element: one (c_py, c_px,
 # c_const) Fp2 triple per optimal-ate schedule step
@@ -1366,25 +1200,13 @@ def precompute_lines(pkx, pky, pkz):
             for c in cand)
         return _jadd_coeffs(X, Y, Z, q2)
 
-    if PAIR_UNROLL:
-        lines = []
-        for op in _OPT_OPS:
-            if op == 0:
-                coeffs, X, Y, Z = _dbl_coeffs(X, Y, Z)
-            else:
-                coeffs, X, Y, Z = _jadd_coeffs(
-                    X, Y, Z, tuple(c[int(op) - 1] for c in cand))
-            lines.append(jnp.stack(coeffs, axis=-3))
-        return jnp.stack(lines, axis=-4)
-
     def step(carry, op):
         X, Y, Z = carry
         coeffs, X, Y, Z = lax.cond(op == 0, dbl_branch, add_branch,
                                    X, Y, Z, op)
         return (X, Y, Z), jnp.stack(coeffs, axis=-3)
 
-    (X, Y, Z), lines = lax.scan(step, (X, Y, Z), jnp.asarray(_OPT_OPS),
-                                unroll=SCAN_UNROLL)
+    (X, Y, Z), lines = lax.scan(step, (X, Y, Z), jnp.asarray(_OPT_OPS))
     return jnp.moveaxis(lines, 0, -4)
 
 
@@ -1445,15 +1267,6 @@ def miller_loop_precomp(sig, hx, hy, table, gen_lines=None):
         C = tab_c[..., 2, :, :]
         return A, B, C
 
-    if PAIR_UNROLL:
-        tab = jnp.moveaxis(table, -4, 0)
-        for i, op in enumerate(_OPT_OPS):
-            if op == 0:
-                f = fp12_sqr(f)
-            f = fp12_mul_line(f, gen_line(gen_lines[i]))
-            f = fp12_mul_line(f, pk_line(tab[i]))
-        return f
-
     def step(f, xs):
         op, line_c, tab_c = xs
         f = lax.cond(op == 0, fp12_sqr, lambda v: v, f)
@@ -1463,8 +1276,7 @@ def miller_loop_precomp(sig, hx, hy, table, gen_lines=None):
 
     f, _ = lax.scan(
         step, f,
-        (jnp.asarray(_OPT_OPS), gen_lines, jnp.moveaxis(table, -4, 0)),
-        unroll=SCAN_UNROLL)
+        (jnp.asarray(_OPT_OPS), gen_lines, jnp.moveaxis(table, -4, 0)))
     return f
 
 

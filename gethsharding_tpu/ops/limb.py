@@ -47,8 +47,9 @@ LIMB_MASK = (1 << LIMB_BITS) - 1
 # - "wide" (default): 25-limb operands, value < 2^273, ONE exact carry per
 #   normalize — minimizes sequential depth (TPU latency).
 # - "exact": 22-limb operands, value < 2^264, three exact carries per
-#   normalize — minimizes schoolbook width (+29% fewer product FLOPs),
-#   better when throughput-bound. bench.py autotunes over both.
+#   normalize — minimizes schoolbook width (+29% fewer product FLOPs);
+#   the form the Pallas mega kernels (ops/pallas_finalexp.py) were last
+#   measured under (PERF.md section 6, PR 30).
 LIMB_FORM = os.environ.get("GETHSHARDING_TPU_LIMB_FORM", "wide")
 if LIMB_FORM == "wide":
     NLIMBS = 25    # operand width: 300 bits of capacity
@@ -149,243 +150,53 @@ def _relaxed_round(z: jnp.ndarray):
     return c[..., -1], lo + shifted
 
 
-CARRY_IMPL = os.environ.get("GETHSHARDING_TPU_CARRY", "scan")
-if CARRY_IMPL not in ("scan", "assoc", "unroll"):
-    raise ValueError(f"GETHSHARDING_TPU_CARRY must be 'scan', 'assoc' or "
-                     f"'unroll', got {CARRY_IMPL!r}")
-
-# GETHSHARDING_TPU_PALLAS=1 routes `ModArith.normalize` through the fused
-# Pallas kernel (ops/pallas_norm.py) on non-CPU backends — one VMEM-
-# resident kernel per normalize instead of an XLA op chain. Off by
-# default; bench.py probes it as an autotune config.
-PALLAS_NORM = os.environ.get("GETHSHARDING_TPU_PALLAS", "0") == "1"
-
-# GETHSHARDING_TPU_NORM=relaxed (wide form only) drops the exact carry
-# from `normalize` entirely: after the fold, FOUR value-preserving
-# relaxed rounds (the top carry is re-fused into the top limb, never
-# dropped) leave QUASI-canonical limbs — range [-1, 2^12 + 64] instead
-# of [0, 2^12). Every consumer's int32 column bound scales by at most
-# (1 + 2^-6)^2 ≈ 3.3%, inside the ≥23% headroom below 2^31 that the
-# canonical-limb proofs leave (4·25·(2^12-1)² < 2^30.7). What it buys:
-# the 25-step sequential ripple — the deepest dependency chain in every
-# field op — becomes ~16 flat vector ops. Incompatible with CONV=mxu8
-# (which requires non-negative product entries).
-NORM_IMPL = os.environ.get("GETHSHARDING_TPU_NORM", "exact")
-if NORM_IMPL not in ("exact", "relaxed"):
-    raise ValueError(f"GETHSHARDING_TPU_NORM must be 'exact' or 'relaxed', "
-                     f"got {NORM_IMPL!r}")
-if NORM_IMPL == "relaxed" and LIMB_FORM != "wide":
-    raise ValueError("GETHSHARDING_TPU_NORM=relaxed requires "
-                     "GETHSHARDING_TPU_LIMB_FORM=wide (the exact 22-limb "
-                     "ladder depends on canonical mid-stage limbs)")
-
-# The schoolbook column sum z[n] = sum_{l+m=n} x_l·y_m has five
-# implementations ($GETHSHARDING_TPU_CONV). Two rankings exist and they
-# disagree, so each says whose it is. THE CHIP'S (one v5e, PR 29,
-# PERF.md section 6; in-process, ms for 16 table-fed Miller steps
-# (fp12 square + two line multiplies) at 56 / 112 / 1 rows, then the
-# masked G2 tree over 112 x 144 and 1 x 144 points):
-#   shift (this form)   5.2 /  5.1 / 1.59    17.1 /  2.06
-#   slices              5.2 /  5.2 / 7.87    14.2 / 12.62
-#   mxu8                7.2 /  7.1 / 1.52    22.1 /  1.97
-#   shift before PR 29 20.3 / 14.3 / 1.50    40.3 /  2.21
-# and in the benchmark's cells the period audit's device time fell
-# from 1,136 to 229 ms keyed and from 374 to 163 ms keyless. A CPU's
-# (r2, XLA:CPU): `slices` had the best dispatch and the heaviest
-# compile, `gather` and `onehot` lost; nothing below is a chip's
-# finding unless it says so.
-# - "shift" (default): row l of the product is padded with l zeros
-#   below and L-1-l above, and the L rows are added. Static pads, so
-#   XLA fuses slice, pad and add into one pass over the product. Until
-#   PR 29 this name held the re-viewing form (pad each row with L
-#   zeros, flatten, re-view at width M+L-1, sum rows): four graph
-#   nodes, but its two reshapes change the minor dimension of a tiled
-#   array, which on the chip re-lays every word of the padded product:
-#   507 ms of `reshape` a keyed period audit, 261 ms of `reduce_sum` +
-#   `pad` + `slice` a keyless one. The padded-row sum costs 74 nodes a
-#   product where that cost 4: tracing and lowering a pairing kernel
-#   takes up to a fifth longer on a CPU and its first verdict from a
-#   warm compile cache 14-27% longer on the chip's host (PERF.md
-#   section 6).
-# - "gather": a static gather aligns prod row l to an l-shifted view,
-#   then sums rows. Few graph nodes but materializes an (..., L, L+M-1)
-#   intermediate — ~L× the product tensor — catastrophically
-#   memory-bound on big batches (the r2 CPU bench regression).
-# - "slices": accumulate row l into out[l : l+M] with L static
-#   slice-adds — minimal working set (best dispatch on XLA:CPU), but L
-#   graph nodes per conv (heaviest compile). On the chip each of the L
-#   updates is an operation of its own: level with "shift" at 56-112
-#   rows, five times behind it at one row, where depth sets the pace.
-# - "onehot": contract the (..., L, M) product planes against a constant
-#   (L, M, L+M-1) one-hot via einsum. XLA lowers this to a DENSE integer
-#   matmul doing (L+M-1)× redundant multiply-accumulates on the VPU
-#   (int32 never rides the MXU): the r1 bench showed it dominating the
-#   pairing dispatch. Kept for comparison.
-# - "mxu8": split the 24-bit products into four 7-bit planes and contract
-#   them against the constant one-hot as int8×int8→int32 matmuls — the
-#   shape the MXU's integer path takes (the reference's answer to this
-#   layer is gfp_amd64.s scalar asm; this is the systolic-array answer).
-#   The column ACCUMULATION rides the MXU; the products stay on the VPU.
-#   Requires non-negative product entries (true for every limb-product
-#   call site: products of canonical <2^12 limbs). On the chip 30-40%
-#   behind "shift" at 56-112 rows and 4% ahead at one row.
-CONV_IMPL = os.environ.get("GETHSHARDING_TPU_CONV", "shift")
-if CONV_IMPL not in ("shift", "slices", "gather", "onehot", "mxu8"):
-    raise ValueError(f"GETHSHARDING_TPU_CONV must be 'shift', 'slices', "
-                     f"'gather', 'onehot' or 'mxu8', got {CONV_IMPL!r}")
-if CONV_IMPL == "mxu8" and NORM_IMPL == "relaxed":
-    raise ValueError("GETHSHARDING_TPU_CONV=mxu8 requires non-negative "
-                     "product entries; GETHSHARDING_TPU_NORM=relaxed "
-                     "yields limbs that can be -1")
-if PALLAS_NORM and NORM_IMPL == "relaxed":
-    # normalize() routes to the exact-carry Pallas kernel BEFORE the
-    # NORM_IMPL branch; a silent override would mislabel autotune results
-    raise ValueError("GETHSHARDING_TPU_PALLAS=1 and GETHSHARDING_TPU_NORM="
-                     "relaxed are mutually exclusive (the Pallas normalize "
-                     "implements the exact ripple)")
-
-
-def conv_cols(prod: jnp.ndarray, impl: "str | None" = None) -> jnp.ndarray:
+def conv_cols(prod: jnp.ndarray) -> jnp.ndarray:
     """Anti-diagonal column sums: (..., L, M) -> (..., L+M-1) with
     out[n] = sum over l of prod[l, n-l] (0 <= n-l < M).
 
-    The building block of every limb product. `impl` overrides the
-    module default per call site."""
-    L, M = prod.shape[-2], prod.shape[-1]
-    ncols = L + M - 1
-    impl = impl or CONV_IMPL
-    if impl == "onehot":
-        return jnp.einsum("...ij,ijk->...k", prod, _conv_onehot(L, M))
-    if impl == "mxu8":
-        # int8 MXU path: 7-bit planes of the (non-negative, <2^28)
-        # entries, each contracted against the flat one-hot; the exact
-        # value re-assembles as sum_k plane_sums[k] << 7k (every partial
-        # term is bounded by the true column value, so int32-safe).
-        onehot = _conv_onehot(L, M).reshape(L * M, ncols).astype(np.int8)
-        flat = prod.reshape(prod.shape[:-2] + (L * M,))
-        planes = jnp.stack(
-            [(flat >> (7 * k)) & 0x7F for k in range(4)],
-            axis=-2).astype(jnp.int8)                    # (..., 4, L·M)
-        sums = lax.dot_general(
-            planes, jnp.asarray(onehot),
-            (((planes.ndim - 1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)            # (..., 4, ncols)
-        weights = np.array([1 << (7 * k) for k in range(4)], np.int32)
-        return (sums * weights[:, None]).sum(axis=-2)
-    if impl == "slices":
-        out = jnp.zeros(prod.shape[:-2] + (ncols,), prod.dtype)
-        for l in range(L):
-            out = out.at[..., l:l + M].add(prod[..., l, :])
-        return out
-    if impl == "shift":
-        # row l belongs to columns l .. l+M-1. lax.pad and not jnp.pad:
-        # a pairing kernel traces ~10^3 products, and jnp.pad's Python
-        # made that a quarter slower (33.9 s against 26.7 on XLA:CPU).
-        zero = jnp.zeros((), prod.dtype)
-        lead = [(0, 0, 0)] * (prod.ndim - 2)
-        out = None
-        for l in range(L):
-            row = lax.pad(lax.index_in_dim(prod, l, prod.ndim - 2, False),
-                          zero, lead + [(l, L - 1 - l, 0)])
-            out = row if out is None else out + row
-        return out
-    prod_p = jnp.pad(prod, [(0, 0)] * (prod.ndim - 1) + [(0, 1)])
-    idx = _conv_gather_idx(L, M)  # (L, ncols) static
-    rows = jnp.take_along_axis(
-        prod_p, jnp.broadcast_to(idx, prod_p.shape[:-2] + (L, ncols)), axis=-1)
-    return rows.sum(axis=-2)
-
-
-def _conv_gather_idx(L: int, M: int) -> np.ndarray:
-    key = (L, M)
-    cached = _CONV_IDX_CACHE.get(key)
-    if cached is None:
-        n = np.arange(L + M - 1)[None, :]
-        l = np.arange(L)[:, None]
-        m = n - l
-        cached = np.where((m >= 0) & (m < M), m, M).astype(np.int32)
-        _CONV_IDX_CACHE[key] = cached
-    return cached
-
-
-def _conv_onehot(L: int, M: int) -> np.ndarray:
-    key = (L, M)
-    cached = _CONV_ONEHOT_CACHE.get(key)
-    if cached is None:
-        e = np.zeros((L, M, L + M - 1), np.int32)
-        for i in range(L):
-            for j in range(M):
-                e[i, j, i + j] = 1
-        cached = e
-        _CONV_ONEHOT_CACHE[key] = cached
-    return cached
-
-
-_CONV_IDX_CACHE: dict = {}
-_CONV_ONEHOT_CACHE: dict = {}
+    The building block of every limb product. Row l of the product
+    belongs to columns l .. l+M-1: it is padded with l zeros below and
+    L-1-l above, and the L rows are added. The pads are STATIC, so XLA
+    fuses slice, pad and add into one pass over the product. Not the
+    shorter re-viewing form (pad each row with L zeros, flatten, re-view
+    at width M+L-1, sum rows): its two reshapes change the minor
+    dimension of a tiled array, which on the chip re-lays every word of
+    the padded product through HBM. PERF.md section 6 (PR 29) has the
+    chip's ranking of this form against the ones it replaced."""
+    L = prod.shape[-2]
+    # lax.pad and not jnp.pad: a pairing kernel traces ~10^3 products,
+    # and jnp.pad's Python made that a quarter slower (33.9 s against
+    # 26.7 on XLA:CPU).
+    zero = jnp.zeros((), prod.dtype)
+    lead = [(0, 0, 0)] * (prod.ndim - 2)
+    out = None
+    for l in range(L):
+        row = lax.pad(lax.index_in_dim(prod, l, prod.ndim - 2, False),
+                      zero, lead + [(l, L - 1 - l, 0)])
+        out = row if out is None else out + row
+    return out
 
 
 def _carry_scan(z: jnp.ndarray):
-    """Exact carry propagation along the last axis.
+    """Exact carry propagation along the last axis, as a sequential
+    `lax.scan` (a compact graph: the big pairing kernels trace thousands
+    of these).
 
     Accepts limbs of either sign with magnitude < 2^31 (arithmetic >> gives
     floor division, so borrows propagate as negative carries). Returns
     (carry_out, limbs): total carry off the top (callers either know it is
     zero or use its sign as a borrow flag) and canonical limbs.
-
-    Three implementations, selected by $GETHSHARDING_TPU_CARRY:
-    - "scan" (default): sequential lax.scan — compact graph, fastest XLA
-      compile for the big pairing kernels.
-    - "unroll": the same sequential ripple as a STATIC python loop. A
-      lax.scan lowers to an XLA While whose body cannot fuse with its
-      neighbours; unrolling turns every normalize's carry into
-      straight-line elementwise code XLA fuses end-to-end. Costs HLO
-      size (L ops per carry) and therefore compile time.
-    - "assoc": two relaxed rounds bound limbs to [-1, 2^LIMB_BITS + eps],
-      then the residual per-position carries (each in {-1,0,1}, acting as
-      monotone maps carry_in -> carry_out) compose via
-      `lax.associative_scan` — log-depth flat vector code, no while loops.
     """
-    if CARRY_IMPL == "unroll":
-        c = z[..., 0] * 0
-        outs = []
-        for i in range(z.shape[-1]):
-            t = z[..., i] + c
-            c = t >> LIMB_BITS
-            outs.append(t & LIMB_MASK)
-        return c, jnp.stack(outs, axis=-1)
-    if CARRY_IMPL == "scan":
-        zs = jnp.moveaxis(z, -1, 0)
+    zs = jnp.moveaxis(z, -1, 0)
 
-        def step(c, x):
-            t = x + c
-            return t >> LIMB_BITS, t & LIMB_MASK
+    def step(c, x):
+        t = x + c
+        return t >> LIMB_BITS, t & LIMB_MASK
 
-        # init carry derived from the input so its varying-manual-axes
-        # match under shard_map (a fresh constant would be unvarying)
-        carry, out = lax.scan(step, zs[0] * 0, zs)
-        return carry, jnp.moveaxis(out, 0, -1)
-
-    c1, z = _relaxed_round(z)
-    c2, z = _relaxed_round(z)
-    # z limbs now in [-1, 2^LIMB_BITS + 2^(LIMB_BITS/2)] — well inside the
-    # [-(2^LIMB_BITS - 1), 2^(LIMB_BITS+1) - 2] window where
-    # (z + c) >> LIMB_BITS stays in {-1, 0, 1} for c in {-1, 0, 1}.
-    t = tuple((z + k) >> LIMB_BITS for k in (-1, 0, 1))  # carry-out per carry-in
-
-    def compose(a, b):
-        # prefix composition: apply earlier map `a` first, then `b`
-        return tuple(
-            jnp.where(ac == -1, b[0], jnp.where(ac == 0, b[1], b[2]))
-            for ac in a)
-
-    prefix = lax.associative_scan(compose, t, axis=-1)
-    # carry into position i = (prefix up to i-1) evaluated at 0
-    ev0 = prefix[1]
-    carries = jnp.concatenate(
-        [jnp.zeros_like(ev0[..., :1]), ev0[..., :-1]], axis=-1)
-    out = (z + carries) & LIMB_MASK
-    return c1 + c2 + ev0[..., -1], out
+    # init carry derived from the input so its varying-manual-axes
+    # match under shard_map (a fresh constant would be unvarying)
+    carry, out = lax.scan(step, zs[0] * 0, zs)
+    return carry, jnp.moveaxis(out, 0, -1)
 
 
 def _pallas_wanted() -> bool:
@@ -440,24 +251,6 @@ class ModArith:
         # borrows leave -1 limbs below FOLD_BASE (lo value >= -2^253) or
         # fold rows act on -1 high limbs (>= -FOLD_ROWS*2^12*p > -2^260).
         self.lift = int_to_limbs(-(-(1 << 261) // p) * p, FOLD_BASE)
-        # The relaxed normalize folds on limbs that can reach -113 (two
-        # pre-fold rounds instead of three), so its folded value can go
-        # as low as -FOLD_ROWS·113·p, plus a lo part down to -113·2^252
-        # — beyond what a FOLD_BASE-wide lift can cover (< 2^264), and
-        # p-DEPENDENT (a fixed 2^266 covers the 254-bit bn256 fields but
-        # NOT a 256-bit modulus like secp256k1's, where ceil(2^266/p) is
-        # only ~2^10 multiples). Derive it from the worst case; it is
-        # NLIMBS wide and added after the pad. Total value stays
-        # < 2^264 + FOLD_ROWS·4208·p + lift < 2^274 — this can exceed
-        # 2^LAZY_BITS by a hair for 256-bit p, which every consumer
-        # absorbs (sub_pad >= 2^300; the fused-accumulator pads cover
-        # 2·LAZY_BITS+1 = 547 bits). Only constructible in the wide form.
-        if NLIMBS * LIMB_BITS >= 272:
-            # fold term + lo term (113 · sum_{i<22} 2^(12i) < 113·2^253)
-            deficit = FOLD_ROWS * 113 * p + (113 << 253)
-            self.lift_relaxed = int_to_limbs(-(-deficit // p) * p, NLIMBS)
-        else:
-            self.lift_relaxed = None
         # Shifted moduli for canonicalization: p << k >= RADIX at k_max;
         # descending conditional subtraction brings any canonical-limb
         # value < p.
@@ -504,45 +297,16 @@ class ModArith:
         (instead of three for an exact-width form) is the point of the
         25-limb lazy representation.
         """
-        if PALLAS_NORM and _pallas_wanted():
-            from gethsharding_tpu.ops.pallas_norm import normalize_pallas
-
-            return normalize_pallas(self, z)
-
         pad = [(0, 0)] * (z.ndim - 1)
 
-        def relax(v, rounds):
-            for _ in range(rounds):
+        def relax3(v):
+            for _ in range(3):
                 top, v = _relaxed_round(jnp.pad(v, pad + [(0, 1)]))
                 # width grew by 1 so the round's own top carry is the new
                 # top limb's whole content; `top` here is always 0
             return v
 
-        def relax3(v):
-            return relax(v, 3)
-
         if LIMB_FORM == "wide":
-            if NORM_IMPL == "relaxed":
-                # round-count-minimal variant. Pre-fold TWO rounds
-                # suffice for the int32 fold bound: |limb| < 2^30.7 ->
-                # r1 < 2^18.8 -> r2 in [-113, 4095 + 2^6.8], so the fold
-                # matmul stays < 33·4210·4095 < 2^30 per column; the
-                # NLIMBS-wide lift_relaxed (>= 2^266) keeps the value
-                # non-negative even against the -113-limb folds.
-                # Post-fold THREE width-preserving rounds (start < 2^29.1:
-                # r1 < 4095+2^17.1, r2 < 4095+2^5.1, r3 <= 4097), each
-                # re-fusing its top carry so the value is preserved
-                # EXACTLY even while transient borrows ripple at the top
-                # (a dropped -1 top carry would subtract 2^300). Output:
-                # limbs in [-1, 2^12 + 64], value unchanged < 2^LAZY_BITS
-                # — no exact ripple anywhere.
-                z = self._fold_hi(relax(z, 2))
-                z = jnp.pad(z, pad + [(0, NLIMBS - FOLD_BASE)])
-                z = z + self.lift_relaxed
-                for _ in range(3):
-                    top, z = _relaxed_round(z)
-                    z = z.at[..., -1].add(top << LIMB_BITS)
-                return z
             z = self._fold_hi(relax3(z)) + self.lift
             return _carry(jnp.pad(z, pad + [(0, NLIMBS - FOLD_BASE)]))
 
@@ -627,16 +391,6 @@ class ModArith:
 
     def _canon_impl(self, x: jnp.ndarray) -> jnp.ndarray:
         z = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, 1)])
-        if NORM_IMPL == "relaxed":
-            # relaxed normalize leaves QUASI-canonical limbs (a limb can be
-            # -1). When the represented value is already < p no conditional
-            # subtract fires, so without this exact pre-carry the output
-            # limbs could keep the -1 — and eq/is_zero compare limb
-            # vectors element-wise, turning two equal field values into a
-            # spurious mismatch. One carry makes the descent's input (and
-            # hence its output) canonical limbs. canon sits only on
-            # equality/export paths, never inside the hot normalize.
-            z = _carry(z)
         for k in range(self.pshift.shape[0]):
             z = _cond_sub(z, self.pshift[k])
         return z[..., :NLIMBS]

@@ -16,7 +16,7 @@ Devegili–Scott–Dahab hard part) as ONE `pallas_call`:
 - RELAXED normalization everywhere (value-preserving carry rounds as
   full-tile vector ops; quasi-canonical limbs in [-1, 2^12+64]) — the
   kernel contains no sequential carry chain at all;
-- batch on lanes, limbs/planes on sublanes (the `pallas_conv` layout):
+- batch on lanes, limbs/planes on sublanes:
   every shift-MAC of the schoolbook convolution is a full-width vector
   op across all 288 product planes of an fp12 product at once.
 
@@ -24,8 +24,8 @@ The arithmetic is self-contained wide-form (25 limbs) regardless of the
 ambient GETHSHARDING_TPU_* knobs: inputs arrive as any lazy limb form
 (22 or 25 wide, value < 2^273) and outputs return as 25-limb
 quasi-canonical limbs which the XLA wrapper re-normalizes into the
-ambient form. Bound proofs mirror ops/limb.py's relaxed-normalize
-derivation (same quasi-canonical bound, same fold/lift constants).
+ambient form. Bound proofs: the quasi-canonical bound and the
+fold/lift constants at `_LIFT_RELAXED` and `_normalize` below.
 
 Reference parity: this replaces the final-exponentiation half of
 `crypto/bn256/cloudflare/optate.go` (finalExponentiation) whose field
@@ -34,7 +34,7 @@ answer to the same problem (fuse the whole field stack below the
 dispatch boundary), re-expressed for a systolic/vector machine.
 
 Opt-in: GETHSHARDING_TPU_FINALEXP=mega routes `bn256_jax.pairing_is_one`
-through `finalexp_is_one`; bench.py probes it as an autotune config.
+through `finalexp_is_one`.
 Differential tests run the kernel in interpreter mode on CPU against the
 XLA path (tests/test_pallas_finalexp.py), and `run_program_xla` executes
 the same instruction stream with the same helpers as plain XLA ops so
@@ -85,7 +85,9 @@ _FOLD_J = np.stack(
      for k in range(KFOLD_ROWS)]).astype(np.int32)     # (33, 22)
 
 # lift added after the fold (multiple of p covering the worst-case
-# negative fold/lo terms of quasi-canonical inputs — limb.py:412-427)
+# negative fold/lo terms of quasi-canonical inputs: the fold acts on
+# limbs that can reach -113, so its value can go as low as
+# -KFOLD_ROWS·113·p, plus a lo part down to -113·2^253)
 _DEFICIT = KFOLD_ROWS * 113 * P + (113 << 253)
 _LIFT_RELAXED = int_to_limbs(-(-_DEFICIT // P) * P, KNL)
 
@@ -173,7 +175,8 @@ def _zeros_like_rows(x, rows: int):
 
 def _round(z):
     """One width-preserving relaxed carry round with top-carry refold:
-    value-exact for any width (limb.py `_relaxed_round` + top re-fuse)."""
+    value-exact for any width (`limb._relaxed_round` with the top carry
+    re-fused into the top limb)."""
     lo = z & LIMB_MASK
     c = z >> LIMB_BITS
     shifted = jnp.concatenate(
@@ -187,8 +190,7 @@ def _round(z):
 def _normalize(z, C: Consts):
     """Relaxed normalize: (..., W, B) accumulator (|limb| < 2^30.7,
     value >= 0) -> (..., 25, B) quasi-canonical limbs in [-1, 2^12+64],
-    value preserved mod p. Mirrors limb.py's wide/relaxed branch
-    (lines ~495-516): 2 growing rounds, fold, lift, 3 refold rounds —
+    value preserved mod p: 2 growing rounds, fold, lift, 3 refold rounds —
     with the growth pre-allocated as zero rows so every round is the
     width-preserving masked form."""
     w = z.shape[-2]
@@ -215,8 +217,8 @@ def _normalize(z, C: Consts):
 
 def _conv(u, v):
     """Schoolbook columns: (..., 25, B) x (..., 25, B) -> (..., 49, B),
-    leading dims broadcast — the stacked-plane form of pallas_conv's
-    shift-MAC loop (25 full-tile MACs for ALL planes at once): each
+    leading dims broadcast — a stacked-plane shift-MAC loop (25
+    full-tile MACs for ALL planes at once): each
     step lands in its column window through a zero-padded concatenate,
     the window update Mosaic lowers (`dynamic_slice` /
     `dynamic_update_slice`, even at static offsets, it does not).

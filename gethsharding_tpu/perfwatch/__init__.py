@@ -8,7 +8,7 @@ The measurement substrate every perf PR gates against:
   every timing closes over a REAL device->host pull, with an always-on
   block-vs-pull self-check (`perfwatch/timer_suspect`);
 - ``ledger.py``   — the append-only JSONL measurement history behind
-  ONE writer (`record_bench`), one schema for every bench.py mode;
+  ONE writer (`record_bench`), one schema for every emitter;
 - ``registry.py`` — the CPU-quick microbench suite the gate watches;
 - ``gate.py``     — `python -m gethsharding_tpu.perfwatch --check`:
   rolling-median + MAD tolerance bands per (workload, backend,
@@ -18,8 +18,8 @@ The measurement substrate every perf PR gates against:
   fires and soundness violations.
 
 Surfaces: the ``perf`` section on ``/status`` (`perf_status`),
-``perfwatch/*`` counters on /metrics + the Prometheus exposition, and
-the ``bench.py --perfwatch`` closed-loop acceptance run.
+``perfwatch/*`` counters on /metrics + the Prometheus exposition
+(tests/test_perfwatch.py holds the acceptance assertions).
 """
 
 from gethsharding_tpu.perfwatch.gate import (

@@ -2,7 +2,7 @@
 
 Hand-edited tables and ad-hoc JSON artifacts give a regression gate
 nothing to diff mechanically. The ledger is the one place every
-`bench.py` measurement lands:
+measurement of the microbench suite and the soak script lands:
 
 - **One schema.** Every record carries the workload name, the batch
   shape, the backend + platform it ran on, the active kernel knobs, an
@@ -11,10 +11,10 @@ nothing to diff mechanically. The ledger is the one place every
   (a record taken while the device timer's block-vs-pull self-check
   fired is stamped ``valid: false`` — see perfwatch/timer.py).
 - **One writer.** `Ledger.append` is the only code path that writes;
-  `record_bench` adapts bench.py's ``{metric, value, unit, extra}``
-  line shape onto it so every `bench.py` mode (--serving/--resident/
-  --overlap/--das/--soundness/--fleet/...) shares the schema instead
-  of each mode keeping its own drifting extras dict.
+  `record_bench` adapts the one-line ``{metric, value, unit, extra}``
+  shape onto it so every emitter (perfwatch/registry.py,
+  scripts/serving_stress.py) shares the schema instead of each keeping
+  its own drifting extras dict.
 - **Append-only JSON lines.** History is never rewritten; the
   regression gate (perfwatch/gate.py) reads a rolling window backward.
 
@@ -85,8 +85,8 @@ def env_fingerprint() -> dict:
 
 
 def knob_snapshot() -> Dict[str, str]:
-    """The active kernel knobs (the bench.py `_knob_snapshot` shape —
-    records must be self-describing about the code paths they timed)."""
+    """The `GETHSHARDING_TPU_*` variables of this process (records
+    must be self-describing about the code paths they timed)."""
     return {key: val for key, val in os.environ.items()
             if key.startswith("GETHSHARDING_TPU_")}
 
@@ -235,7 +235,7 @@ def build_record(metric: str, value: float, unit: Optional[str] = None,
                  workload: Optional[str] = None,
                  source: str = "bench", valid: bool = True,
                  suspects: int = 0) -> dict:
-    """THE adapter from bench.py's one-line ``{metric, value, unit,
+    """THE adapter from the one-line ``{metric, value, unit,
     vs_baseline, extra}`` contract onto the ledger schema, so the
     extras-splitting rules live in one function. Numeric extras become
     gateable metrics; everything else rides in ``extra`` verbatim."""
@@ -302,7 +302,7 @@ def record_bench(metric: str, value: float, unit: Optional[str] = None,
                  suspects: int = 0,
                  ledger: Optional[Ledger] = None) -> dict:
     """Build (`build_record`) + append in one step — the live
-    emitters' entry (bench.py `_emit`). LIVE records (source
+    emitters' entry. LIVE records (source
     \"bench\") additionally carry the devscope stamp
     (`_devscope_fields`): peak-HBM into the gated metrics dict, compile
     attribution into `extra` — ONE schema, stamped by the one writer,

@@ -1,8 +1,8 @@
 """Microbenchmark registry: the CPU-quick workloads the gate watches.
 
-The headline bench (`bench.py`) needs a signing workload cache and
-minutes of wall clock; a refactor gate needs something a CI step can
-run in seconds, anywhere, and still catch "the sigbackend split cost
+The benchmark (`benchmark/run.py`) needs a chip and minutes of wall
+clock; a refactor gate needs something a CI step can run in seconds,
+anywhere, and still catch "the sigbackend split cost
 10% on the host paths". These microbenches are that tier: small,
 deterministic, host-only workloads registered with their gated metric
 directions, each run appended to the ledger through the one writer so

@@ -2,8 +2,7 @@
 
 JAX dispatch is asynchronous, and a timing that closes before the
 device finished measures the enqueue. `DeviceTimer` is the one timing
-primitive every dispatch site in `sigbackend/`, `serving/` and
-`bench.py` uses:
+primitive every dispatch site in `sigbackend/` and `serving/` uses:
 
 - **The pull is the clock.** `pull(x)` materializes the value on the
   host (`np.asarray`) — the only operation that provably waits for
@@ -111,8 +110,8 @@ def _verdict(op: str, blocked: bool, block_s: float, pull_s: float) -> bool:
 
 def checked_pull(value, op: str = "pull") -> np.ndarray:
     """Materialize a device value on the host with the block-vs-pull
-    self-check, WITHOUT the marshal/device stage rollups — the bench
-    harness's one-shot form (`bench.py` extras, probe scripts)."""
+    self-check, WITHOUT the marshal/device stage rollups — the
+    one-shot form (`ensure_host`, probe scripts)."""
     arr, _, _, _ = _checked_materialize(value, op)
     return arr
 

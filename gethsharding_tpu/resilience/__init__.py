@@ -17,7 +17,7 @@ mainchain bridge (ISSUE 5):
   + audit high-water mark through `db/kv`, replayed on notary start;
 - ``chaos.py``    — seeded, deterministic failure schedules injectable
   at the backend-op, mainchain-call and dispatch seams (tests,
-  ``bench.py --chaos``, ``--chaos`` on the node CLI), including the
+  ``--chaos`` on the node CLI), including the
   silent-corruption ``mode=corrupt`` rules;
 - ``soundness.py`` — `SpotCheckSigBackend`: continuous statistically-
   sound integrity audit of the fast path — sampled random-row

@@ -21,7 +21,7 @@ reusable injection surface driven by a seeded, replayable schedule:
   the soundness spot-checker (`resilience/soundness.py`) can catch;
 - the schedule itself is pure decision logic: per-seam call counters
   plus a seed, so the SAME spec replays the SAME failure timeline in
-  tests, `bench.py --chaos`, and a devnet node booted with
+  tests and a devnet node booted with
   ``--chaos`` — no `random` module state leaks between runs.
 
 `InjectedFault` subclasses `ConnectionError` deliberately: injected

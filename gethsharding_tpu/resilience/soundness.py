@@ -68,8 +68,7 @@ from gethsharding_tpu.resilience.errors import SoundnessViolation
 from gethsharding_tpu.sigbackend import SigBackend, VerdictFuture
 
 # the default sampled fraction of dispatches: at 4 checked rows per
-# 64-row dispatch this re-verifies ~0.3% of all rows — inside the <2%
-# overhead budget bench.py --soundness asserts — while catching an
+# 64-row dispatch this re-verifies ~0.3% of all rows while catching an
 # every-dispatch single-row corruptor within ~1500 dispatches at 99%
 # confidence (seconds at production dispatch rates; corrupting MORE
 # rows per dispatch, or a larger share of dispatches, detects faster)

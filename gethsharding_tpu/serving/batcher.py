@@ -46,8 +46,7 @@ tracing is off the hot path pays one attribute read per request.
 Every completed request additionally records one per-class SLO event
 (``gethsharding_tpu/slo/``): good with its end-to-end latency on
 success, bad on a shed or a failed batch — the burn-rate feed, always
-on and budgeted inside the serving tier's 2% overhead bar (asserted in
-``bench.py --fleet``).
+on and budgeted inside the serving tier's 2% overhead bar.
 """
 
 from __future__ import annotations

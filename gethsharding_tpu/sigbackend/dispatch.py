@@ -854,8 +854,7 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
     # populated by EVERY committee dispatch (no sync, pure nbytes
     # arithmetic): {wire_bytes, g2_wire_bytes, pk_hit_bytes, pk_rows,
     # pk_hit_rows, resident, precomp, wire} — the transfer-attribution
-    # ledger bench.py records per config and the residency/precomp
-    # tests assert on (steady state: g2_wire_bytes == 0; precomp True
+    # ledger the residency/precomp tests assert on (steady state: g2_wire_bytes == 0; precomp True
     # when the dispatch consumed resident line tables)
     last_wire: dict | None = None
 

@@ -1,7 +1,8 @@
 """Per-class service-level objectives: error budgets, burn rates, breaches.
 
-The bench gates (``bench.py --fleet``'s per-class p99 assertions) are
-one-shot: they say whether a 12-second soak stayed inside its SLO. A
+A soak's gates (``scripts/serving_stress.py``'s per-class p99
+assertions) are one-shot: they say whether a 12-second soak stayed
+inside its SLO. A
 production fleet needs the CONTINUOUS form — declarative objectives per
 admission class, rolling multi-window burn-rate tracking (the SRE
 fast-5m/slow-1h pattern), an error budget that depletes and recovers,

@@ -21,8 +21,7 @@ idle class costs nothing). Latency distribution rides a
 p50/p95/p99 shown on /status. Everything is O(ring) only on reads
 that are throttled to ~1/s; the hot-path `record()` is two dict hops,
 two int adds and a histogram observe under a per-objective lock —
-budgeted (with tracing off) under 2% of the serving hot path,
-asserted in ``bench.py --fleet``.
+budgeted (with tracing off) under 2% of the serving hot path.
 """
 
 from __future__ import annotations
@@ -104,8 +103,7 @@ def _env_float(name: str, default: Optional[float]) -> Optional[float]:
 
 
 # (availability, p99 latency ms or None) per objective; the latency
-# defaults mirror the bench --fleet gates (interactive 8000 ms is
-# GETHSHARDING_FLEET_SLO_INTERACTIVE_MS's hermetic-CPU default)
+# defaults are generous for a hermetic CPU (interactive 8000 ms)
 _DEFAULTS = {
     "interactive": (0.999, 8000.0),
     "bulk_audit": (0.99, 30000.0),

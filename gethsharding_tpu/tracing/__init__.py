@@ -13,8 +13,7 @@ around it:
   ``jax.profiler.TraceAnnotation`` (JAX imported), plus the collector's
   pause counter (``GC_CLOCK``).
 - ``export.py`` — Chrome ``trace_event`` JSON export
-  (Perfetto-loadable; the ``--trace-out`` / ``bench.py --trace``
-  artifact).
+  (Perfetto-loadable; the ``--trace-out`` artifact).
 
 Surfaces: ``GET /trace`` on the node StatusServer (recent traces),
 ``--trace`` / ``--trace-out`` / ``--trace-ring`` on the sharding CLI,

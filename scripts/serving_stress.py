@@ -29,8 +29,7 @@ breaker-guarded serving replicas behind the shard-aware router
   mid-soak so the drain→probe→re-enter cycle runs under load.
 
 Per-class p99 latencies are reported and (when `--slo-interactive-ms`
-etc. are nonzero) GATED: `bench.py --fleet` runs this model with SLOs
-on. Exit code 1 on any divergence, hung client, interactive shed, or
+etc. are nonzero) GATED. Exit code 1 on any divergence, hung client, interactive shed, or
 SLO breach.
 
 Light-client traffic model (`--light-clients N`): N threads drive

@@ -191,10 +191,8 @@ _SLOW_MODULES = {
     "test_graft_entry",
     "test_period_pipeline",
     "test_end_to_end",
-    "test_limb",  # the Fermat-inversion pow chains dominate its compiles
     "test_replay",
     "test_stress",
-    "test_pallas",  # interpreter-mode kernels are slow per element
     "test_knob_combos",  # one cold kernel compile per subprocess
 }
 # test_pallas_finalexp stays in the FAST tier on purpose: its three

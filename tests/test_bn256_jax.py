@@ -265,143 +265,3 @@ def test_tree_reduce_non_power_of_two_width():
         zinv = pow(int(Zi[b]), ref.P - 2, ref.P)
         assert (int(Xi[b]) * zinv % ref.P,
                 int(Yi[b]) * zinv % ref.P) == host
-
-
-# == PAIR_UNROLL differential coverage =====================================
-# The unrolled drivers run the IDENTICAL op sequence as their scan/switch
-# twins, so raw outputs must be bit-equal. Each driver is compared
-# separately on a small input (the fully inlined end-to-end kernel takes
-# >35 min to compile on XLA:CPU — too heavy for the suite; the bench's
-# audit_period correctness gate covers the composed path on TPU, and
-# test_pair_unroll_full_e2e below runs it on demand).
-
-
-def _canon12(x):
-    return np.asarray(k.FP.canon(x))
-
-
-def test_pair_unroll_pow_u_matches_scan(monkeypatch):
-    rng = np.random.default_rng(17)
-    x = jnp.asarray(_fp12_to_arr(_rand_fp12(rng)))
-    want = _canon12(k._pow_u(x))
-    monkeypatch.setattr(k, "FE_UNROLL", True)
-    assert (_canon12(k._pow_u(x)) == want).all()
-
-
-def test_pair_unroll_pow_u_fraction_matches_scan(monkeypatch):
-    rng = np.random.default_rng(19)
-    x = jnp.asarray(np.stack([_fp12_to_arr(_rand_fp12(rng)),
-                              _fp12_to_arr(_rand_fp12(rng))]))
-    want = _canon12(k._pow_u_fraction(x))
-    monkeypatch.setattr(k, "FE_UNROLL", True)
-    assert (_canon12(k._pow_u_fraction(x)) == want).all()
-
-
-def test_pair_unroll_hard_part_matches_scan(monkeypatch):
-    """Register-machine mechanics (static indices vs dynamic slots):
-    run _HARD_PROGRAM with a cheap stand-in for pow_u so the comparison
-    compiles in seconds; the program executed is the real one."""
-    rng = np.random.default_rng(23)
-    f = jnp.asarray(_fp12_to_arr(_rand_fp12(rng)))
-    want = _canon12(k._run_hard_part(f, k.fp12_sqr, k.fp12_conj))
-    monkeypatch.setattr(k, "FE_UNROLL", True)
-    assert (_canon12(k._run_hard_part(f, k.fp12_sqr, k.fp12_conj))
-            == want).all()
-
-
-def test_pair_unroll_miller_matches_scan(monkeypatch):
-    """Miller drivers on a TRUNCATED static program (covers the static
-    dbl/add branch selection and candidate indexing of both the affine
-    and the projective walk without the 91-step inlined compile)."""
-    # keep one DBL, one ADD(+Q), one ADD(πQ), one ADD(-π²Q)
-    short_ops = np.asarray([0, 1, 0, 3, 4], np.int32)
-    short_lines = k._GEN_LINES[:5]
-    monkeypatch.setattr(k, "_OPT_OPS", short_ops)
-    monkeypatch.setattr(k, "_GEN_LINES", short_lines)
-
-    g1 = ref.g1_mul(41, ref.G1_GEN)
-    g2 = ref.g2_mul(43, ref.G2_GEN)
-    px, py, _ = k.g1_to_limbs([g1])
-    qx, qy, _ = k.g2_to_limbs([g2])
-    sig_aff = (jnp.asarray(px), jnp.asarray(py), None)
-    pk_aff = (jnp.asarray(qx), jnp.asarray(qy), None)
-    # projective variant: scale by z (the walk must be z-invariant up to
-    # the same sequence of ops, so unrolled == scan exactly per form)
-    z = 7
-    sig_proj = (jnp.asarray(px), jnp.asarray(py),
-                jnp.asarray(k.FP.from_ints([z])))
-    qxz, qyz, _ = k.g2_to_limbs([(g2[0].scalar(z), g2[1].scalar(z * z))])
-    pk_proj = (jnp.asarray(qxz), jnp.asarray(qyz),
-               jnp.asarray(np.stack([k.FP.from_int(z), k.FP.from_int(0)]))[None])
-
-    for sig, pk in ((sig_aff, pk_aff), (sig_proj, pk_proj)):
-        monkeypatch.setattr(k, "PAIR_UNROLL", False)
-        want = _canon12(k._bls_miller_opt(sig, jnp.asarray(px),
-                                          jnp.asarray(py), pk))
-        monkeypatch.setattr(k, "PAIR_UNROLL", True)
-        got = _canon12(k._bls_miller_opt(sig, jnp.asarray(px),
-                                         jnp.asarray(py), pk))
-        assert (got == want).all()
-
-    # the plain ate loop's unrolled twin, over a truncated bit pattern
-    monkeypatch.setattr(k, "ATE_BITS", np.asarray([1, 0, 1], np.int32))
-    monkeypatch.setattr(k, "PAIR_UNROLL", False)
-    want = _canon12(k.miller_loop(jnp.asarray(px[0]), jnp.asarray(py[0]),
-                                  jnp.asarray(qx[0]), jnp.asarray(qy[0])))
-    monkeypatch.setattr(k, "PAIR_UNROLL", True)
-    got = _canon12(k.miller_loop(jnp.asarray(px[0]), jnp.asarray(py[0]),
-                                 jnp.asarray(qx[0]), jnp.asarray(qy[0])))
-    assert (got == want).all()
-
-
-@pytest.mark.skipif(os.environ.get("GETHSHARDING_RUN_XSLOW") != "1",
-                    reason="fully inlined kernel compiles >35 min on "
-                           "XLA:CPU; set GETHSHARDING_RUN_XSLOW=1")
-def test_pair_unroll_full_e2e(monkeypatch):
-    """Full-fidelity end-to-end: unrolled pairing value vs the scalar
-    reference. On-demand only (see skip reason)."""
-    # the production GETHSHARDING_TPU_PAIR_UNROLL=1 sets BOTH flags
-    monkeypatch.setattr(k, "PAIR_UNROLL", True)
-    monkeypatch.setattr(k, "FE_UNROLL", True)
-    g1 = ref.g1_mul(29, ref.G1_GEN)
-    g2 = ref.g2_mul(31, ref.G2_GEN)
-    px, py, _ = k.g1_to_limbs([g1])
-    qx, qy, _ = k.g2_to_limbs([g2])
-    f = k.final_exponentiation(
-        k.miller_loop(jnp.asarray(px[0]), jnp.asarray(py[0]),
-                      jnp.asarray(qx[0]), jnp.asarray(qy[0])))
-    got = np.asarray(_arr_to_coeffs(f))
-    assert (got == _fp12_coeffs(ref.pairing(g1, g2))).all()
-
-
-@slow
-def test_relaxed_norm_pairing_value(monkeypatch):
-    """The whole pairing stack under GETHSHARDING_TPU_NORM=relaxed (no
-    exact carry anywhere in normalize) must reproduce the scalar pairing
-    exactly — canon() re-canonicalizes quasi-canonical limbs at the
-    comparison boundary."""
-    from gethsharding_tpu.ops import limb as _limb
-    if _limb.LIMB_FORM != "wide":
-        pytest.skip("relaxed normalize is wide-form only")
-    if _limb.CONV_IMPL == "mxu8":
-        pytest.skip("mxu8 conv requires non-negative products; "
-                    "incompatible with relaxed limbs")
-    # the fp2/fp12 tower ops are @jax.jit with executables cached by
-    # shape: earlier tests compile them under NORM_IMPL="exact" at these
-    # exact shapes, which would make this test run the exact path
-    # vacuously (and leak relaxed executables to later tests) without a
-    # cache flush on both sides
-    jax.clear_caches()
-    monkeypatch.setattr(_limb, "NORM_IMPL", "relaxed")
-    try:
-        g1 = ref.g1_mul(57, ref.G1_GEN)
-        g2 = ref.g2_mul(61, ref.G2_GEN)
-        px, py, _ = k.g1_to_limbs([g1])
-        qx, qy, _ = k.g2_to_limbs([g2])
-        f = k.final_exponentiation(
-            k.miller_loop(jnp.asarray(px[0]), jnp.asarray(py[0]),
-                          jnp.asarray(qx[0]), jnp.asarray(qy[0])))
-        got = np.asarray(_arr_to_coeffs(f))
-        assert (got == _fp12_coeffs(ref.pairing(g1, g2))).all()
-    finally:
-        jax.clear_caches()

@@ -123,7 +123,7 @@ def test_requested_pallas_kernel_never_gets_the_xla_path(monkeypatch):
     import jax
     import jax.numpy as jnp
 
-    from gethsharding_tpu.ops import limb, pallas_norm
+    from gethsharding_tpu.ops import bn256_jax, limb, pallas_finalexp
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert limb._pallas_wanted() is True
@@ -137,22 +137,22 @@ def test_requested_pallas_kernel_never_gets_the_xla_path(monkeypatch):
     with pytest.raises(RuntimeError, match="backend init failed"):
         limb._pallas_wanted()
 
-    def refused(arith, z):
+    def refused(f):
         raise NotImplementedError("Mosaic refused the kernel")
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(limb, "PALLAS_NORM", True)
-    monkeypatch.setattr(pallas_norm, "normalize_pallas", refused)
-    arith = limb.ModArith(21888242871839275222246405745257275088696311157297823662689037894645226208583)
+    monkeypatch.setattr(bn256_jax, "FINALEXP", "mega")
+    monkeypatch.setattr(pallas_finalexp, "finalexp_is_one", refused)
     with pytest.raises(NotImplementedError, match="Mosaic refused"):
-        arith.normalize(jnp.zeros((2, 49), jnp.int32))
+        bn256_jax.pairing_is_one(
+            jnp.zeros((2, 6, 2, limb.NLIMBS), jnp.int32))
 
 
 # == the cache rule =========================================================
 
 _ENTRY_POINTS = ("gethsharding_tpu.rpc.chain_server", "gethsharding_tpu.cli",
                  "gethsharding_tpu.node.cli", "gethsharding_tpu.fleet.frontend",
-                 "bench", "chip_smoke")
+                 "chip_smoke")
 _CACHE_PROBE = (
     "import importlib, jax\n"
     "from gethsharding_tpu.ops.device import configure_compile_cache\n"

@@ -245,7 +245,7 @@ def test_the_collectors_callback_takes_no_lock(watch, name):
 
 
 def test_a_library_dispatch_freezes_nothing(monkeypatch):
-    """`JaxSigBackend` alone, as a test or `bench.py` builds it: the
+    """`JaxSigBackend` alone, as a test builds it: the
     compile is booked with the process's watch, which has no listener."""
     from gethsharding_tpu.sigbackend import JaxSigBackend
 

@@ -1,9 +1,9 @@
-"""The autotune knob matrix must be sound COMBINED, not just per knob.
+"""The mega switches with the 22-limb form, combined.
 
-Each case runs the committee-verify kernel end to end (good + tampered
-rows) in a subprocess with the knob env set — the knobs are read at
-import, so a fresh interpreter is the only honest way to exercise a
-configuration exactly as the bench's sweep children deploy it."""
+Runs the committee-verify kernel end to end (good + tampered rows) in a
+subprocess with the switches set — they are read at import, so a fresh
+interpreter is the only honest way to exercise the configuration as
+`chip_smoke.py` leg C deploys it."""
 
 import os
 import subprocess
@@ -39,86 +39,23 @@ assert [bool(v) for v in np.asarray(out)] == [True, False], out
 print("combo-ok")
 """
 
-COMBOS = [
-    # the sweep's prime candidates
-    {"GETHSHARDING_TPU_LIMB_FORM": "wide", "GETHSHARDING_TPU_NORM": "relaxed",
-     "GETHSHARDING_TPU_PAIR_UNROLL": "finalexp"},
-    # mega finalexp on CPU exercises the knob wiring + XLA fallback (the
-    # kernel itself is interpret-tested in test_pallas_finalexp)
-    {"GETHSHARDING_TPU_LIMB_FORM": "exact", "GETHSHARDING_TPU_CARRY": "scan",
-     "GETHSHARDING_TPU_FINALEXP": "mega"},
-    {"GETHSHARDING_TPU_LIMB_FORM": "exact", "GETHSHARDING_TPU_CARRY": "unroll",
-     "GETHSHARDING_TPU_SCAN_UNROLL": "4"},
-    {"GETHSHARDING_TPU_LIMB_FORM": "wide", "GETHSHARDING_TPU_NORM": "relaxed",
-     "GETHSHARDING_TPU_SCAN_UNROLL": "4"},
-]
-
-
-_RELAXED_CANON_DRIVER = """
-from gethsharding_tpu.parallel.virtual import force_virtual_cpu_devices
-force_virtual_cpu_devices(1)
-import numpy as np
-from gethsharding_tpu.ops import limb
-from gethsharding_tpu.ops.bn256_jax import FP
-
-# a value < p in a QUASI-canonical representation (one -1 limb, value
-# unchanged): canon must still emit the unique canonical limb vector,
-# or eq/is_zero would report two equal field values unequal
-v = FP.p - 12345
-base = limb.int_to_limbs(v)
-k = int(np.argmin(base[1:])) + 1  # a zero-ish limb to drive to -1
-quasi = base.copy()
-quasi[k] -= 1
-quasi[k - 1] += 1 << limb.LIMB_BITS
-got = np.asarray(FP.canon(quasi[None]))[0]
-assert (got == base).all(), (got, base)
-assert bool(FP.eq(quasi[None], base[None])[0])
-print("canon-ok")
-"""
-
-
-def test_relaxed_canon_handles_quasi_canonical_limbs():
-    env = {key: val for key, val in os.environ.items()
-           if not key.startswith("GETHSHARDING_TPU_")}
-    env.update({"GETHSHARDING_TPU_LIMB_FORM": "wide",
-                "GETHSHARDING_TPU_NORM": "relaxed"})
-    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    proc = subprocess.run([sys.executable, "-c", _RELAXED_CANON_DRIVER],
-                          env=env, capture_output=True, text=True,
-                          timeout=600, cwd=repo_root)
-    assert proc.returncode == 0 and "canon-ok" in proc.stdout, (
-        proc.stdout[-500:], proc.stderr[-1500:])
-
-
-def test_finalexp_mega_conflicts_with_pair_unroll():
-    env = {key: val for key, val in os.environ.items()
-           if not key.startswith("GETHSHARDING_TPU_")}
-    env.update({"GETHSHARDING_TPU_FINALEXP": "mega",
-                "GETHSHARDING_TPU_PAIR_UNROLL": "finalexp"})
-    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    proc = subprocess.run(
-        [sys.executable, "-c",
-         "from gethsharding_tpu.parallel.virtual import "
-         "force_virtual_cpu_devices\n"
-         "force_virtual_cpu_devices(1)\n"
-         "import gethsharding_tpu.ops.bn256_jax\n"],
-        env=env, capture_output=True, text=True, timeout=600, cwd=repo_root)
-    assert proc.returncode != 0 and "FINALEXP" in proc.stderr
+# mega finalexp on the CPU exercises the switch's wiring and the XLA
+# path under the 22-limb form (the kernels themselves are
+# interpret-tested in test_pallas_finalexp)
+MEGA = {"GETHSHARDING_TPU_LIMB_FORM": "exact",
+        "GETHSHARDING_TPU_FINALEXP": "mega"}
 
 
 @slow
-@pytest.mark.parametrize("combo", COMBOS,
-                         ids=["relaxed+feunroll", "mega", "unroll+su4",
-                              "relaxed+su4"])
-def test_knob_combo_committee_verify(combo):
-    # a clean knob slate: ambient GETHSHARDING_TPU_* exports must not
-    # leak into the configuration under test
+def test_mega_switches_committee_verify():
+    # a clean slate: ambient GETHSHARDING_TPU_* exports must not leak
+    # into the configuration under test
     env = {key: val for key, val in os.environ.items()
            if not key.startswith("GETHSHARDING_TPU_")}
-    env.update(combo)
+    env.update(MEGA)
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     proc = subprocess.run([sys.executable, "-c", _DRIVER], env=env,
                           capture_output=True, text=True, timeout=1500,
                           cwd=repo_root)
     assert proc.returncode == 0 and "combo-ok" in proc.stdout, (
-        combo, proc.stdout[-500:], proc.stderr[-1500:])
+        proc.stdout[-500:], proc.stderr[-1500:])

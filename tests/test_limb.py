@@ -4,13 +4,24 @@ Covers both consensus moduli (bn256 base/scalar fields, secp256k1 base/
 scalar fields) — the same ModArith machinery backs the pairing kernel and
 the ECDSA kernel, mirroring how the reference's gfP asm and libsecp256k1
 field code each serve one curve (SURVEY.md §2.3).
+
+The limb product's column sum (`limb.conv_cols`, which every field
+product of every kernel goes through: `ModArith.mul_cols`,
+`bn256_jax._pair_conv_combine`) is held to a plain NumPy schoolbook.
 """
 
+import ast
+import math
+import os
 import random
+import re
+import subprocess
+import sys
 
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from gethsharding_tpu.crypto import bn256 as bn_ref
@@ -128,93 +139,143 @@ def test_batch_shapes_nd():
             assert int(out[i][j]) == pow(vals[i][j], 2, p)
 
 
-def test_assoc_carry_impl_matches_scan(monkeypatch):
-    """All carry implementations (scan / assoc / unroll) must agree
-    exactly; the non-default paths are env-selected and would otherwise
-    go untested."""
-    p = MODULI["bn256_p"]
-    rng = random.Random(11)
-    vals_a = [rng.randrange(p) for _ in range(8)]
-    vals_b = [rng.randrange(p) for _ in range(8)]
-    x = jnp.asarray(limb.ints_to_limbs(vals_a))
-    y = jnp.asarray(limb.ints_to_limbs(vals_b))
-
-    fp = limb.ModArith(p)
-    expect = [a * b % p for a, b in zip(vals_a, vals_b)]
-    got_scan = fp.to_ints(fp.mul(x, y))
-    monkeypatch.setattr(limb, "CARRY_IMPL", "assoc")
-    got_assoc = fp.to_ints(fp.sub(fp.mul(x, y), y))
-    monkeypatch.setattr(limb, "CARRY_IMPL", "unroll")
-    got_unroll = fp.to_ints(fp.sub(fp.mul(x, y), y))
-    monkeypatch.setattr(limb, "CARRY_IMPL", "scan")
-    assert [int(v) for v in got_scan] == expect
-    expect_sub = [(a * b - b) % p for a, b in zip(vals_a, vals_b)]
-    assert [int(v) for v in got_assoc] == expect_sub
-    assert [int(v) for v in got_unroll] == expect_sub
-
-
-_CONV_SMALL = [(22, 22), (25, 49), (3, 7), (1, 5), (22, 43)]
+def _schoolbook_cols(prod: np.ndarray) -> np.ndarray:
+    """out[n] = sum over l of prod[l, n-l], in plain NumPy (int64)."""
+    L, M = prod.shape[-2:]
+    out = np.zeros(prod.shape[:-2] + (L + M - 1,), np.int64)
+    for l in range(L):
+        out[..., l:l + M] += prod[..., l, :]
+    return out
 
 
 def _shape_id(shape):
     return "x".join(map(str, shape))
 
 
-@pytest.mark.parametrize("shape", _CONV_SMALL, ids=_shape_id)
-@pytest.mark.parametrize("impl", ["shift", "slices", "gather", "mxu8"])
-def test_conv_impls_agree(impl, shape):
-    """Every conv_cols implementation computes the same anti-diagonal
-    sums (the autotune sweep may deploy any of them)."""
+@pytest.mark.parametrize("shape", [(22, 22), (25, 49), (3, 7), (1, 5),
+                                   (22, 43)], ids=_shape_id)
+def test_conv_cols_matches_schoolbook(shape):
+    """Anti-diagonal sums of signed entries, square and ragged shapes."""
     L, M = shape
     rng = np.random.default_rng(7 + 100 * L + M)
     prod = rng.integers(-2**20, 2**20, size=(2, 3, L, M),
                         dtype=np.int64).astype(np.int32)
-    if impl == "mxu8":
-        # mxu8's int8-plane split assumes non-negative entries (the
-        # limb-product contract: products of canonical <2^12 limbs)
-        prod = np.abs(prod)
-    want = limb.conv_cols(jnp.asarray(prod), impl="onehot")
-    got = limb.conv_cols(jnp.asarray(prod), impl=impl)
-    assert np.array_equal(np.asarray(got), np.asarray(want))
+    got = np.asarray(limb.conv_cols(jnp.asarray(prod)))
+    assert got.dtype == np.int32
+    assert np.array_equal(got, _schoolbook_cols(prod))
 
 
-def test_relaxed_norm_matches_exact(monkeypatch):
-    """GETHSHARDING_TPU_NORM=relaxed (wide form): same residues as the
-    exact ripple on mul/add/sub chains, and the quasi-canonical limb
-    contract holds (limbs in [-1, 2^12 + 64]) — the range every fused
-    accumulator's int32 proof budgets for."""
-    if limb.LIMB_FORM != "wide":
-        pytest.skip("relaxed normalize is wide-form only")
-    if limb.CONV_IMPL == "mxu8":
-        pytest.skip("mxu8 conv requires non-negative products; "
-                    "incompatible with relaxed limbs")
-    for name in ("bn256_p", "secp_p", "secp_n", "bn256_n"):
-        _relaxed_norm_case(monkeypatch, MODULI[name])
+# the product shapes the benchmark's cells trace: the recompute kernel's
+# fp12 square at 112 rows, a table-fed line multiply on a 56-row lane
+# block, one field product of the one-row vote, the aggregation tree
+_CONV_CELLS = [(112, 6, 2, 2, 25, 25), (56, 3, 2, 2, 25, 25), (1, 25, 25),
+               (144, 25, 25)]
 
 
-def _relaxed_norm_case(monkeypatch, p):
-    fp = limb.ModArith(p)
-    rng = random.Random(99)
-    vals_a = [rng.randrange(p) for _ in range(16)]
-    vals_b = [rng.randrange(p) for _ in range(16)]
-    x = jnp.asarray(limb.ints_to_limbs(vals_a))
-    y = jnp.asarray(limb.ints_to_limbs(vals_b))
+def _limbs_for(shape, fill, seed):
+    """The two operands of a product of shape (..., L, M): random
+    canonical limbs, or every limb at the range's edge."""
+    x_shape, y_shape = shape[:-1], shape[:-2] + shape[-1:]
+    if fill == "edge":
+        return (np.full(x_shape, limb.LIMB_MASK, np.int32),
+                np.full(y_shape, limb.LIMB_MASK, np.int32))
+    rng = np.random.default_rng(seed)
+    return (rng.integers(0, 1 << limb.LIMB_BITS, x_shape).astype(np.int32),
+            rng.integers(0, 1 << limb.LIMB_BITS, y_shape).astype(np.int32))
 
-    def chain():
-        z = fp.mul(fp.sub(fp.mul(x, y), y), fp.sub(x, fp.mul(y, y)))
-        return fp.sub(z, fp.mul(z, x))
 
-    monkeypatch.setattr(limb, "NORM_IMPL", "relaxed")
-    # sub-heavy chain: borrows exercise the negative-limb transients the
-    # top-carry re-fuse exists for
-    z = chain()
-    got = [int(v) for v in fp.to_ints(z)]
-    arr = np.asarray(z)
-    assert arr.min() >= -1 and arr.max() <= (1 << limb.LIMB_BITS) + 64, (
-        arr.min(), arr.max())
-    monkeypatch.setattr(limb, "NORM_IMPL", "exact")
-    want = [int(v) for v in fp.to_ints(chain())]
-    expect = [(((a * b - b) % p) * ((a - b * b) % p) % p) for a, b
-              in zip(vals_a, vals_b)]
-    expect = [(e - e * a) % p for e, a in zip(expect, vals_a)]
-    assert got == want == expect
+@pytest.mark.parametrize("fill", ["random", "edge"])
+@pytest.mark.parametrize("shape", _CONV_CELLS, ids=_shape_id)
+def test_conv_cols_on_cell_shapes(shape, fill):
+    """`conv_cols` returns the schoolbook's columns bit for bit on the
+    product shapes the benchmark's cells trace, with random limbs and
+    with every limb at the range's edge (4095: columns reach
+    25 * 4095^2 < 2^29)."""
+    x, y = _limbs_for(shape, fill, seed=sum(shape))
+    prod = jnp.asarray(x)[..., :, None] * jnp.asarray(y)[..., None, :]
+    assert prod.shape == shape
+    got = np.asarray(limb.conv_cols(prod))
+    assert got.dtype == np.int32
+    assert np.array_equal(got, _schoolbook_cols(np.asarray(prod)))
+    if fill == "edge":
+        n = np.arange(shape[-2] + shape[-1] - 1)
+        terms = np.minimum(n, n[::-1]) + 1
+        assert np.array_equal(got.reshape(-1, n.size)[0],
+                              terms * limb.LIMB_MASK ** 2)
+
+
+def test_mul_lowers_without_product_sized_reshape():
+    """The lowered text of `ModArith.mul` re-views nothing
+    product-sized: no `reshape` whose operand or result holds the
+    product's 625 words a row (or the 1,250 of its padded form). On
+    the chip such a reshape changes the minor dimension of a tiled
+    array, a physical re-laying of every word: 507 ms of the keyed
+    period audit before PR 29 (PERF.md section 6)."""
+    rows = 8
+    fp = limb.ModArith(bn_ref.P)
+    arg = jax.ShapeDtypeStruct((rows, limb.NLIMBS), jnp.int32)
+    text = jax.jit(fp.mul).lower(arg, arg).as_text()
+    assert "stablehlo.multiply" in text
+    product_words = rows * limb.NLIMBS * limb.NLIMBS
+    reshapes = re.findall(
+        r"stablehlo\.reshape[^\n]*?tensor<([0-9x]+)xi32>\) -> "
+        r"tensor<([0-9x]+)xi32>", text)
+    words = [math.prod(map(int, dims.split("x")))
+             for pair in reshapes for dims in pair]
+    assert max(words, default=0) < product_words, reshapes
+
+
+# == one kernel path ========================================================
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# the import-time switches that outlived PR 30: the Pallas mega kernels
+# and the 22-limb form they were measured under (PERF.md section 6)
+_SWITCHES_LEFT = {
+    "gethsharding_tpu/ops/limb.py": {"GETHSHARDING_TPU_LIMB_FORM"},
+    "gethsharding_tpu/ops/bn256_jax.py": {
+        "GETHSHARDING_TPU_FINALEXP", "GETHSHARDING_TPU_MILLER",
+        "GETHSHARDING_TPU_AGG"},
+}
+# the seven that went, each with the code only it reached
+_SWITCHES_GONE = ("CARRY", "PALLAS", "NORM", "CONV", "PAIRCONV",
+                  "PAIR_UNROLL", "SCAN_UNROLL")
+
+
+def _env_reads(tree: ast.AST) -> list:
+    """The variable each touch of `os.environ` / `os.getenv` reads:
+    its name for `os.environ.get("X", ...)` and `os.getenv("X")`, None
+    for any other use."""
+    named = {}
+    for node in ast.walk(tree):
+        if not (isinstance(node, ast.Call)
+                and isinstance(node.func, ast.Attribute)):
+            continue
+        touch = node.func.value if node.func.attr == "get" else node.func
+        if (isinstance(touch, ast.Attribute) and node.args
+                and isinstance(node.args[0], ast.Constant)):
+            named[id(touch)] = node.args[0].value
+    return [named.get(id(node)) for node in ast.walk(tree)
+            if isinstance(node, ast.Attribute)
+            and node.attr in ("environ", "environb", "getenv")]
+
+
+def test_kernel_modules_read_no_other_switch():
+    """`ops/limb.py` and `ops/bn256_jax.py` read the four variables of
+    the mega path and nothing else, and a value the seven removed
+    switches would have refused at import changes nothing."""
+    for rel, allowed in _SWITCHES_LEFT.items():
+        with open(os.path.join(_REPO, rel)) as src:
+            reads = _env_reads(ast.parse(src.read()))
+        assert set(reads) == allowed, (rel, reads)
+    env = {key: val for key, val in os.environ.items()
+           if not key.startswith("GETHSHARDING_TPU_")}
+    env.update({"JAX_PLATFORMS": "cpu", "PYTHONPATH": _REPO})
+    env.update({f"GETHSHARDING_TPU_{name}": "nonsense"
+                for name in _SWITCHES_GONE})
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "from gethsharding_tpu.ops import bn256_jax, limb\n"
+         "print(limb.NLIMBS, bn256_jax.NLIMBS)"],
+        env=env, cwd=_REPO, capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split() == ["25", "25"]

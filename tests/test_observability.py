@@ -4,7 +4,6 @@ surface."""
 
 import io
 import json
-import os
 import subprocess
 import sys
 import threading
@@ -622,42 +621,3 @@ def test_jax_compile_cache_shape_tracking(tracer):
     assert backend._m_shape_miss.value - misses0 == 3
     assert backend._m_shape_hit.value - hits0 == 1
     assert DEFAULT_REGISTRY.get("jax/compile_cache/misses") is not None
-
-
-def test_bench_trace_mode_emits_perfetto_profile(tmp_path):
-    """ACCEPTANCE: `bench.py --trace` produces a Chrome trace-event
-    JSON whose serving-request spans decompose into queue_wait /
-    batch_assembly / device_dispatch children summing (±5%) to the
-    parent span."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    trace_path = str(tmp_path / "bench_trace.json")
-    env = {**os.environ,
-           "JAX_PLATFORMS": "cpu",
-           "GETHSHARDING_BENCH_SERVING_CLIENTS": "4",
-           "GETHSHARDING_BENCH_SERVING_REQS": "2"}
-    out = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"), "--trace",
-         "--trace-out", trace_path],
-        capture_output=True, text=True, timeout=180, env=env, cwd=repo)
-    assert out.returncode == 0, out.stderr
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["metric"] == "serving_trace_profile"
-    assert line["extra"]["trace_out"] == trace_path
-    assert line["extra"]["traced_requests"] == 8
-
-    events = [e for e in json.load(open(trace_path))["traceEvents"]
-              if e["ph"] != "M"]  # skip the process_name merge metadata
-    assert line["extra"]["trace_events"] == len(events)
-    requests = [e for e in events
-                if e["name"] == "serving/ecrecover/request"]
-    assert len(requests) == 8
-    phases = {"serving/ecrecover/queue_wait",
-              "serving/ecrecover/batch_assembly",
-              "serving/ecrecover/device_dispatch"}
-    for req in requests:
-        kids = [e for e in events
-                if e["args"]["parent_id"] == req["args"]["span_id"]
-                and e["name"] in phases]
-        assert {k["name"] for k in kids} == phases
-        assert abs(sum(k["dur"] for k in kids) - req["dur"]) \
-            <= 0.05 * req["dur"]

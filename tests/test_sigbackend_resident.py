@@ -98,7 +98,7 @@ def test_warm_device_cache_ships_zero_g2_bytes():
     """The steady-state audit shape: identical keyed committees every
     dispatch. Cold ships the G2 planes; warm must ship ZERO G2 bytes
     (full device-cache hit) with an unchanged verdict — the acceptance
-    ledger `bench.py --resident` asserts at protocol scale."""
+    ledger, here at a small scale."""
     backend = JaxSigBackend()  # fresh cache; defaults (resident on)
     assert backend._resident
     rng = random.Random(99)
