@@ -58,15 +58,6 @@ from gethsharding_tpu.ops.limb import LIMB_BITS, LIMB_MASK, int_to_limbs
 
 BLOCK_LANES = 128
 
-
-def block_lanes() -> int:
-    """The mega-kernels' lane-block width — the natural granularity for
-    pipelining precomp Miller lane blocks against finalexp
-    (sigbackend/dispatch aligns GETHSHARDING_PRECOMP_BLOCKS slices to
-    it so a pipelined block never pads down to a partial lane
-    block)."""
-    return BLOCK_LANES
-
 # == self-contained wide-relaxed limb constants ============================
 # The kernel always computes in the 25-limb wide form with relaxed
 # normalization, independent of the ambient knobs (a 22-limb ambient form

@@ -104,20 +104,6 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
             raise ValueError(
                 f"GETHSHARDING_PRECOMP={precomp!r}: want 0 or 1")
         self._precomp = precomp == "1"
-        # GETHSHARDING_PRECOMP_BLOCKS: split the precomp dispatch into
-        # N lane blocks, enqueuing block k+1's Miller stage BEFORE
-        # block k's finalexp so the device overlaps sparse line
-        # evaluation with the previous block's finalexp mega-kernel.
-        # 1 = single fused dispatch (no pipelining).
-        blocks = os.environ.get("GETHSHARDING_PRECOMP_BLOCKS", "2")
-        try:
-            self._precomp_blocks = int(blocks)
-        except ValueError:
-            self._precomp_blocks = 0
-        if self._precomp_blocks < 1:
-            raise ValueError(
-                f"GETHSHARDING_PRECOMP_BLOCKS={blocks!r}: want a"
-                " positive integer")
 
         def _precompute_planes(px, py, pm):
             i32 = jnp.int32
@@ -135,16 +121,11 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
                 hx.astype(i32), hy.astype(i32), sx.astype(i32),
                 sy.astype(i32), sm, tab, inf, hok, gen_lines=gen)
 
-        def _precomp_miller(hx, hy, sx, sy, sm, tab, inf, hok, gen):
-            i32 = jnp.int32
-            return bn256_jax.bls_committee_precomp_miller(
-                hx.astype(i32), hy.astype(i32), sx.astype(i32),
-                sy.astype(i32), sm, tab, inf, hok, gen_lines=gen)
-
+        # ONE program over the whole bucket, as the recompute path has:
+        # rows lie on the lane axis and a step is bound by the number of
+        # operations, so a bucket cut into lane blocks pays each block's
+        # cost again (PERF.md section 6, PR 31)
         self._precomp_full = jax.jit(_precomp_full)
-        self._precomp_miller = jax.jit(_precomp_miller)
-        self._precomp_finalexp = jax.jit(
-            bn256_jax.bls_committee_precomp_finalexp)
         # the backend is a process-wide singleton shared by every actor
         # thread (get_backend caches instances): all cache structures
         # are lock-guarded (cache.py)
@@ -280,46 +261,6 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
     # partially-built test instances
     _gen_lines_dev = None
     _gen_lines_mesh = None
-
-    def _precomp_nblocks(self, bucket: int) -> int:
-        """Pipeline block count for a precomp dispatch: the largest
-        divisor of `bucket` not above GETHSHARDING_PRECOMP_BLOCKS, and
-        never splitting below the finalexp mega-kernel's lane block
-        (a partial block would pad back to BLOCK_LANES, wasting
-        lanes)."""
-        nb = min(self._precomp_blocks, bucket)
-        if nb > 1 and self._bn.FINALEXP == "mega":
-            from gethsharding_tpu.ops.pallas_finalexp import block_lanes
-
-            nb = min(nb, max(1, bucket // block_lanes()))
-        while nb > 1 and bucket % nb:
-            nb -= 1
-        return nb
-
-    def _precomp_launch(self, args, bucket: int, blocks: int):
-        """Launch the precomp committee dispatch: one fused kernel, or
-        `blocks` pipelined lane blocks. Block k+1's Miller stage is
-        enqueued BEFORE block k's finalexp, so the device overlaps the
-        sparse line evaluations with the previous block's finalexp
-        mega-kernel (every launch is async; the caller's pull is the
-        only barrier). Splitting is along the independent row axis —
-        per-row values, and therefore verdicts, are identical to the
-        fused launch."""
-        jnp = self._jnp
-        gen = self._gen_lines_dev
-        if blocks <= 1:
-            return self._precomp_full(*args, gen)
-        bs = bucket // blocks
-        staged = None
-        outs = []
-        for k in range(blocks):
-            blk = tuple(a[k * bs:(k + 1) * bs] for a in args)
-            nxt = self._precomp_miller(*blk, gen)
-            if staged is not None:
-                outs.append(self._precomp_finalexp(*staged))
-            staged = nxt
-        outs.append(self._precomp_finalexp(*staged))
-        return jnp.concatenate(outs)
 
     def ecrecover_addresses(self, digests, sigs65):
         import numpy as np
@@ -575,22 +516,18 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         launch = tracing.stage("sig/launch_time", _T_LAUNCH,
                                ctx=dt.span_ctx)
         if st["precomp"]:
-            with self._compiles.compile_span(
-                    "bls_committee_precomp",
-                    (st["bucket"], st["width"], self._wire,
-                     st["blocks"]), st["fresh"]), launch:
-                # async launch(es): the pipelined form enqueues Miller
-                # block k+1 before finalexp block k
-                out = self._precomp_launch(args, st["bucket"],
-                                           st["blocks"])
+            # the table-fed Miller and the final exponentiation, one
+            # program over the whole bucket like the recompute kernel
+            op, fn = "bls_committee_precomp", self._precomp_full
+            args += (self._gen_lines_dev,)
         else:
-            fn = (self._bls_committee_u16 if self._wire_u16
-                  else self._bls_committee)
-            with self._compiles.compile_span(
-                    "bls_committee",
-                    (st["bucket"], st["width"], self._wire),
-                    st["fresh"]), launch:
-                out = fn(*args)  # async dispatch: returns pre-execution
+            op, fn = "bls_committee", (
+                self._bls_committee_u16 if self._wire_u16
+                else self._bls_committee)
+        with self._compiles.compile_span(
+                op, (st["bucket"], st["width"], self._wire),
+                st["fresh"]), launch:
+            out = fn(*args)  # async dispatch: returns pre-execution
         # finalize must close over SCALARS, not the marshal dict: `st`
         # pins every host limb plane (MBs per dispatch) until result(),
         # and an overlapped K-period pipeline holds K of them at once
@@ -773,18 +710,14 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         # residents) — keyless or resident-off dispatches fall back to
         # the recompute kernel, today's path bit-for-bit
         precomp = self._precomp and resident
-        blocks = self._precomp_nblocks(bucket) if precomp else 0
         # the compile-cache key INCLUDES the wire dtype: the u16 wire
         # compiles a different XLA program for the same (bucket, width),
         # so counting it against the other wire's entry would book a
         # real recompile as a hit. The precomp path is its own op (line
-        # tables in, no G2 planes, its own block pipeline).
-        if precomp:
-            fresh = self._note_shape("bls_committee_precomp", bucket,
-                                     width, self._wire, blocks)
-        else:
-            fresh = self._note_shape("bls_committee", bucket, width,
-                                     self._wire)
+        # tables in, no G2 planes).
+        fresh = self._note_shape(
+            "bls_committee_precomp" if precomp else "bls_committee",
+            bucket, width, self._wire)
         check = os.environ.get("GETHSHARDING_CHECK") == "1"
         host = marshal.committee_host_planes(
             self._bn, messages, sig_rows, pad, width,
@@ -794,8 +727,7 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
               "pk_rows": sum(1 for r in rows if r),
               "hx": host["hx"], "hy": host["hy"], "hok": host["hok"],
               "sx": host["sx"], "sy": host["sy"], "sm": host["sm"],
-              "resident": resident, "precomp": precomp,
-              "blocks": blocks}
+              "resident": resident, "precomp": precomp}
         if precomp:
             self._line_resolve(st, rows, keys)
         elif resident:
@@ -847,7 +779,6 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
                 "pk_hit_rows": int(hit_rows),
                 "resident": st["resident"],
                 "precomp": st["precomp"],
-                "blocks": (int(st["blocks"]) if st["precomp"] else None),
                 "wire": self._wire}
         return args, wire
 

@@ -99,34 +99,9 @@ def test_precomp_flag_validation(monkeypatch):
     # flag off: no generator table is shipped at construction
     assert off._gen_lines_dev is None and off._gen_lines_mesh is None
     monkeypatch.setenv("GETHSHARDING_PRECOMP", "1")
-    monkeypatch.setenv("GETHSHARDING_PRECOMP_BLOCKS", "0")
-    with pytest.raises(ValueError):
-        JaxSigBackend()
-    monkeypatch.setenv("GETHSHARDING_PRECOMP_BLOCKS", "two")
-    with pytest.raises(ValueError):
-        JaxSigBackend()
-    monkeypatch.delenv("GETHSHARDING_PRECOMP_BLOCKS")
     on = JaxSigBackend()
-    assert on._precomp is True and on._precomp_blocks == 2  # the default
+    assert on._precomp is True  # the default
     assert on._gen_lines_dev is not None
-
-
-def test_precomp_nblocks_policy(monkeypatch):
-    """Pipeline blocks: largest divisor of the bucket not above the
-    flag, never splitting below the finalexp mega-kernel lane block."""
-    backend = JaxSigBackend()
-    monkeypatch.setattr(backend._bn, "FINALEXP", "jax", raising=False)
-    backend._precomp_blocks = 4
-    assert backend._precomp_nblocks(8) == 4
-    assert backend._precomp_nblocks(6) == 3  # largest divisor <= 4
-    assert backend._precomp_nblocks(7) == 1  # prime bucket: fused
-    assert backend._precomp_nblocks(1) == 1
-    monkeypatch.setattr(backend._bn, "FINALEXP", "mega", raising=False)
-    from gethsharding_tpu.ops.pallas_finalexp import block_lanes
-
-    lanes = block_lanes()
-    assert backend._precomp_nblocks(lanes) == 1  # one lane block: fused
-    assert backend._precomp_nblocks(4 * lanes) == 4  # lane-aligned split
 
 
 def test_count_ops_on_hlo_text():
@@ -208,6 +183,95 @@ def test_forged_empty_ragged_rows_match_scalar(monkeypatch, path):
                                         pk_row_keys=sent)
     assert got == want
     assert backend.last_wire["precomp"] is (path == "table_fed")
+
+
+def _keyed_rows(n_rows):
+    """`n_rows` keyed rows, every one three slots wide (width bucket 4,
+    the suite's), dealt in turn from the four kinds a period audit must
+    not get wrong: a ragged row (an absent voter's two infinity slots),
+    a forged vote under honest keys, an empty committee (no vote in any
+    slot) and a full row."""
+    def row(i):
+        kind = ("ragged", "forged", "empty", "full")[i % 4]
+        tag = b"one-program-%d" % i
+        members = [(i + j) % len(KEYPOOL) for j in range(3)]
+        sigs = [bls.bls_sign(tag, KEYPOOL[m][0]) for m in members]
+        pks = [KEYPOOL[m][1] for m in members]
+        if kind == "ragged":
+            sigs[1] = pks[1] = None
+        elif kind == "forged":
+            sigs[-1] = bls.bls_sign(b"forged", KEYPOOL[members[-1]][0])
+        elif kind == "empty":
+            sigs, pks = [None] * 3, [None] * 3
+        return tag, sigs, pks, (tuple(members), kind)
+
+    return tuple(list(col) for col in zip(*(row(i) for i in range(n_rows))))
+
+
+@pytest.mark.parametrize("bucket", [1, 4, 8])
+def test_keyed_dispatch_is_one_program_over_the_bucket(monkeypatch, bucket):
+    """A keyed dispatch launches ONE table-fed program over the whole
+    bucket (PR 31: no lane blocks, nothing chosen by a variable): one
+    call of the jitted kernel with the bucket as its leading axis, one
+    counted compile under a shape key of (bucket, width, wire), no
+    `blocks` in the wire ledger; and its verdicts on ragged, forged,
+    empty and full rows are the keyless recompute call's and the scalar
+    backend's, bit for bit."""
+    from gethsharding_tpu import devscope
+
+    monkeypatch.delenv("GETHSHARDING_TPU_WIRE", raising=False)
+    monkeypatch.setenv("GETHSHARDING_PRECOMP", "1")
+    heard = []
+    monkeypatch.setattr(devscope.COMPILES, "after_compile",
+                        lambda op, shape: heard.append((op, shape)))
+    backend = JaxSigBackend()
+    launched = []
+    full = backend._precomp_full
+
+    def counted(*args):
+        launched.append(args[0].shape[0])
+        return full(*args)
+
+    backend._precomp_full = counted
+    # 1, 3 and 7 rows: the last two leave the bucket a padded row
+    msgs, sig_rows, pk_rows, keys = _keyed_rows(max(1, bucket - 1))
+    assert JaxSigBackend._bucket(len(msgs)) == bucket
+    want = get_backend("python").bls_verify_committees(
+        msgs, sig_rows, pk_rows)
+    assert want == [kind in ("full", "ragged") for _, kind in keys]
+    for _ in range(2):  # cold (tables precomputed), then warm
+        assert backend.bls_verify_committees(
+            msgs, sig_rows, pk_rows, pk_row_keys=keys) == want
+        assert backend.last_wire["precomp"] is True
+        assert "blocks" not in backend.last_wire
+    assert launched == [bucket, bucket]
+    assert [shape for op, shape in heard
+            if op == "bls_committee_precomp"] == [(bucket, 4, "i32")]
+    assert {key for key in backend._shape_seen
+            if key[0] == "bls_committee_precomp"} \
+        == {("bls_committee_precomp", bucket, 4, "i32")}
+    # the keyless call takes the recompute kernel: the same verdicts
+    assert backend.bls_verify_committees(msgs, sig_rows, pk_rows) == want
+    assert backend.last_wire["precomp"] is False
+    assert launched == [bucket, bucket]
+
+
+def test_no_reader_of_the_block_variable_is_left():
+    """The lane-block variable went with the pipeline (PR 31): its name
+    stands in no file of the package, the tests, the benchmark, the
+    scripts, `chip_smoke.py` or the README."""
+    import pathlib
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    name = "GETHSHARDING_" + "PRECOMP_" + "BLOCKS"
+    files = [root / "chip_smoke.py", root / "README.md"]
+    for sub in ("gethsharding_tpu", "tests", "benchmark", "scripts"):
+        files += [f for f in (root / sub).rglob("*")
+                  if f.is_file() and f.suffix in
+                  (".py", ".md", ".json", ".sh", ".toml", ".txt")]
+    assert len(files) > 200  # the walk found the tree
+    assert [str(f.relative_to(root)) for f in files
+            if name in f.read_text(errors="replace")] == []
 
 
 def test_warm_line_tables_ship_zero_g2_bytes():
