@@ -25,6 +25,10 @@ The miss rows of a dispatch are precomputed together, padded to
 `marshal.bucket_size` of their number, so that the precompute is one
 compiled program per bucket, counted and settled like every other
 (`g2_line_precompute` in `jax/compile_cache/*` and the compile watch).
+That program hands back one table a row, and a second one
+(`line_table_stack`, one per dispatch bucket) stacks a dispatch's
+tables: between the miss planes' staging and the verify's launch no
+eager operation touches a table.
 Tables are keyed by `pk_row_key` alone — the on-device aggregate is a
 function of row content only, so one table serves every committee
 width and wire dtype. Entries are charged at their TRUE device byte
@@ -451,8 +455,10 @@ class ResidentPkCache:
         row = self._pk_zero_rows.get("lines")
         if row is None:
             jnp = self._jnp
-            row = (jnp.zeros(self._bn.LINE_TABLE_SHAPE, np.int32),
-                   jnp.asarray(True))
+            # staged from the host: no eager operation inside the stage
+            row = (jnp.asarray(np.zeros(self._bn.LINE_TABLE_SHAPE,
+                                        np.int32)),
+                   jnp.asarray(np.asarray(True)))
             self._pk_zero_rows["lines"] = row
         return row
 
@@ -517,23 +523,63 @@ class ResidentPkCache:
             [row for row, _ in misses] + [[]] * pad, width,
             row_keys=[key for _, key in misses])
 
+    def _counted_launch(self, op: str, shape: tuple, fn, *args):
+        """Launch a jitted program of the line-table path. A shape this
+        process has not launched before is a compile like any other:
+        counted by `_note_shape`, booked and settled by
+        `compile_span`."""
+        fresh = self._note_shape(op, *shape)
+        with self._compiles.compile_span(op, shape, fresh):
+            return fn(*args)
+
     def _precompute_lines(self, planes, width: int, *shape_tail):
-        """Launch the precompute over padded miss planes already on
-        their device. A bucket this process has not precomputed before
-        is a compile like any other: counted by `_note_shape`, booked
-        and settled by `compile_span`."""
+        """The precompute over padded miss planes already on their
+        device, one program per bucket: returns a table and a flag A
+        ROW, cut inside the program, the bucket's empty rows too."""
         shape = (int(planes[0].shape[0]), width, self._wire) + shape_tail
-        fresh = self._note_shape("g2_line_precompute", *shape)
-        with self._compiles.compile_span("g2_line_precompute", shape,
-                                         fresh):
-            return self._precompute(*planes)
+        return self._counted_launch("g2_line_precompute", shape,
+                                    self._precompute, *planes)
+
+    def _line_entries(self, tabs, infs, n: int) -> list:
+        """The LRU entries of a precompute's first `n` rows (the tables
+        of the bucket's empty rows are dropped). `nbytes` is the true
+        device byte count of an int32 table and its bool flag, from the
+        kernel's shape and dtypes: the same for every key."""
+        import math
+
+        import numpy as np
+
+        nbytes = (math.prod(self._bn.LINE_TABLE_SHAPE)
+                  * np.dtype(np.int32).itemsize + np.dtype(bool).itemsize)
+        return [(tabs[j], infs[j], None, nbytes) for j in range(n)]
+
+    def _stack_line_plan(self, plan, miss_dev, zero, *shape_tail):
+        """One (table, flag) a row of `plan` (zero row, hit, miss),
+        stacked into the (B, L, 3, 2, nl) table plane and the (B,)
+        infinity flags by ONE launch of 2 x B arguments, one program per
+        B: an eager `jnp.stack` is two dispatches a row."""
+        ts, fs = [], []
+        for step in plan:
+            if step[0] == "zero":
+                entry = zero
+            elif step[0] == "hit":
+                entry = step[1]
+            else:
+                entry = miss_dev[step[1]]
+            ts.append(entry[0])
+            fs.append(entry[1])
+        return self._counted_launch(
+            "line_table_stack", (len(ts),) + shape_tail,
+            self._stack_lines, ts, fs)
 
     def _line_tables(self, st: dict):
         """Device half of the precomp path: ONE precompute dispatch
         walks the fixed-argument point arithmetic for ALL miss rows
-        (cold cost, paid once per key), then hits + misses + zeros stack
-        into the (B, L, 3, 2, nl) table plane + (B,) infinity flags.
-        Returns (table, inf, transferred_g2_bytes)."""
+        (cold cost, paid once per key) and hands back one table a row,
+        then ONE stack dispatch assembles hits + misses + zeros into
+        the (B, L, 3, 2, nl) table plane + (B,) infinity flags. No
+        eager operation touches a table. Returns (table, inf,
+        transferred_g2_bytes)."""
         jnp = self._jnp
         if st["line_memo"] is not None:
             tab, inf = st["line_memo"]
@@ -551,26 +597,14 @@ class ResidentPkCache:
                 tabs, infs = self._precompute_lines(
                     (jnp.asarray(mx), jnp.asarray(my), jnp.asarray(mm)),
                     st["width"])
-                # the tables of the bucket's empty rows are dropped
-                for j, key in enumerate(st["line_miss_keys"]):
-                    nbytes = int(tabs[j].nbytes) + int(infs[j].nbytes)
-                    entry = (tabs[j], infs[j], None, nbytes)
+                keys = st["line_miss_keys"]
+                miss_dev = self._line_entries(tabs, infs, len(keys))
+                for key, entry in zip(keys, miss_dev):
                     if key is not None:
                         self._pk_dev_insert((key, "lines"), entry)
-                    miss_dev.append(entry)
         with tracing.stage("sig/line_stack_time", _T_LINE_STACK):
-            zt, zi = self._zero_line_row()
-            ts, fs = [], []
-            for step in st["line_plan"]:
-                if step[0] == "zero":
-                    entry = (zt, zi)
-                elif step[0] == "hit":
-                    entry = step[1]
-                else:
-                    entry = miss_dev[step[1]]
-                ts.append(entry[0])
-                fs.append(entry[1])
-            tab, inf = jnp.stack(ts), jnp.stack(fs)
+            tab, inf = self._stack_line_plan(
+                st["line_plan"], miss_dev, self._zero_line_row())
             if st["line_key"] is not None:
                 with self._pk_dev_lock:
                     self._pk_line_memo = (st["line_key"], (tab, inf),
@@ -833,7 +867,6 @@ class ResidentPkCache:
         (table, inf, transferred g2_bytes)."""
         import jax
 
-        jnp = self._jnp
         width, bucket = st["width"], st["bucket"]
         rpd = layout.rows_per_device(bucket)
         if keys is not None and all(
@@ -894,26 +927,15 @@ class ResidentPkCache:
                     tuple(jax.device_put(plane, shard.device)
                           for plane in (mx, my, mm)),
                     width, shard.index)
-                for j, (row, key) in enumerate(misses):
-                    nbytes = int(tabs[j].nbytes) + int(infs[j].nbytes)
-                    entry = (tabs[j], infs[j], None, nbytes)
+                miss_dev = self._line_entries(tabs, infs, len(misses))
+                for (_, key), entry in zip(misses, miss_dev):
                     if key is not None:
                         self._mesh_shard_insert(
                             shard, (key, "lines"), entry)
-                    miss_dev.append(entry)
-            zt, zi = self._mesh_zero_line(shard)
-            ts, fs = [], []
-            for step in plan:
-                if step[0] == "zero":
-                    entry = (zt, zi)
-                elif step[0] == "hit":
-                    entry = step[1]
-                else:
-                    entry = miss_dev[step[1]]
-                ts.append(entry[0])
-                fs.append(entry[1])
-            per_t.append(jnp.stack(ts))
-            per_i.append(jnp.stack(fs))
+            slab_t, slab_i = self._stack_line_plan(
+                plan, miss_dev, self._mesh_zero_line(shard), shard.index)
+            per_t.append(slab_t)
+            per_i.append(slab_i)
         tab = layout.assemble(per_t)
         inf = layout.assemble(per_i)
         self._m_dev_hit.inc(hit_rows)

@@ -107,13 +107,27 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
 
         def _precompute_planes(px, py, pm):
             i32 = jnp.int32
-            return bn256_jax.precompute_g2_lines(
+            tabs, infs = bn256_jax.precompute_g2_lines(
                 px.astype(i32), py.astype(i32), pm)
+            # cut INSIDE the program: one buffer a row is what the LRU
+            # stores, and an eager `tabs[j]` on the host is three
+            # dispatches a key (PERF.md section 6, PR 34)
+            rows = range(px.shape[0])
+            return (tuple(tabs[j] for j in rows),
+                    tuple(infs[j] for j in rows))
 
         # one precompute jit serves every layout: committed inputs keep
         # the dispatch on the owning device (mesh shards included); the
         # astype is a no-op on the i32 wire
         self._precompute = jax.jit(_precompute_planes)
+
+        def _stack_lines(tabs, infs):
+            return jnp.stack(tabs), jnp.stack(infs)
+
+        # its inverse: one table and one flag a row (zero row, hit,
+        # miss) into the (B, L, 3, 2, nl) and (B,) planes of the
+        # table-fed verify, in one launch of 2 x B arguments
+        self._stack_lines = jax.jit(_stack_lines)
 
         def _precomp_full(hx, hy, sx, sy, sm, tab, inf, hok, gen):
             i32 = jnp.int32

@@ -163,7 +163,8 @@ def test_miss_counts_share_a_bucket_and_each_bucket_is_a_counted_compile(
         return [shape for op, shape in heard if op == "g2_line_precompute"]
 
     # the first request also compiles the committee kernel's own shape
-    assert send(4, 0) == [2, 2, 4]
+    # and the stack program of its dispatch bucket (PR 34)
+    assert send(4, 0) == [3, 3, 4]
     assert precomputes() == [(4, 4, "i32")]
     assert send(1, 1) == [1, 1, 1]
     assert send(2, 2) == [1, 1, 2]
@@ -171,7 +172,10 @@ def test_miss_counts_share_a_bucket_and_each_bucket_is_a_counted_compile(
     # three misses pad to the bucket of four: no new program
     assert send(3, 3) == [0, 0, 3]
     assert send(1, 4) == [0, 0, 1]
-    assert len(heard) == 4
+    # one stack program a DISPATCH bucket, whatever the miss count
+    assert [shape for op, shape in heard
+            if op == "line_table_stack"] == [(4,)]
+    assert len(heard) == 5
     noted = {key[1:] for key in backend._shape_seen
              if key[0] == "g2_line_precompute"}
     assert noted == {(1, 4, "i32"), (2, 4, "i32"), (4, 4, "i32")}

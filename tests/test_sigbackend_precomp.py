@@ -256,6 +256,160 @@ def test_keyed_dispatch_is_one_program_over_the_bucket(monkeypatch, bucket):
     assert launched == [bucket, bucket]
 
 
+@functools.lru_cache(maxsize=1)
+def _reference_precompute():
+    """The precompute as the kernel module gives it, one (B, ...) table
+    plane and one (B,) flag plane: no cut, no stack."""
+    import jax
+
+    from gethsharding_tpu.ops import bn256_jax
+
+    return jax.jit(bn256_jax.precompute_g2_lines)
+
+
+def _assembly_batch(case):
+    """(resident, batch, plan) of one assembly case, every batch four
+    rows of `_keyed_rows` (bucket 4, width 4). `resident` is dispatched
+    first to fill the LRU (None: nothing); `plan` names what each row
+    of `batch` must then be: "miss", "hit" or "zero"."""
+    msgs, sig_rows, pk_rows, keys = _keyed_rows(4)
+    first = (msgs, sig_rows, pk_rows, keys)
+
+    def rekeyed(plan):
+        cols = [list(col) for col in first]
+        for i, kind in enumerate(plan):
+            if kind == "miss":
+                cols[3][i] = (case,) + keys[i]
+            elif kind == "zero":
+                cols[1][i], cols[2][i], cols[3][i] = [], [], None
+        return tuple(cols), plan
+
+    if case == "all_miss":      # four misses: the precompute's bucket 4
+        return (None,) + rekeyed(["miss"] * 4)
+    if case == "all_hit":       # by key: the test clears the line memo
+        return (first,) + rekeyed(["hit"] * 4)
+    if case == "three_miss":    # three misses share the bucket of four
+        return (first,) + rekeyed(["miss", "hit", "miss", "miss"])
+    if case == "mixed":         # zero, hit and miss, no kind together
+        return (first,) + rekeyed(["miss", "zero", "hit", "miss"])
+    raise ValueError(case)
+
+
+@pytest.mark.parametrize("case",
+                         ["all_miss", "all_hit", "three_miss", "mixed"])
+def test_assembled_tables_are_the_precomputes_bit_for_bit(monkeypatch, case):
+    """What the two launches hand the table-fed verify (PR 34: the cut
+    inside the precompute's program, the jitted stack) is, bit for bit,
+    `np.stack` of the per-row tables the kernel module's precompute
+    gives, with the zero table and a set flag on an empty row; for a
+    plan of misses alone, of hits alone (by key, the line memo
+    cleared), of three misses in the bucket of four and of zero, hit
+    and miss in no order. The verdicts are the keyless
+    recompute call's and the scalar backend's."""
+    import numpy as np
+
+    monkeypatch.delenv("GETHSHARDING_TPU_WIRE", raising=False)
+    monkeypatch.setenv("GETHSHARDING_PRECOMP", "1")
+    backend = JaxSigBackend()
+    resident, batch, plan = _assembly_batch(case)
+    if resident is not None:
+        backend.bls_verify_committees(*resident[:3], pk_row_keys=resident[3])
+        with backend._pk_dev_lock:
+            backend._pk_line_memo = None    # hits go by key, not by memo
+    assembled, plans = [], []
+    line_tables = backend._line_tables
+
+    def captured(st):
+        plans.append([step[0] for step in st["line_plan"]])
+        assembled.append(line_tables(st))
+        return assembled[-1]
+
+    backend._line_tables = captured
+    misses = metrics.counter("jax/pk_device_cache/misses")
+    before = misses.value
+    msgs, sig_rows, pk_rows, keys = batch
+    got = backend.bls_verify_committees(msgs, sig_rows, pk_rows,
+                                        pk_row_keys=keys)
+    assert plans == [plan]
+    assert misses.value - before == plan.count("miss")
+    want = get_backend("python").bls_verify_committees(
+        msgs, sig_rows, pk_rows)
+    assert got == want
+    assert backend.bls_verify_committees(msgs, sig_rows, pk_rows) == want
+    tab, inf, g2_bytes = assembled[0]
+    assert (g2_bytes > 0) == ("miss" in plan)
+    px, py, pm = backend._pk_rows_to_limbs(pk_rows, 4)
+    ref_tab, ref_inf = (np.array(a)
+                        for a in _reference_precompute()(px, py, pm))
+    for i, kind in enumerate(plan):
+        if kind == "zero":
+            ref_tab[i], ref_inf[i] = 0, True
+    assert ref_tab.any() and not ref_inf.all()
+    assert tab.dtype == ref_tab.dtype and inf.dtype == ref_inf.dtype
+    assert np.array_equal(np.asarray(tab), ref_tab)
+    assert np.array_equal(np.asarray(inf), ref_inf)
+
+
+def test_a_miss_dispatch_is_two_launches_and_no_eager_operation(monkeypatch):
+    """Between the miss planes' staging and the table-fed verify's
+    launch a table is touched by two jitted programs and by nothing
+    else (PR 34): `_line_tables` calls the precompute once and the
+    stack once, and binds NO primitive eagerly (the hook:
+    `jax._src.core.EvalTrace.process_primitive`, through which every
+    eager operation runs and a jitted call does not), on a backend's
+    first dispatch and on a later one; a memo hit launches neither."""
+    import jax.numpy as jnp
+    from jax._src import core
+
+    monkeypatch.delenv("GETHSHARDING_TPU_WIRE", raising=False)
+    monkeypatch.setenv("GETHSHARDING_PRECOMP", "1")
+    backend = JaxSigBackend()
+    launched, eager, listening = [], [], []
+    for name in ("_precompute", "_stack_lines"):
+        def counted(*args, _fn=getattr(backend, name), _name=name):
+            launched.append(_name)
+            return _fn(*args)
+
+        setattr(backend, name, counted)
+    process_primitive = core.EvalTrace.process_primitive
+
+    def heard(self, primitive, args, params):
+        if listening:
+            eager.append(primitive.name)
+        return process_primitive(self, primitive, args, params)
+
+    monkeypatch.setattr(core.EvalTrace, "process_primitive", heard)
+    line_tables = backend._line_tables
+
+    def listened(st):
+        listening.append(True)
+        try:
+            return line_tables(st)
+        finally:
+            listening.pop()
+
+    backend._line_tables = listened
+    # the hook hears what the old path did: an eager index and a stack
+    listening.append(True)
+    rows = jnp.zeros((2, 3), jnp.int32)
+    jnp.stack([rows[0], rows[1]])
+    listening.pop()
+    assert {"concatenate", "squeeze"} <= set(eager)
+    del eager[:]
+    msgs, sig_rows, pk_rows, keys = _keyed_rows(4)
+    want = [kind in ("full", "ragged") for _, kind in keys]
+    for stamp in (0, 1):    # the backend's first dispatch, and a later
+        fresh = [(stamp,) + key for key in keys]
+        assert backend.bls_verify_committees(
+            msgs, sig_rows, pk_rows, pk_row_keys=fresh) == want
+        assert launched == ["_precompute", "_stack_lines"] * (stamp + 1)
+    assert backend.bls_verify_committees(
+        msgs, sig_rows, pk_rows, pk_row_keys=fresh) == want  # the memo
+    assert backend.last_wire["g2_wire_bytes"] == 0
+    assert launched == ["_precompute", "_stack_lines"] * 2
+    assert eager == []
+
+
 def test_no_reader_of_the_block_variable_is_left():
     """The lane-block variable went with the pipeline (PR 31): its name
     stands in no file of the package, the tests, the benchmark, the

@@ -80,15 +80,16 @@ class Served:
         self.client = RpcReplicaBackend.dial(*self.server.address,
                                              timeout=600.0)
 
-    def request(self, keyed=False):
+    def request(self, keyed=False, again=False):
         """One keyless request, or one of `_keyed_committees` under row
-        keys never sent before, its verdicts checked; returns once the
-        server has booked it (it books after it flushes the response)."""
+        keys never sent before (`again`: under the last keyed request's),
+        its verdicts checked; returns once the server has booked it (it
+        books after it flushes the response)."""
         booked = metrics.timer(RPC + "server_time")
         count = booked.count
         args, want = self.args, self.want
         if keyed:
-            self.keyed_sent += 1
+            self.keyed_sent += not again
             *args, want = self.keyed
             args.append([("stage", self.keyed_sent, r) for r in range(4)])
         t0 = time.monotonic()
@@ -152,6 +153,15 @@ def keyed(served, untraced):
     latency = served.request(keyed=True)
     after = served.client.metrics()
     return {"before": before, "after": after, "latency_s": latency}
+
+
+@pytest.fixture(scope="module")
+def keyed_again(served, keyed):
+    """One request under the row keys of the keyed request before it:
+    the batch memo holds its tables."""
+    before = served.client.metrics()
+    served.request(keyed=True, again=True)
+    return {"before": before, "after": served.client.metrics()}
 
 
 @pytest.fixture(scope="module")
@@ -229,6 +239,14 @@ def test_a_line_table_miss_enters_each_line_stage_once(untraced, keyed,
                                                        name):
     assert _delta(untraced, name) == 0      # no row keys: no line table
     assert _delta(keyed, name) == 1
+
+
+@pytest.mark.parametrize("name", LINE_STAGES)
+def test_a_memo_hit_enters_no_line_stage(keyed_again, name):
+    assert _delta(keyed_again, "sig/transfer_time") == 1
+    assert _delta(keyed_again, name) == 0
+    assert _delta(keyed_again, "jax/pk_device_cache/misses") == 0
+    assert _delta(keyed_again, "jax/wire/g2_bytes") == 0
 
 
 def test_the_transfer_stage_covers_the_line_stages(keyed):
