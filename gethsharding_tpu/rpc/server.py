@@ -16,6 +16,7 @@ from __future__ import annotations
 import json
 import logging
 import os
+import queue
 import socket
 import socketserver
 import threading
@@ -216,9 +217,22 @@ class RPCServer:
     def _handle_connection(self, handler) -> None:
         write_lock = threading.Lock()
         slots = threading.BoundedSemaphore(max(1, CONN_CONCURRENCY))
+        # The connection's workers outlive their requests: a request
+        # runs on an idle worker where there is one and on a new thread
+        # only when all are busy. A thread a request was measured at
+        # 230 ms of `json.loads` on a 14 MB `shard_dasVerify` frame
+        # where the main thread takes 18 (PERF.md section 6, PR 35): a
+        # new thread is handed another malloc arena, in turn, once the
+        # process has many threads, and the frame's 1,600 strings of 8
+        # KB grow that arena page by page every time. A thread that
+        # stays keeps the arena it has grown.
+        todo: "queue.SimpleQueue" = queue.SimpleQueue()
         workers = []
+        idle_lock = threading.Lock()
+        idle = 0    # workers waiting for a request, under idle_lock
 
         def serve_one(raw: bytes, t_read: float) -> None:
+            nonlocal idle
             marks = _Marks(t_read)
             try:
                 try:
@@ -227,6 +241,11 @@ class RPCServer:
                 finally:
                     with self._sub_lock:
                         self._inflight -= 1
+                    # idle from here, before the response goes out: a
+                    # caller that answers its answer at once must find
+                    # this worker, not none
+                    with idle_lock:
+                        idle += 1
                 if response is not None:
                     with write_lock:
                         handler.wfile.write(
@@ -238,11 +257,17 @@ class RPCServer:
                 slots.release()
                 self._book(marks, time.monotonic())
 
+        def work() -> None:
+            for raw, t_read in iter(todo.get, None):
+                serve_one(raw, t_read)
+                raw = None  # a waiting worker pins no frame
+
         try:
             for raw in handler.rfile:
                 t_read = time.monotonic()
-                raw = raw.strip()
-                if not raw:
+                # json.loads takes the line's whitespace; no copy of a
+                # frame of megabytes to strip it
+                if raw.isspace():
                     continue
                 with self._sub_lock:
                     self._inflight += 1
@@ -251,19 +276,23 @@ class RPCServer:
                 # once CONN_CONCURRENCY requests are in flight the read
                 # loop blocks here — TCP backpressure to the sender
                 slots.acquire()
-                worker = threading.Thread(target=serve_one,
-                                          args=(raw, t_read), daemon=True,
-                                          name="rpc-conn-worker")
-                workers.append(worker)
-                worker.start()
-                if len(workers) > CONN_CONCURRENCY:
-                    workers = [w for w in workers if w.is_alive()]
+                with idle_lock:
+                    spawn = idle == 0
+                    idle -= not spawn
+                if spawn:
+                    worker = threading.Thread(target=work, daemon=True,
+                                              name="rpc-conn-worker")
+                    workers.append(worker)
+                    worker.start()
+                todo.put((raw, t_read))
         except (OSError, ValueError):
             pass
         finally:
             # drain in-flight workers briefly (shared deadline, not
             # per-thread): their responses are undeliverable now, and
             # they are daemons — this just keeps teardown orderly
+            for _ in workers:
+                todo.put(None)
             deadline = time.monotonic() + 1.0
             for worker in workers:
                 worker.join(timeout=max(0.0, deadline - time.monotonic()))

@@ -39,9 +39,9 @@ from gethsharding_tpu.sigbackend import marshal
 from gethsharding_tpu.sigbackend.cache import ResidentPkCache
 from gethsharding_tpu.sigbackend.marshal import bucket_size
 
-# the committee dispatch's host stages, the parts of DeviceTimer's
-# sig/marshal_time (host_marshal + transfer) and sig/device_time (launch,
-# then perfwatch/timer.py's block and pull)
+# the host stages of the committee and the DAS sample dispatches, the
+# parts of DeviceTimer's sig/marshal_time (host_marshal + transfer) and
+# sig/device_time (launch, then perfwatch/timer.py's block and pull)
 _T_HOST_MARSHAL = metrics.timer("sig/host_marshal_time")
 _T_TRANSFER = metrics.timer("sig/transfer_time")
 _T_LAUNCH = metrics.timer("sig/launch_time")
@@ -147,6 +147,8 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         self._m_wire_bytes = metrics.counter("jax/wire/bytes")
         # the G2 part of it: pk planes shipped cold (0 on a warm audit)
         self._m_g2_bytes = metrics.counter("jax/wire/g2_bytes")
+        # the chunk plane's part of jax/wire/bytes on the DAS sample path
+        self._m_das_chunk_bytes = metrics.counter("das/wire/chunk_bytes")
         self._m_pk_hit_bytes = metrics.counter("jax/wire/pk_device_hit_bytes")
         # device-time attribution rollups (sig/{marshal_time,
         # device_time}) are fed by the perfwatch DeviceTimer each
@@ -392,10 +394,18 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         dt = DeviceTimer("das_verify")
         bucket = self._bucket(n)
         fresh = self._note_shape("das_verify", bucket)
-        st = das_proofs.marshal_samples(chunks, indices, proofs, roots,
-                                        bucket)
+        # the committee path's stages (below), so that one set of timers
+        # and per-layer metrics reads every op: host marshal and transfer
+        # inside the marshal phase, the launch inside the device phase
+        with tracing.stage("sig/host_marshal_time", _T_HOST_MARSHAL):
+            st = das_proofs.marshal_samples(chunks, indices, proofs, roots,
+                                            bucket)
         planes = (st["chunks"], st["sibs"], st["bits"], st["levels"],
                   st["roots"], st["valid"])
+        # the staging and the enqueue of the copies, not their
+        # completion: the launch below waits for no transfer
+        with tracing.stage("sig/transfer_time", _T_TRANSFER):
+            args = tuple(jnp.asarray(p) for p in planes)
         sample_bytes = sum(int(p.nbytes) for p in planes)
         # the per-dispatch wire ledger (same contract as the committee
         # path: pure nbytes arithmetic, no device sync) — the sample
@@ -406,16 +416,21 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
                           "rows": n, "bucket": bucket, "wire": self._wire}
         RECORDER.record_wire("das_verify_samples", self.last_wire)
         self._m_wire_bytes.inc(sample_bytes)
+        # the chunk plane's part of it: bucket x 4,096, padding included
+        self._m_das_chunk_bytes.inc(int(st["chunks"].nbytes))
         tracing.tag_current_add(wire_bytes=sample_bytes,
                                 sample_wire_bytes=sample_bytes)
-        dt.dispatched()
-        with self._compiles.compile_span("das_verify", (bucket,), fresh):
-            out = das_proofs.batch_verifier()(
-                *(jnp.asarray(p) for p in planes))
+        dt.dispatched()  # marshal (incl. transfer staging) closes here
+        launch = tracing.stage("sig/launch_time", _T_LAUNCH,
+                               ctx=dt.span_ctx)
+        with self._compiles.compile_span("das_verify", (bucket,),
+                                         fresh), launch:
+            out = das_proofs.batch_verifier()(*args)
         res = [bool(b) for b in dt.pull(out)[:n]]
         dt.done()
         dt.record_span("jax/das_verify_dispatch", rows=n, bucket=bucket,
                        compile="miss" if fresh else "hit",
+                       wire_bytes=sample_bytes,
                        sample_wire_bytes=sample_bytes)
         return res
 

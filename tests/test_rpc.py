@@ -624,3 +624,66 @@ def test_bootnode_introduction_without_a_chain():
             b.stop()
     finally:
         server.stop()
+
+
+class _ThreadTellingServer(RPCServer):
+    """Two methods that say which thread served them."""
+
+    def rpc_threadIdent(self):
+        import threading
+
+        return threading.get_ident()
+
+    def rpc_heldThreadIdent(self, seconds):
+        import threading
+
+        time.sleep(seconds)
+        return threading.get_ident()
+
+
+def test_a_connections_workers_outlive_their_requests():
+    """Requests that follow one another on a connection run on ONE
+    thread (ISSUE 35: a new thread a request paid 230 ms of malloc arena
+    growth on every 14 MB frame); requests in flight together run on a
+    thread each, which later requests then find idle; a blank line is no
+    request; closing the connection ends its workers."""
+    import threading
+    from concurrent.futures import ThreadPoolExecutor
+
+    from gethsharding_tpu.rpc.client import RPCClient
+
+    def all_workers():
+        return {t.ident for t in threading.enumerate()
+                if t.name == "rpc-conn-worker"}
+
+    # an earlier test's connection that is still open keeps its workers
+    others = all_workers()
+
+    def conn_workers():
+        return sorted(all_workers() - others)
+
+    server = _ThreadTellingServer(SimulatedMainchain())
+    server.start()
+    try:
+        client = RPCClient(*server.address)
+        client._file.write(b" \n")      # a blank line between frames
+        client._file.flush()
+        serial = {client.call("shard_threadIdent") for _ in range(5)}
+        assert len(serial) == 1
+        with ThreadPoolExecutor(3) as pool:
+            held = list(pool.map(
+                lambda _: client.call("shard_heldThreadIdent", 0.3),
+                range(3)))
+        assert len(set(held)) == 3 and serial <= set(held)
+        assert conn_workers() == sorted(held)
+        # all three are idle now: nothing is spawned for what follows
+        assert {client.call("shard_threadIdent")
+                for _ in range(5)} <= set(held)
+        assert conn_workers() == sorted(held)
+        other = RPCClient(*server.address)
+        assert other.call("shard_threadIdent") not in held
+        other.close()
+        client.close()
+        assert wait_until(lambda: conn_workers() == [])
+    finally:
+        server.stop()

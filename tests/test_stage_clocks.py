@@ -1,6 +1,7 @@
-"""Stage clocks (ISSUE 26): one `shard_verifyCommittees` request through
-an in-process RPC server over the jax backend, at a tiny shape on the
-CPU, read three ways: the registry timers (always on), the tracer's
+"""Stage clocks (ISSUE 26): one `shard_verifyCommittees` request (and,
+since ISSUE 35, one `shard_dasVerify` request: the same tests, a case
+each) through an in-process RPC server over the jax backend, at a tiny
+shape on the CPU, read three ways: the registry timers (always on), the tracer's
 spans (one trace id from the client's call to the device pull), and the
 benchmark's per-layer metric files over two `shard_metrics` snapshots.
 Plus the primitive alone, the collector's clock and the kernels' names.
@@ -23,19 +24,32 @@ from gethsharding_tpu.crypto import bn256 as bls
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 OP = "bls_committee"
 RPC = "rpc/verifyCommittees/"
-STAGE_TIMERS = (RPC + "server_time", RPC + "parse_time", RPC + "decode_time",
-                "sig/host_marshal_time", "sig/transfer_time",
-                "sig/launch_time", "sig/block_time", "sig/pull_time")
-SIG_SPANS = STAGE_TIMERS[3:]
+# the RPC methods whose request enters every stage, each with its serving
+# op, the span of its dispatch and the fixtures that hold one request of it
+METHODS = {
+    "verifyCommittees": {"op": OP, "dispatch": "jax/bls_committee_dispatch",
+                         "untraced": "untraced", "traced": "traced"},
+    "dasVerify": {"op": "das_verify", "dispatch": "jax/das_verify_dispatch",
+                  "untraced": "das_untraced", "traced": "das_traced"},
+}
+SIG_SPANS = ("sig/host_marshal_time", "sig/transfer_time",
+             "sig/launch_time", "sig/block_time", "sig/pull_time")
+# `{rpc}` is the method's `rpc/<m>/`, `{op}` its serving op
+STAGE_TIMERS = ("{rpc}server_time", "{rpc}parse_time", "{rpc}decode_time",
+                *SIG_SPANS)
 # each whole covers its parts
 WHOLES = {
     "sig/marshal_time": ("sig/host_marshal_time", "sig/transfer_time"),
     "sig/device_time": ("sig/launch_time", "sig/block_time",
                         "sig/pull_time"),
-    RPC + "server_time": (RPC + "parse_time", RPC + "decode_time",
-                          f"serving/{OP}/wait_time",
-                          f"serving/{OP}/dispatch_latency"),
+    "{rpc}server_time": ("{rpc}parse_time", "{rpc}decode_time",
+                         "serving/{op}/wait_time",
+                         "serving/{op}/dispatch_latency"),
 }
+
+
+def _named(name, method):
+    return name.format(rpc=f"rpc/{method}/", op=METHODS[method]["op"])
 
 
 def _committees():
@@ -61,6 +75,22 @@ def _keyed_committees():
     return msgs, sig_rows, [[pk for _, pk in keys] for _ in msgs], [True] * 4
 
 
+def _das_samples():
+    """Four sampled rows of one three-chunk body, one of them withheld:
+    bucket 4, the shape `tests/test_das_period.py` keeps warm."""
+    from gethsharding_tpu.das import erasure, proofs
+
+    body = erasure.extend_body(bytes(range(256)) * 32, parity_ratio=0.5)
+    levels = proofs.merkle_levels([proofs.chunk_leaf(c)
+                                   for c in body.chunks])
+    indices = [0, 1, 2, 1]
+    chunks = [body.chunks[i] for i in indices]
+    chunks[3] = bytes(reversed(chunks[3]))
+    return (chunks, indices, [proofs.merkle_proof(levels, i)
+                              for i in indices],
+            [levels[-1][0]] * 4, [True, True, True, False])
+
+
 class Served:
     """The in-process server, its client and one request's arguments."""
 
@@ -73,6 +103,7 @@ class Served:
 
         *self.args, self.want = _committees()
         self.keyed, self.keyed_sent = _keyed_committees(), 0
+        self.das = _das_samples()
         self.serving = ServingSigBackend(JaxSigBackend())
         self.server = RPCServer(SimulatedMainchain(),
                                 sig_backend=self.serving)
@@ -80,20 +111,24 @@ class Served:
         self.client = RpcReplicaBackend.dial(*self.server.address,
                                              timeout=600.0)
 
-    def request(self, keyed=False, again=False):
+    def request(self, keyed=False, again=False, method="verifyCommittees"):
         """One keyless request, or one of `_keyed_committees` under row
         keys never sent before (`again`: under the last keyed request's),
-        its verdicts checked; returns once the server has booked it (it
-        books after it flushes the response)."""
-        booked = metrics.timer(RPC + "server_time")
+        or one `shard_dasVerify` of `_das_samples`, its verdicts checked;
+        returns once the server has booked it (it books after it flushes
+        the response)."""
+        booked = metrics.timer(f"rpc/{method}/server_time")
         count = booked.count
-        args, want = self.args, self.want
-        if keyed:
+        call, args, want = (self.client.bls_verify_committees, self.args,
+                            self.want)
+        if method == "dasVerify":
+            call, (*args, want) = self.client.das_verify_samples, self.das
+        elif keyed:
             self.keyed_sent += not again
             *args, want = self.keyed
             args.append([("stage", self.keyed_sent, r) for r in range(4)])
         t0 = time.monotonic()
-        assert self.client.bls_verify_committees(*args) == want
+        assert call(*args) == want
         latency = time.monotonic() - t0
         deadline = time.monotonic() + 10.0
         while booked.count == count and time.monotonic() < deadline:
@@ -130,18 +165,39 @@ def untraced(served):
             "spans": tracing.TRACER.spans_recorded - spans}
 
 
-@pytest.fixture(scope="module")
-def traced(served, untraced):
-    """The spans of one request with the tracer on (client and server
-    share the process, so one ring holds both ends)."""
+def _traced_request(served, **kind):
     tracing.enable(ring_spans=4096)
     tracing.TRACER.clear()
     try:
-        served.request()
+        served.request(**kind)
         return tracing.TRACER.recent_spans()
     finally:
         tracing.disable()
         tracing.TRACER.clear()
+
+
+@pytest.fixture(scope="module")
+def traced(served, untraced):
+    """The spans of one request with the tracer on (client and server
+    share the process, so one ring holds both ends)."""
+    return _traced_request(served)
+
+
+@pytest.fixture(scope="module")
+def das_untraced(served, untraced):
+    """One `shard_dasVerify` request with the tracer off, between two
+    `shard_metrics`, after one that compiled or read the cache."""
+    served.request(method="dasVerify")
+    before = served.client.metrics()
+    latency = served.request(method="dasVerify")
+    after = served.client.metrics()
+    return {"before": before, "after": after, "latency_s": latency}
+
+
+@pytest.fixture(scope="module")
+def das_traced(served, das_untraced):
+    """The spans of one such request with the tracer on."""
+    return _traced_request(served, method="dasVerify")
 
 
 @pytest.fixture(scope="module")
@@ -167,14 +223,7 @@ def keyed_again(served, keyed):
 @pytest.fixture(scope="module")
 def keyed_traced(served, keyed):
     """The spans of one such request with the tracer on."""
-    tracing.enable(ring_spans=4096)
-    tracing.TRACER.clear()
-    try:
-        served.request(keyed=True)
-        return tracing.TRACER.recent_spans()
-    finally:
-        tracing.disable()
-        tracing.TRACER.clear()
+    return _traced_request(served, keyed=True)
 
 
 @pytest.fixture(scope="module")
@@ -220,18 +269,23 @@ def _delta(snap, name, field="count"):
 # == the registry: always on ================================================
 
 
+@pytest.mark.parametrize("method", sorted(METHODS))
 @pytest.mark.parametrize("name", STAGE_TIMERS)
-def test_one_request_counts_once_in_every_stage_timer(untraced, name):
-    assert _delta(untraced, name) == 1
+def test_one_request_counts_once_in_every_stage_timer(request, method, name):
+    snap = request.getfixturevalue(METHODS[method]["untraced"])
+    assert _delta(snap, _named(name, method)) == 1
 
 
+@pytest.mark.parametrize("method", sorted(METHODS))
 @pytest.mark.parametrize("whole", sorted(WHOLES))
-def test_each_whole_covers_its_parts(untraced, whole):
-    assert _delta(untraced, whole) == 1
-    parts = sum(_delta(untraced, part, "total") for part in WHOLES[whole])
+def test_each_whole_covers_its_parts(request, method, whole):
+    snap = request.getfixturevalue(METHODS[method]["untraced"])
+    assert _delta(snap, _named(whole, method)) == 1
+    parts = sum(_delta(snap, _named(part, method), "total")
+                for part in WHOLES[whole])
     # a snapshot rounds a mean to the microsecond
-    assert parts <= _delta(untraced, whole, "total") + 1e-5 * len(
-        WHOLES[whole])
+    assert parts <= _delta(snap, _named(whole, method), "total") \
+        + 1e-5 * len(WHOLES[whole])
 
 
 @pytest.mark.parametrize("name", LINE_STAGES)
@@ -272,61 +326,71 @@ def test_tracer_off_records_no_span_and_stage_still_feeds_its_timer(
 # == the tracer: one trace id from the client to the pull ====================
 
 
-def _request_trace(spans):
-    handlers = [s for s in spans if s["name"] == "rpc/shard_verifyCommittees"]
+def _request_trace(spans, method="verifyCommittees"):
+    handlers = [s for s in spans if s["name"] == f"rpc/shard_{method}"]
     assert len(handlers) == 1
     trace_id = handlers[0]["trace"]
     return trace_id, [s for s in spans if s["trace"] == trace_id]
 
 
+@pytest.mark.parametrize("method", sorted(METHODS))
 @pytest.mark.parametrize("name", [
-    "rpc/client/shard_verifyCommittees", "rpc/client/encode",
+    "rpc/client/shard_{m}", "rpc/client/encode",
     "rpc/client/roundtrip", "rpc/client/decode",
-    RPC + "server_time", "rpc/shard_verifyCommittees", RPC + "admit",
-    RPC + "parse_time", RPC + "decode_time", RPC + "respond",
-    f"serving/{OP}/request", f"serving/{OP}/device_dispatch",
-    f"serving/{OP}/dispatch", "jax/bls_committee_dispatch", *SIG_SPANS])
-def test_one_trace_id_holds_the_request_down_to_the_pull(traced, name):
-    _, mine = _request_trace(traced)
+    "{rpc}server_time", "rpc/shard_{m}", "{rpc}admit",
+    "{rpc}parse_time", "{rpc}decode_time", "{rpc}respond",
+    "serving/{op}/request", "serving/{op}/device_dispatch",
+    "serving/{op}/dispatch", "{dispatch}", *SIG_SPANS])
+def test_one_trace_id_holds_the_request_down_to_the_pull(request, method,
+                                                         name):
+    spans = request.getfixturevalue(METHODS[method]["traced"])
+    _, mine = _request_trace(spans, method)
+    name = name.format(m=method, rpc=f"rpc/{method}/", **METHODS[method])
     assert [s["name"] for s in mine].count(name) >= 1, sorted(
         s["name"] for s in mine)
 
 
-def test_no_dispatch_span_is_a_trace_of_its_own(traced):
-    trace_id, _ = _request_trace(traced)
-    strays = [s["name"] for s in traced if s["trace"] != trace_id
+@pytest.mark.parametrize("method", sorted(METHODS))
+def test_no_dispatch_span_is_a_trace_of_its_own(request, method):
+    spans = request.getfixturevalue(METHODS[method]["traced"])
+    trace_id, _ = _request_trace(spans, method)
+    strays = [s["name"] for s in spans if s["trace"] != trace_id
               and s["name"].startswith(("jax/", "sig/", "serving/"))]
     assert strays == []
 
 
-def test_the_chain_of_parents_runs_from_the_client_to_the_stages(traced):
-    _, mine = _request_trace(traced)
+@pytest.mark.parametrize("method", sorted(METHODS))
+def test_the_chain_of_parents_runs_from_the_client_to_the_stages(request,
+                                                                 method):
+    spans = request.getfixturevalue(METHODS[method]["traced"])
+    _, mine = _request_trace(spans, method)
     by_id = {s["span"]: s for s in mine}
     one = {s["name"]: s for s in mine}
+    rpc, handler = f"rpc/{method}/", f"rpc/shard_{method}"
+    op, dispatch = METHODS[method]["op"], METHODS[method]["dispatch"]
 
     def parent(name):
         return by_id[one[name]["parent"]]["name"]
 
     for name in ("rpc/client/encode", "rpc/client/roundtrip"):
-        assert parent(name) == "rpc/client/shard_verifyCommittees"
+        assert parent(name) == f"rpc/client/shard_{method}"
     # the envelope names the roundtrip, not the call's span: the server
     # works inside the roundtrip
-    for name in ("rpc/client/decode", RPC + "server_time"):
+    for name in ("rpc/client/decode", rpc + "server_time"):
         assert parent(name) == "rpc/client/roundtrip"
-    for name in ("rpc/shard_verifyCommittees", RPC + "admit",
-                 RPC + "parse_time", RPC + "respond"):
-        assert parent(name) == RPC + "server_time"
-    assert parent(RPC + "decode_time") == "rpc/shard_verifyCommittees"
-    assert parent(f"serving/{OP}/request") == "rpc/shard_verifyCommittees"
-    assert parent(f"serving/{OP}/device_dispatch") == f"serving/{OP}/request"
-    assert parent(f"serving/{OP}/dispatch") == f"serving/{OP}/device_dispatch"
-    assert one[f"serving/{OP}/device_dispatch"]["tags"]["dispatch_span"] \
-        == one[f"serving/{OP}/dispatch"]["span"]
-    for name in ("sig/host_marshal_time", "sig/transfer_time",
-                 "jax/bls_committee_dispatch"):
-        assert parent(name) == f"serving/{OP}/dispatch"
+    for name in (handler, rpc + "admit", rpc + "parse_time",
+                 rpc + "respond"):
+        assert parent(name) == rpc + "server_time"
+    assert parent(rpc + "decode_time") == handler
+    assert parent(f"serving/{op}/request") == handler
+    assert parent(f"serving/{op}/device_dispatch") == f"serving/{op}/request"
+    assert parent(f"serving/{op}/dispatch") == f"serving/{op}/device_dispatch"
+    assert one[f"serving/{op}/device_dispatch"]["tags"]["dispatch_span"] \
+        == one[f"serving/{op}/dispatch"]["span"]
+    for name in ("sig/host_marshal_time", "sig/transfer_time", dispatch):
+        assert parent(name) == f"serving/{op}/dispatch"
     for name in ("sig/launch_time", "sig/block_time", "sig/pull_time"):
-        assert parent(name) == "jax/bls_committee_dispatch"
+        assert parent(name) == dispatch
 
 
 def test_the_line_stages_are_spans_under_the_transfer_stage(keyed_traced):
@@ -340,9 +404,12 @@ def test_the_line_stages_are_spans_under_the_transfer_stage(keyed_traced):
     assert (tags["line_miss_rows"], tags["line_hit_rows"]) == (4, 0)
 
 
-@pytest.mark.parametrize("caller", ["traced", "keyed_traced"])
-def test_every_child_lies_inside_its_parents_interval(request, caller):
-    _, mine = _request_trace(request.getfixturevalue(caller))
+@pytest.mark.parametrize("caller, method", [
+    ("traced", "verifyCommittees"), ("keyed_traced", "verifyCommittees"),
+    ("das_traced", "dasVerify")])
+def test_every_child_lies_inside_its_parents_interval(request, caller,
+                                                      method):
+    _, mine = _request_trace(request.getfixturevalue(caller), method)
     by_id = {s["span"]: s for s in mine}
     checked = 0
     for span in mine:
@@ -363,18 +430,20 @@ def test_every_child_lies_inside_its_parents_interval(request, caller):
     assert checked >= 16
 
 
-@pytest.mark.parametrize("caller, root, spans", [
-    ("traced", "rpc/client/shard_verifyCommittees", 20),
-    ("keyed_traced", "rpc/client/shard_verifyCommittees", 22),
-    ("traced_server_alone", RPC + "server_time", 16)])
+@pytest.mark.parametrize("caller, method, root, spans", [
+    ("traced", "verifyCommittees", "rpc/client/shard_verifyCommittees", 20),
+    ("keyed_traced", "verifyCommittees",
+     "rpc/client/shard_verifyCommittees", 22),
+    ("das_traced", "dasVerify", "rpc/client/shard_dasVerify", 20),
+    ("traced_server_alone", "verifyCommittees", RPC + "server_time", 16)])
 def test_the_self_times_of_a_real_request_add_up_to_its_root(
-        request, caller, root, spans):
+        request, caller, method, root, spans):
     """fleettrace's walk over the spans of a real request: every span
     hangs in one tree under `root`, and no stretch of it is booked
     twice (an enclosing span beside the span it encloses would be)."""
     from gethsharding_tpu.fleettrace.critical_path import attribute
 
-    _, mine = _request_trace(request.getfixturevalue(caller))
+    _, mine = _request_trace(request.getfixturevalue(caller), method)
     attr = attribute(mine)
     assert attr["root"] == root
     assert attr["orphan_spans"] == 0 and attr["spans"] >= spans
@@ -476,9 +545,13 @@ with open(os.path.join(REPO, "BENCHMARK.json")) as _src:
 @pytest.mark.parametrize("name", PER_LAYER)
 def test_every_per_layer_metric_reads_a_number_from_two_snapshots(
         request, name):
-    # what only a line-table miss writes is read over the keyed request
+    # what only a line-table miss writes is read over the keyed request,
+    # what only `shard_dasVerify` writes over a request of that method
+    das = name.startswith("das_")
     snap = request.getfixturevalue(
-        "keyed" if name.startswith("line_") else "untraced")
+        "keyed" if name.startswith("line_") else
+        "das_untraced" if das else "untraced")
+    op = METHODS["dasVerify" if das else "verifyCommittees"]["op"]
     bench = os.path.join(REPO, "benchmark")
     if bench not in sys.path:
         sys.path.insert(0, bench)
@@ -487,13 +560,15 @@ def test_every_per_layer_metric_reads_a_number_from_two_snapshots(
     spec = run.read_json("layer_metrics", name + ".json")
     assert spec["name"] == name
     # the device trace is the chip's; its one metric reads this stand-in
-    trace = {"busy_s": 0.001, "counts": {f"serving/{OP}/dispatches": 1}}
-    value = run.layer_metric(spec, OP, snap["before"], snap["after"],
+    trace = {"busy_s": 0.001, "counts": {f"serving/{op}/dispatches": 1}}
+    value = run.layer_metric(spec, op, snap["before"], snap["after"],
                              client_mean_ms=1e3 * snap["latency_s"],
                              trace=trace)
     assert isinstance(value, (int, float)) and value >= 0.0, (name, value)
     if name == "line_miss_rows":
         assert value == 4.0
+    if name == "das_chunk_bytes":
+        assert value == 4 * 4096    # bucket 4, a 4,096-byte chunk a row
 
 
 # == the kernels' names =====================================================
