@@ -457,15 +457,14 @@ class FrontendServer:
         from gethsharding_tpu.rpc import codec
 
         self._check_accepting("shard_verifyCommittees")
-        keys = None if pk_row_keys is None else [
-            None if k is None else str(k) for k in pk_row_keys]
+        # a packed row stays packed through the relay: the replica's
+        # client re-encodes it from its bytes, no point is opened here
+        *args, keys, _ = codec.dec_committee_call(
+            messages, sig_rows, pk_rows, pk_row_keys)
         affinity = None
         if keys:
             affinity = next((k for k in keys if k is not None), None)
-        out = self._route("bls_verify_committees",
-                          [codec.dec_bytes(m) for m in messages],
-                          codec.dec_g1_rows(sig_rows),
-                          codec.dec_g2_rows(pk_rows),
+        out = self._route("bls_verify_committees", *args,
                           pk_row_keys=keys, affinity=affinity,
                           klass=klass, tenant=tenant)
         return [bool(b) for b in out]

@@ -1338,8 +1338,9 @@ class RpcReplicaBackend:
 
         klass, tenant = current_admission()
         # the codec's share of a traced call, beside the client's own
-        # rpc/client/encode (json.dumps): 22,000 points a period in
-        # pure Python
+        # rpc/client/encode (json.dumps): a row goes as one packed
+        # string (one to_bytes a coordinate; none for a row that is
+        # packed already, the frontend relaying)
         with tracing.span("rpc/client/encode",
                           method="shard_verifyCommittees"):
             params = ([codec.enc_bytes(m) for m in messages],

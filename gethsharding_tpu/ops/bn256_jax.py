@@ -43,7 +43,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from gethsharding_tpu import metrics
 from gethsharding_tpu.crypto import bn256 as ref
+from gethsharding_tpu.crypto import pointrows
 from gethsharding_tpu.ops import limb as _limb
 from gethsharding_tpu.ops.limb import ModArith, NLIMBS, ints_to_limbs, int_to_limbs
 
@@ -1347,34 +1349,105 @@ def g2_to_limbs(points: Sequence[ref.G2Point]):
     return (np.stack(xs), np.stack(ys), np.asarray(ok))
 
 
+_COORD_BYTES = pointrows.COORD_BYTES
+_LIMB_BYTES = -(-NLIMBS * _limb.LIMB_BITS // 8)  # of `limb.ints_to_bytes`
+_P_WORDS = np.frombuffer(P.to_bytes(_COORD_BYTES, "big"), ">u8")
+_P_TOP_BYTE = P >> (8 * (_COORD_BYTES - 1))
+# rows that took the integer entry inside a conversion that had packed
+# rows: listed rows beside packed ones, and the `>= P` fallback
+_INT_ROWS = metrics.counter("sig/marshal/int_rows")
+
+
+def _not_below_p(be: np.ndarray) -> np.ndarray:
+    """(n, 32) uint8 big-endian coordinates -> (n,) bool: value >= P."""
+    w = be.view(">u8")
+    ge = w[:, 3] >= _P_WORDS[3]
+    for i in (2, 1, 0):
+        ge = (w[:, i] > _P_WORDS[i]) | ((w[:, i] == _P_WORDS[i]) & ge)
+    return ge
+
+
+def _int_row(le: np.ndarray, mask: np.ndarray, b: int, row, half: int):
+    """The integer entry: one row of Python points (None = empty slot),
+    reduced mod P, into row `b` of the byte plane and the mask."""
+    xs, ys = [], []
+    for c, pt in enumerate(row):
+        if pt is None:
+            xs.extend((0,) * half)
+            ys.extend((0,) * half)
+            continue
+        if half == 2:
+            x, y = pt
+            xs.extend((x.a % P, x.b % P))
+            ys.extend((y.a % P, y.b % P))
+        else:
+            xs.append(pt[0] % P)
+            ys.append(pt[1] % P)
+        mask[b, c] = True
+    le[:, b, :len(row)] = _limb.ints_to_bytes(xs + ys).reshape(
+        2, len(row), half, _LIMB_BYTES)
+
+
+def _committee_to_limbs(rows, width: int, out_dtype, point_size: int):
+    """One algorithm, two entries, chosen by the row's type: every row's
+    coordinates are laid little-endian into ONE byte plane, a
+    `PackedRow` by one `np.frombuffer` and a byte reversal, any other
+    row point by point through the integers (`_int_row`); then one
+    bit-plane pass for x and y (`limb.bytes_to_limbs`). The planes are
+    those of the integer entry bit for bit: `% P` is kept exactly, a
+    packed row with a coordinate not below P re-enters through the
+    integers."""
+    half = point_size // (2 * _COORD_BYTES)  # Fp coordinates of x (of y)
+    B = len(rows)
+    le = np.zeros((2, B, width, half, _LIMB_BYTES), np.uint8)
+    mask = np.zeros((B, width), bool)
+    packed = int_rows = 0
+    for b, row in enumerate(rows):
+        k = len(row)
+        if k > width:
+            raise ValueError(f"committee of {k} exceeds width {width}")
+        if not k:
+            continue
+        if isinstance(row, pointrows.PackedRow) \
+                and row.point_size == point_size:
+            arr = np.frombuffer(row.raw, np.uint8).reshape(
+                k, 2, half, _COORD_BYTES)
+            le[:, b, :k, :, :_COORD_BYTES] = \
+                arr.transpose(1, 0, 2, 3)[..., ::-1]
+            mask[b, :k] = True
+            packed += 1
+        else:
+            _int_row(le, mask, b, row, half)
+            int_rows += 1
+    if packed:
+        # only a coordinate whose top byte reaches P's can reach P
+        # (and none that came through the integers does)
+        at = np.nonzero(le[..., _COORD_BYTES - 1] >= _P_TOP_BYTE)
+        over = _not_below_p(np.ascontiguousarray(
+            le[at][:, _COORD_BYTES - 1::-1]))
+        for b in sorted(set(at[1][over].tolist())):
+            le[:, b] = 0
+            _int_row(le, mask, b, rows[b], half)
+            int_rows += 1
+        _INT_ROWS.inc(int_rows)
+    both = _limb.bytes_to_limbs(le.reshape(-1, _LIMB_BYTES),
+                                out_dtype=out_dtype)
+    xs, ys = both.reshape((2, B, width) + ((2, NLIMBS) if half == 2
+                                           else (NLIMBS,)))
+    return xs, ys, mask
+
+
 def g1_committee_to_limbs(rows: Sequence[Sequence[ref.G1Point]], width: int,
                           out_dtype=np.int32):
-    """B rows of ≤width G1 points (None = empty slot) -> the committee
-    kernel inputs (B, width, 22) ×2 + mask (B, width). Vectorized through
-    the bulk `ints_to_limbs` bit-plane path — this sits on the audit's
-    host marshalling critical path (B·width points per dispatch).
-    `out_dtype=np.uint16` marshals directly into the u16 wire format
-    (canonical 12-bit limbs) without a second full-plane copy."""
-    B = len(rows)
-    flat_x, flat_y = [], []
-    mask = np.zeros((B, width), bool)
-    for b, row in enumerate(rows):
-        if len(row) > width:
-            raise ValueError(f"committee of {len(row)} exceeds width {width}")
-        for c in range(width):
-            pt = row[c] if c < len(row) else None
-            if pt is None:
-                flat_x.append(0)
-                flat_y.append(0)
-            else:
-                flat_x.append(pt[0] % P)
-                flat_y.append(pt[1] % P)
-                mask[b, c] = True
-    # one bit-plane pass for x+y
-    both = ints_to_limbs(flat_x + flat_y, out_dtype=out_dtype)
-    xs = both[:B * width].reshape(B, width, NLIMBS)
-    ys = both[B * width:].reshape(B, width, NLIMBS)
-    return xs, ys, mask
+    """B rows of ≤width G1 points (None = empty slot; a row may be a
+    `crypto.pointrows.PackedRow`) -> the committee kernel inputs
+    (B, width, 22) ×2 + mask (B, width). Vectorized through the bulk
+    bit-plane path — this sits on the audit's host marshalling critical
+    path (B·width points per dispatch). `out_dtype=np.uint16` marshals
+    directly into the u16 wire format (canonical 12-bit limbs) without
+    a second full-plane copy."""
+    return _committee_to_limbs(rows, width, out_dtype,
+                               pointrows.G1_POINT_BYTES)
 
 
 def g2_committee_to_limbs(rows: Sequence[Sequence[ref.G2Point]], width: int,
@@ -1382,29 +1455,9 @@ def g2_committee_to_limbs(rows: Sequence[Sequence[ref.G2Point]], width: int,
     """B rows of ≤width G2 points -> (B, width, 2, 22) ×2 + mask.
 
     The audit's LARGEST host buffers (the G2 share of every dispatch);
-    `out_dtype` as in `g1_committee_to_limbs`."""
-    B = len(rows)
-    flat_x, flat_y = [], []
-    mask = np.zeros((B, width), bool)
-    for b, row in enumerate(rows):
-        if len(row) > width:
-            raise ValueError(f"committee of {len(row)} exceeds width {width}")
-        for c in range(width):
-            pt = row[c] if c < len(row) else None
-            if pt is None:
-                flat_x.extend((0, 0))
-                flat_y.extend((0, 0))
-            else:
-                x, y = pt
-                flat_x.extend((x.a % P, x.b % P))
-                flat_y.extend((y.a % P, y.b % P))
-                mask[b, c] = True
-    # one bit-plane pass for x+y
-    both = ints_to_limbs(flat_x + flat_y, out_dtype=out_dtype)
-    half = B * width * 2
-    xs = both[:half].reshape(B, width, 2, NLIMBS)
-    ys = both[half:].reshape(B, width, 2, NLIMBS)
-    return xs, ys, mask
+    rows and `out_dtype` as in `g1_committee_to_limbs`."""
+    return _committee_to_limbs(rows, width, out_dtype,
+                               pointrows.G2_POINT_BYTES)
 
 
 # tower-order interop: w-coeff k ↔ tower slot (h, l) with k = 2l + h

@@ -92,25 +92,43 @@ def _limbs_to_int_nd(arr: np.ndarray):
     return out
 
 
-def ints_to_limbs(values: Sequence[int], nlimbs: int = NLIMBS,
-                  out_dtype=np.int32) -> np.ndarray:
-    """Batch conversion: (batch,) python ints -> (batch, nlimbs) limbs.
+_BLOCK_ROWS = 4096  # rows of one bit-plane pass (`bytes_to_limbs`)
 
-    Vectorized: one to_bytes per int (C speed), then a numpy bit-plane
-    extraction — this sits on the host marshalling critical path
-    (hashes/signatures -> limbs for every batch dispatch). `out_dtype`
-    lets the u16 wire format (12-bit limbs always fit uint16) marshal
-    straight into the wire width instead of paying a second full-plane
-    astype copy of the audit's largest buffers."""
-    n = len(values)
-    if n == 0:
-        return np.zeros((0, nlimbs), out_dtype)
+
+def ints_to_bytes(values: Sequence[int],
+                  nlimbs: int = NLIMBS) -> np.ndarray:
+    """The integer entry of `ints_to_limbs`: (batch,) python ints ->
+    (batch, nbytes) uint8, little-endian, `nbytes` the whole bytes
+    `nlimbs` limbs take: one to_bytes per int (C speed). Read-only."""
     nbytes = -(-nlimbs * LIMB_BITS // 8)
     try:
         raw = b"".join(v.to_bytes(nbytes, "little") for v in values)
     except OverflowError as exc:
         raise ValueError(f"value out of range for {nlimbs} limbs") from exc
-    arr = np.frombuffer(raw, np.uint8).reshape(n, nbytes)
+    return np.frombuffer(raw, np.uint8).reshape(len(values), nbytes)
+
+
+def ints_to_limbs(values: Sequence[int], nlimbs: int = NLIMBS,
+                  out_dtype=np.int32) -> np.ndarray:
+    """Batch conversion: (batch,) python ints -> (batch, nlimbs) limbs.
+
+    Two steps, `ints_to_bytes` then `bytes_to_limbs`: a caller that
+    holds bytes already (a packed row off the wire) enters at the
+    second. This sits on the host marshalling critical path
+    (hashes/signatures -> limbs for every batch dispatch)."""
+    return bytes_to_limbs(ints_to_bytes(values, nlimbs), nlimbs, out_dtype)
+
+
+def bytes_to_limbs(arr: np.ndarray, nlimbs: int = NLIMBS,
+                   out_dtype=np.int32) -> np.ndarray:
+    """(batch, nbytes) uint8 little-endian -> (batch, nlimbs) limbs, a
+    numpy bit-plane extraction. `out_dtype` lets the u16 wire format
+    (12-bit limbs always fit uint16) marshal straight into the wire
+    width instead of paying a second full-plane astype copy of the
+    audit's largest buffers."""
+    n, nbytes = arr.shape
+    if n == 0:
+        return np.zeros((0, nlimbs), out_dtype)
     spare_bits = nbytes * 8 - nlimbs * LIMB_BITS
     if spare_bits:
         # capacity is not byte-aligned: the spare top bits must be zero
@@ -120,20 +138,27 @@ def ints_to_limbs(values: Sequence[int], nlimbs: int = NLIMBS,
             raise ValueError("value does not fit in limbs")
     # limb pairs span 3 bytes: even = b0 | low-nibble(b1)<<8, odd =
     # high-nibble(b1) | b2<<4. Contiguous reshape + strided writes beat
-    # the per-limb gather by ~6x on the audit marshalling path.
+    # the per-limb gather by ~6x on the audit marshalling path. In
+    # blocks of rows: one pass over a whole committee plane (64,512
+    # rows into 6.4 MB of int32) took 14-23 ms in a process that runs
+    # the TPU runtime's 160 threads, blocks of 2,048-16,384 rows 4.6-5.3
+    # ms for the same plane (PERF.md section 6, PR 36).
     pairs = nlimbs // 2
     out = np.empty((n, nlimbs), out_dtype)
-    if pairs:
-        main = arr[:, :pairs * 3].reshape(n, pairs, 3).astype(np.uint16)
-        out[:, 0:2 * pairs:2] = main[..., 0] | ((main[..., 1] & 0x0F) << 8)
-        out[:, 1:2 * pairs:2] = (main[..., 1] >> 4) | (main[..., 2] << 4)
-    if nlimbs % 2:
-        # trailing even limb: its 12 bits start at byte 3*pairs
-        b0 = pairs * 3
-        tail = arr[:, b0].astype(np.int32)
-        if b0 + 1 < nbytes:
-            tail |= (arr[:, b0 + 1].astype(np.int32) & 0x0F) << 8
-        out[:, -1] = tail
+    for lo in range(0, n, _BLOCK_ROWS):
+        a, o = arr[lo:lo + _BLOCK_ROWS], out[lo:lo + _BLOCK_ROWS]
+        if pairs:
+            main = a[:, :pairs * 3].reshape(len(a), pairs, 3).astype(
+                np.uint16)
+            o[:, 0:2 * pairs:2] = main[..., 0] | ((main[..., 1] & 0x0F) << 8)
+            o[:, 1:2 * pairs:2] = (main[..., 1] >> 4) | (main[..., 2] << 4)
+        if nlimbs % 2:
+            # trailing even limb: its 12 bits start at byte 3*pairs
+            b0 = pairs * 3
+            tail = a[:, b0].astype(np.int32)
+            if b0 + 1 < nbytes:
+                tail |= (a[:, b0 + 1].astype(np.int32) & 0x0F) << 8
+            o[:, -1] = tail
     return out
 
 

@@ -5,6 +5,24 @@ bn256 curve points as hex-int coordinate arrays (G1 = [x, y], G2 =
 [[xa, xb], [ya, yb]], null = infinity/absent), registry entries and
 collation records as plain objects. Deliberately schema-first and
 version-tagged so a non-Python peer can implement the same surface.
+
+A ROW of points on the committee plane (`shard_verifyCommittees`'
+`sig_rows` and `pk_rows`) has two wire forms, and a handler accepts
+both, row by row, telling them apart by the row's JSON type:
+
+- a STRING: the packed row, 0x-hex of the points' coordinates back to
+  back, each coordinate 32 bytes big-endian. G1 is 64 bytes a point,
+  ``x ‖ y``; G2 is 128 bytes a point, ``xa ‖ xb ‖ ya ‖ yb`` (of
+  ``x = xa + xb·i``: real part first, the order of the list form).
+  ``"0x"`` is the empty row. The packed form has no absent point. A
+  string whose byte length is no multiple of the point size (or no
+  hex) gets the error response a malformed list gets.
+- a LIST: the points in the coordinate-array form above, where a slot
+  may be null. A sender uses it for a row with an absent point or a
+  coordinate that does not fit 32 bytes; older clients send only it.
+
+One request may mix the two. Coordinates are not reduced on the wire:
+the verifier reduces them mod p, in either form.
 """
 
 from __future__ import annotations
@@ -12,6 +30,8 @@ from __future__ import annotations
 from typing import Optional
 
 from gethsharding_tpu.crypto import bn256
+from gethsharding_tpu.crypto.pointrows import (
+    G1_POINT_BYTES, G2_POINT_BYTES, PackedRow, pack_row)
 from gethsharding_tpu.utils.hexbytes import Address20, Hash32
 
 
@@ -113,22 +133,55 @@ def dec_record(obj: Optional[dict]):
 # the same schema-first contract as the rest of the surface.
 
 
+def _enc_point_rows(rows, point_size: int, enc_point) -> list:
+    out = []
+    for row in rows:
+        packed = pack_row(row, point_size)
+        out.append([enc_point(p) for p in row] if packed is None
+                   else enc_bytes(packed.raw))
+    return out
+
+
+def _dec_point_rows(rows, point_size: int, dec_point) -> list:
+    # a string is a packed row, a list the points one by one: the two
+    # wire forms of a row (module docstring); `bytes.fromhex` and
+    # `PackedRow` raise ValueError on a malformed string, which the
+    # server answers with an error, like a malformed list
+    return [PackedRow(dec_bytes(row), point_size) if isinstance(row, str)
+            else [dec_point(p) for p in row] for row in rows]
+
+
 def enc_g1_rows(rows) -> list:
-    """Per-row G1 point lists (committee vote signatures)."""
-    return [[enc_g1(p) for p in row] for row in rows]
+    """Per-row G1 point lists (committee vote signatures): a row as one
+    packed string, or as a list of points where it holds a `None`."""
+    return _enc_point_rows(rows, G1_POINT_BYTES, enc_g1)
 
 
 def dec_g1_rows(rows) -> list:
-    return [[dec_g1(p) for p in row] for row in rows]
+    return _dec_point_rows(rows, G1_POINT_BYTES, dec_g1)
 
 
 def enc_g2_rows(rows) -> list:
-    """Per-row G2 point lists (committee member pubkeys)."""
-    return [[enc_g2(p) for p in row] for row in rows]
+    """Per-row G2 point lists (committee member pubkeys), as
+    `enc_g1_rows`."""
+    return _enc_point_rows(rows, G2_POINT_BYTES, enc_g2)
 
 
 def dec_g2_rows(rows) -> list:
-    return [[dec_g2(p) for p in row] for row in rows]
+    return _dec_point_rows(rows, G2_POINT_BYTES, dec_g2)
+
+
+def dec_committee_call(messages, sig_rows, pk_rows, pk_row_keys) -> tuple:
+    """The `shard_verifyCommittees` argument plane, for the replica's
+    handler and the frontend's alike: (messages, sig_rows, pk_rows,
+    keys, packed), `packed` the number of rows whose signature AND key
+    row arrived packed."""
+    sigs, pks = dec_g1_rows(sig_rows), dec_g2_rows(pk_rows)
+    keys = None if pk_row_keys is None else [
+        None if k is None else str(k) for k in pk_row_keys]
+    packed = sum(1 for s, p in zip(sigs, pks)
+                 if isinstance(s, PackedRow) and isinstance(p, PackedRow))
+    return [dec_bytes(m) for m in messages], sigs, pks, keys, packed
 
 
 def enc_pk_row_keys(keys) -> Optional[list]:

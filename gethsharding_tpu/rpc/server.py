@@ -81,6 +81,12 @@ def _decode_stage(stem: str):
                          metrics.timer(f"rpc/{stem}/decode_time"))
 
 
+# rows of shard_verifyCommittees requests whose signature AND key row
+# arrived packed (codec: one string a row), the wire form the limb
+# marshal takes without opening a point
+_PACKED_ROWS = metrics.counter("rpc/verifyCommittees/packed_rows")
+
+
 class RPCServer:
     """Threaded JSON-RPC server over TCP (host, port) — port 0 picks a
     free one (`server.address` reports the bound endpoint)."""
@@ -642,11 +648,9 @@ class RPCServer:
 
         serving = self._serving()
         with _decode_stage("verifyCommittees"):
-            args = ([codec.dec_bytes(m) for m in messages],
-                    codec.dec_g1_rows(sig_rows),
-                    codec.dec_g2_rows(pk_rows))
-            keys = None if pk_row_keys is None else [
-                None if k is None else str(k) for k in pk_row_keys]
+            *args, keys, packed = codec.dec_committee_call(
+                messages, sig_rows, pk_rows, pk_row_keys)
+        _PACKED_ROWS.inc(packed)
         if klass is not None or tenant is not None:
             with admission_class(klass or "interactive", tenant):
                 out = serving.bls_verify_committees(*args,

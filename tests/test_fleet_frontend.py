@@ -683,6 +683,64 @@ def test_frontend_serves_all_planes_and_orchestrates_drains():
             serving.close()
 
 
+def test_frontend_relays_a_packed_request_without_opening_a_point(
+        monkeypatch):
+    """Client -> frontend -> `RpcReplicaBackend` -> replica: every hop
+    hands the packed rows on as their bytes. The replica's backend here
+    answers without reading a row, so any point opened on the way would
+    be the relay's doing; rows with an absent point travel listed."""
+    from gethsharding_tpu.crypto import pointrows
+
+    seen = {}
+
+    class Recorder(PythonSigBackend):
+        def bls_verify_committees(self, messages, sig_rows, pk_rows,
+                                  pk_row_keys=None):
+            seen.update(sig_rows=sig_rows, pk_rows=pk_rows)
+            return [len(r) > 0 for r in sig_rows]
+
+    registry = _registry()
+    serving = ServingSigBackend(Recorder(), ServingConfig(flush_us=200),
+                                registry=_registry())
+    replica_server = RPCServer(SimulatedMainchain(), sig_backend=serving)
+    replica_server.start()
+    wire = RpcReplicaBackend.dial(*replica_server.address)
+    frontend = FrontendServer(FleetRouter(
+        [Replica("r0", wire, probe=None, metrics_read=None,
+                 registry=registry)],
+        health_interval_s=0.05, registry=registry))
+    frontend.start()
+    actor = RpcReplicaBackend.dial(*frontend.address)
+    msgs, sig_rows, pk_rows, keys = _committee_rows(4)
+    sig_rows[2], pk_rows[2] = [], []
+    sig_rows[3][0] = pk_rows[3][0] = None    # an absent voter: listed
+    opened = []
+    points = pointrows.PackedRow._points
+    try:
+        # the actor packs its own Python points; from there on no hop
+        # may turn a packed row back into points
+        params = (codec.enc_g1_rows(sig_rows), codec.enc_g2_rows(pk_rows))
+        monkeypatch.setattr(
+            pointrows.PackedRow, "_points",
+            lambda self, raw: opened.append(self) or points(self, raw))
+        got = actor.bls_verify_committees(msgs, sig_rows, pk_rows,
+                                          pk_row_keys=keys)
+        monkeypatch.undo()
+    finally:
+        actor.close()
+        frontend.stop()
+        wire.close()
+        replica_server.stop()
+        serving.close()
+    assert got == [True, True, False, True] and opened == []
+    for sent, rows in zip(params, (seen["sig_rows"], seen["pk_rows"])):
+        assert [isinstance(r, pointrows.PackedRow) for r in rows] \
+            == [True, True, True, False]
+        assert ["0x" + r.raw.hex() for r in rows[:3]] == sent[:3]
+    assert [list(r) for r in seen["sig_rows"]] == sig_rows
+    assert [list(r) for r in seen["pk_rows"]] == pk_rows
+
+
 def test_frontend_restart_with_actor_mid_request_recovers():
     """An actor (an `RpcReplicaBackend` dialing the FRONTEND) whose
     in-flight request dies with the frontend gets a TYPED transport

@@ -29,6 +29,7 @@ import random
 import pytest
 
 from gethsharding_tpu.crypto import bn256 as bls
+from gethsharding_tpu.rpc import codec
 from gethsharding_tpu.sigbackend import PythonSigBackend, get_backend
 from gethsharding_tpu.sigbackend import marshal
 from gethsharding_tpu.sigbackend.layout import (DeviceLayout,
@@ -140,6 +141,50 @@ def test_mesh_layout_geometry():
     assert (np.asarray(whole) == host).all()
 
 
+def test_the_mesh_step_gets_the_same_planes_from_packed_rows():
+    """The mesh twin's marshal (fast tier: the step itself is stubbed,
+    its compile is the slow tier's): rows that arrive packed off the
+    wire give the pjit'd step the planes the listed rows give it, bit
+    for bit, split over both devices; the `None` row stays listed."""
+    import jax
+    import numpy as np
+
+    from gethsharding_tpu.crypto.pointrows import PackedRow
+    from gethsharding_tpu.sigbackend.dispatch import JaxSigBackend
+
+    if jax.device_count() < 2:
+        pytest.skip("needs the virtual multi-device mesh (conftest)")
+    messages, sig_rows, pk_rows, _ = _committee_cols()
+    packed_sigs = codec.dec_g1_rows(codec.enc_g1_rows(sig_rows))
+    packed_pks = codec.dec_g2_rows(codec.enc_g2_rows(pk_rows))
+    assert [isinstance(r, PackedRow) for r in packed_sigs] \
+        == [True, True, False, True, True, True]
+    backend = JaxSigBackend(mesh_devices=2)
+    lay = backend._layout
+    bucket, width = lay.mesh_bucket(6), marshal.committee_width(sig_rows,
+                                                                pk_rows)
+    calls = []
+
+    def step(*args):
+        calls.append(args)
+        return lay.place(np.zeros(bucket, bool)), np.int32(0)
+
+    key = (bucket, width, backend._wire, "recompute")
+    backend._mesh_exec[key], backend._mesh_collectives[key] = step, 1
+    for sigs, pks in ((sig_rows, pk_rows), (packed_sigs, packed_pks)):
+        assert backend.bls_verify_committees(messages, sigs, pks) \
+            == [False] * 6
+    listed, packed = calls
+    assert len(listed) == len(packed) == 9
+    for a, b in zip(listed, packed):
+        assert len(b.sharding.device_set) == 2
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    # sx is the signatures' plane: 6 of `bucket` rows hold points
+    assert np.asarray(packed[4]).any(axis=1).tolist() \
+        == [True, False, True, True, True, True] + [False] * (bucket - 6)
+
+
 # -- the tri-layout dispatch workloads (slow tier: pairing compiles) -------
 
 
@@ -232,6 +277,13 @@ def test_committee_tri_layout_bit_identity(backends):
             messages, sig_rows, pk_rows, pk_row_keys=keys)
         assert not fut.done()  # staged, not pulled
         assert fut.result() == want, f"{n}-device async verdicts diverge"
+        # the rows as they arrive off the wire: packed, under new keys
+        # (the row with the absent voter stays a list)
+        got = backend.bls_verify_committees(
+            messages, codec.dec_g1_rows(codec.enc_g1_rows(sig_rows)),
+            codec.dec_g2_rows(codec.enc_g2_rows(pk_rows)),
+            pk_row_keys=[k + ":packed" for k in keys])
+        assert got == want, f"{n}-device packed verdicts diverge"
     # the single-device layout never reports mesh evidence
     assert backends[1].last_mesh is None
 

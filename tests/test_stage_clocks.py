@@ -5,6 +5,8 @@ shape on the CPU, read three ways: the registry timers (always on), the tracer's
 spans (one trace id from the client's call to the device pull), and the
 benchmark's per-layer metric files over two `shard_metrics` snapshots.
 Plus the primitive alone, the collector's clock and the kernels' names.
+Since ISSUE 36 the client sends a committee row packed, one string a row;
+the coordinate lists of an older client are a case of the same tests.
 
 Counts and containment only: no time measured here means anything.
 """
@@ -226,35 +228,57 @@ def keyed_traced(served, keyed):
     return _traced_request(served, keyed=True)
 
 
+def _bare_socket_request(served, args, want, listed=False):
+    """One request over a bare socket with no `trace` envelope, as the
+    benchmark's client sends it; `listed`: every row as the coordinate
+    lists of a client older than the packed row. Returns once the
+    server has booked it."""
+    from gethsharding_tpu.rpc import codec
+
+    messages, sig_rows, pk_rows, *keys = args
+    if listed:
+        sigs = [[codec.enc_g1(p) for p in row] for row in sig_rows]
+        pks = [[codec.enc_g2(p) for p in row] for row in pk_rows]
+    else:
+        sigs, pks = codec.enc_g1_rows(sig_rows), codec.enc_g2_rows(pk_rows)
+    frame = {"jsonrpc": "2.0", "id": 1, "method": "shard_verifyCommittees",
+             "params": [[codec.enc_bytes(m) for m in messages], sigs, pks,
+                        *(codec.enc_pk_row_keys(k) for k in keys)]}
+    booked = metrics.timer(RPC + "server_time")
+    count = booked.count
+    with socket.create_connection(served.server.address,
+                                  timeout=600.0) as sock:
+        sock.sendall((json.dumps(frame) + "\n").encode())
+        reply = json.loads(sock.makefile("rb").readline())
+    assert [bool(b) for b in reply["result"]] == want
+    deadline = time.monotonic() + 10.0
+    while booked.count == count and time.monotonic() < deadline:
+        time.sleep(0.001)
+    assert booked.count == count + 1
+
+
 @pytest.fixture(scope="module")
 def traced_server_alone(served, traced):
     """The spans of one request whose caller is not traced, as the
     benchmark's client is not: the frame goes over a bare socket with no
     `trace` envelope, to a server with the tracer on."""
-    from gethsharding_tpu.rpc import codec
-
-    messages, sig_rows, pk_rows = served.args
-    frame = {"jsonrpc": "2.0", "id": 1, "method": "shard_verifyCommittees",
-             "params": [[codec.enc_bytes(m) for m in messages],
-                        codec.enc_g1_rows(sig_rows),
-                        codec.enc_g2_rows(pk_rows)]}
-    booked = metrics.timer(RPC + "server_time")
-    count = booked.count
     tracing.enable(ring_spans=4096)
     tracing.TRACER.clear()
     try:
-        with socket.create_connection(served.server.address,
-                                      timeout=600.0) as sock:
-            sock.sendall((json.dumps(frame) + "\n").encode())
-            reply = json.loads(sock.makefile("rb").readline())
-        assert [bool(b) for b in reply["result"]] == served.want
-        deadline = time.monotonic() + 10.0
-        while booked.count == count and time.monotonic() < deadline:
-            time.sleep(0.001)
+        _bare_socket_request(served, served.args, served.want)
         return tracing.TRACER.recent_spans()
     finally:
         tracing.disable()
         tracing.TRACER.clear()
+
+
+@pytest.fixture(scope="module")
+def listed(served, untraced):
+    """One request of an older client, every row as coordinate lists,
+    with the tracer off, between two `shard_metrics`."""
+    before = served.client.metrics()
+    _bare_socket_request(served, served.args, served.want, listed=True)
+    return {"before": before, "after": served.client.metrics()}
 
 
 def _delta(snap, name, field="count"):
@@ -269,23 +293,99 @@ def _delta(snap, name, field="count"):
 # == the registry: always on ================================================
 
 
-@pytest.mark.parametrize("method", sorted(METHODS))
+# (method, the fixture that holds one untraced request of it): the packed
+# rows `RpcReplicaBackend` sends, an older client's listed rows, the DAS
+# plane
+UNTRACED = [(m, METHODS[m]["untraced"]) for m in sorted(METHODS)] \
+    + [("verifyCommittees", "listed")]
+
+
+@pytest.mark.parametrize("method, fixture", UNTRACED)
 @pytest.mark.parametrize("name", STAGE_TIMERS)
-def test_one_request_counts_once_in_every_stage_timer(request, method, name):
-    snap = request.getfixturevalue(METHODS[method]["untraced"])
+def test_one_request_counts_once_in_every_stage_timer(request, method,
+                                                      fixture, name):
+    snap = request.getfixturevalue(fixture)
     assert _delta(snap, _named(name, method)) == 1
 
 
-@pytest.mark.parametrize("method", sorted(METHODS))
+@pytest.mark.parametrize("method, fixture", UNTRACED)
 @pytest.mark.parametrize("whole", sorted(WHOLES))
-def test_each_whole_covers_its_parts(request, method, whole):
-    snap = request.getfixturevalue(METHODS[method]["untraced"])
+def test_each_whole_covers_its_parts(request, method, fixture, whole):
+    snap = request.getfixturevalue(fixture)
     assert _delta(snap, _named(whole, method)) == 1
     parts = sum(_delta(snap, _named(part, method), "total")
                 for part in WHOLES[whole])
     # a snapshot rounds a mean to the microsecond
     assert parts <= _delta(snap, _named(whole, method), "total") \
         + 1e-5 * len(WHOLES[whole])
+
+
+@pytest.mark.parametrize("fixture, rows", [("untraced", 2), ("listed", 0),
+                                           ("keyed", 4)])
+def test_the_server_counts_the_rows_that_arrived_packed(request, fixture,
+                                                        rows):
+    snap = request.getfixturevalue(fixture)
+    assert _delta(snap, RPC + "packed_rows") == rows
+    # no packed coordinate reached P, and no listed row lay beside a
+    # packed one: nothing took the integer entry of a packed dispatch
+    assert _delta(snap, "sig/marshal/int_rows") == 0
+
+
+def test_forged_and_empty_rows_read_the_same_through_both_wire_forms(
+        served, keyed_again):
+    """Four rows under new row keys (the shapes the keyed request keeps
+    warm; after `keyed_again`, whose batch memo these requests replace):
+    a good row, a forged one, an EMPTY one, a good one, through the
+    packed and the listed wire, against the scalar backend."""
+    from gethsharding_tpu.sigbackend import PythonSigBackend
+
+    msgs, sig_rows, pk_rows, _ = _keyed_committees()
+    sig_rows, pk_rows = [list(r) for r in sig_rows], list(pk_rows)
+    sig_rows[1][2] = sig_rows[0][2]     # a real vote, on another header
+    sig_rows[2], pk_rows[2] = [], []
+    want = PythonSigBackend().bls_verify_committees(msgs, sig_rows, pk_rows)
+    assert want == [True, False, False, True]
+    packed = metrics.counter(RPC + "packed_rows")
+
+    def keys(wire):
+        return [("stage-forged", wire, r) for r in range(4)]
+
+    before = packed.value
+    assert served.client.bls_verify_committees(msgs, sig_rows, pk_rows,
+                                               keys("packed")) == want
+    assert packed.value - before == 4       # the empty row is the row "0x"
+    _bare_socket_request(served, (msgs, sig_rows, pk_rows, keys("listed")),
+                         want, listed=True)
+    assert packed.value - before == 4
+
+
+@pytest.mark.parametrize("group, point_bytes", [("sig_rows", 64),
+                                                ("pk_rows", 128)])
+def test_a_packed_row_of_bad_length_gets_a_malformed_lists_error(
+        served, group, point_bytes):
+    """No whole number of points: the error response a malformed list
+    gets, and the connection's next request is served."""
+    from gethsharding_tpu.rpc import codec
+    from gethsharding_tpu.rpc.client import RPCClient, RPCError
+
+    messages, sig_rows, pk_rows = served.args
+    good = {"messages": [codec.enc_bytes(m) for m in messages],
+            "sig_rows": codec.enc_g1_rows(sig_rows),
+            "pk_rows": codec.enc_g2_rows(pk_rows)}
+    client = RPCClient(*served.server.address, timeout=600.0)
+    try:
+        codes = []
+        for bad in ("0x" + "00" * (point_bytes + 1), [["0x1"]]):
+            params = dict(good)
+            params[group] = [params[group][0], bad]
+            with pytest.raises(RPCError) as err:
+                client.call("shard_verifyCommittees", *params.values())
+            codes.append(err.value.code)
+        assert codes[0] == codes[1]
+        assert client.call("shard_verifyCommittees",
+                           *good.values()) == served.want
+    finally:
+        client.close()
 
 
 @pytest.mark.parametrize("name", LINE_STAGES)
@@ -567,6 +667,8 @@ def test_every_per_layer_metric_reads_a_number_from_two_snapshots(
     assert isinstance(value, (int, float)) and value >= 0.0, (name, value)
     if name == "line_miss_rows":
         assert value == 4.0
+    if name == "packed_rows":
+        assert value == 2.0         # both rows of the request
     if name == "das_chunk_bytes":
         assert value == 4 * 4096    # bucket 4, a 4,096-byte chunk a row
 
