@@ -1171,6 +1171,10 @@ class RouterSigBackend:
         self.router.close()
 
 
+# where `RpcReplicaBackend.metrics` lays the calling process's own rows
+CALLER_PREFIX = "caller/"
+
+
 class RpcReplicaBackend:
     """A chain_server replica's verification surface over JSON-RPC —
     the cross-process face a frontend router balances. Covers the FULL
@@ -1256,7 +1260,12 @@ class RpcReplicaBackend:
             except Exception:  # noqa: BLE001 - already dead
                 pass
 
-    def _call(self, method: str, *params):
+    def _call(self, method: str, *params, encode=None):
+        """One wire call. `encode` builds the leading params from the
+        caller's objects (`RPCClient.call` runs it as the stage
+        ``rpc/client/<m>/encode_time``, the mirror of the server's
+        ``rpc/<m>/decode_time``): the five verification-plane methods
+        hand their `codec.enc_*` calls over so."""
         from gethsharding_tpu.resilience.chaos import transport_disturb
         from gethsharding_tpu.rpc.client import RPCError
 
@@ -1268,7 +1277,7 @@ class RpcReplicaBackend:
             # this call actually dialed — the router's `replica` tag
             # names the routing slot, this names the wire address
             tracing.tag_current(endpoint=self.name)
-            return client.call(method, *params)
+            return client.call(method, *params, encode=encode)
         except RPCError as exc:
             if "draining" in exc.message:
                 # the replica refused because it is shutting down: a
@@ -1310,10 +1319,10 @@ class RpcReplicaBackend:
         from gethsharding_tpu.serving.classes import current_admission
 
         klass, tenant = current_admission()
-        out = self._call("shard_ecrecover",
-                         [codec.enc_bytes(d) for d in digests],
-                         [codec.enc_bytes(s) for s in sigs65],
-                         klass, tenant)
+        out = self._call(
+            "shard_ecrecover", klass, tenant,
+            encode=lambda: ([codec.enc_bytes(d) for d in digests],
+                            [codec.enc_bytes(s) for s in sigs65]))
         return [None if a is None else Address20(codec.dec_bytes(a))
                 for a in out]
 
@@ -1323,11 +1332,11 @@ class RpcReplicaBackend:
         from gethsharding_tpu.serving.classes import current_admission
 
         klass, tenant = current_admission()
-        out = self._call("shard_verifyAggregates",
-                         [codec.enc_bytes(m) for m in messages],
-                         [codec.enc_g1(s) for s in agg_sigs],
-                         [codec.enc_g2(p) for p in agg_pks],
-                         klass, tenant)
+        out = self._call(
+            "shard_verifyAggregates", klass, tenant,
+            encode=lambda: ([codec.enc_bytes(m) for m in messages],
+                            [codec.enc_g1(s) for s in agg_sigs],
+                            [codec.enc_g2(p) for p in agg_pks]))
         return [bool(b) for b in out]
 
     def bls_verify_committees(self, messages, sig_rows, pk_rows,
@@ -1337,17 +1346,14 @@ class RpcReplicaBackend:
         from gethsharding_tpu.serving.classes import current_admission
 
         klass, tenant = current_admission()
-        # the codec's share of a traced call, beside the client's own
-        # rpc/client/encode (json.dumps): a row goes as one packed
-        # string (one to_bytes a coordinate; none for a row that is
-        # packed already, the frontend relaying)
-        with tracing.span("rpc/client/encode",
-                          method="shard_verifyCommittees"):
-            params = ([codec.enc_bytes(m) for m in messages],
-                      codec.enc_g1_rows(sig_rows),
-                      codec.enc_g2_rows(pk_rows),
-                      codec.enc_pk_row_keys(pk_row_keys))
-        out = self._call("shard_verifyCommittees", *params, klass, tenant)
+        # a row goes as one packed string (one to_bytes a coordinate;
+        # none for a row that is packed already, the frontend relaying)
+        out = self._call(
+            "shard_verifyCommittees", klass, tenant,
+            encode=lambda: ([codec.enc_bytes(m) for m in messages],
+                            codec.enc_g1_rows(sig_rows),
+                            codec.enc_g2_rows(pk_rows),
+                            codec.enc_pk_row_keys(pk_row_keys)))
         return [bool(b) for b in out]
 
     def bls_verify_committees_async(self, messages, sig_rows, pk_rows,
@@ -1369,11 +1375,11 @@ class RpcReplicaBackend:
         from gethsharding_tpu.serving.classes import current_admission
 
         klass, tenant = current_admission()
-        # the codec's share of a traced call, as on the committee plane:
         # 17,600 hex strings a period
-        with tracing.span("rpc/client/encode", method="shard_dasVerify"):
-            params = codec.enc_das_call(chunks, indices, proofs, roots)
-        out = self._call("shard_dasVerify", *params, klass, tenant)
+        out = self._call(
+            "shard_dasVerify", klass, tenant,
+            encode=lambda: codec.enc_das_call(chunks, indices, proofs,
+                                              roots))
         return [bool(b) for b in out]
 
     def das_verify_multiproofs(self, commitments, index_rows, eval_rows,
@@ -1383,10 +1389,10 @@ class RpcReplicaBackend:
         from gethsharding_tpu.serving.classes import current_admission
 
         klass, tenant = current_admission()
-        out = self._call("shard_dasPolyVerify",
-                         *codec.enc_das_poly_call(commitments, index_rows,
-                                                  eval_rows, proofs, ns),
-                         klass, tenant)
+        out = self._call(
+            "shard_dasPolyVerify", klass, tenant,
+            encode=lambda: codec.enc_das_poly_call(
+                commitments, index_rows, eval_rows, proofs, ns))
         return [bool(b) for b in out]
 
     # -- control plane -----------------------------------------------------
@@ -1397,8 +1403,17 @@ class RpcReplicaBackend:
     def metrics(self) -> dict:
         """The replica's full registry snapshot (`shard_metrics`) —
         the federation scrape the router's health sweep folds into
-        ``fleet/replica/<name>/...`` rollups."""
-        return self._call("shard_metrics")
+        ``fleet/replica/<name>/...`` rollups. Beside it, under
+        ``caller/``, THIS process's own ``rpc/client/*`` rows: the
+        caller's stage clocks (`RPCClient.call`), which no server's
+        registry can hold, so that one reading holds both halves of a
+        request. The sweep holds those rows first-hand and folds none
+        of them (`_FOLD_NAMESPACES`)."""
+        snapshot = self._call("shard_metrics")
+        mine = metrics.DEFAULT_REGISTRY.snapshot("rpc/client/")
+        snapshot.update((CALLER_PREFIX + name, row)
+                        for name, row in mine.items())
+        return snapshot
 
     def drain(self) -> dict:
         return self._call("shard_drain")

@@ -328,9 +328,11 @@ class Registry:
     def get(self, name: str) -> Optional[object]:
         return self._metrics.get(name)
 
-    def snapshot(self) -> Dict[str, dict]:
+    def snapshot(self, prefix: str = "") -> Dict[str, dict]:
+        """Every metric's snapshot by name, or those of one namespace."""
         with self._lock:
-            items = list(self._metrics.items())
+            items = [item for item in self._metrics.items()
+                     if item[0].startswith(prefix)]
         return {name: metric.snapshot() for name, metric in sorted(items)}
 
 

@@ -25,7 +25,7 @@ import time
 from dataclasses import dataclass
 from typing import Callable, List, Optional
 
-from gethsharding_tpu import tracing
+from gethsharding_tpu import metrics, tracing
 from gethsharding_tpu.rpc import codec
 from gethsharding_tpu.smc.state_machine import SMCRevert
 from gethsharding_tpu.utils.hexbytes import Address20, Hash32
@@ -55,15 +55,16 @@ class RemoteReceipt:
 
 
 @contextlib.contextmanager
-def _roundtrip(ctx, span_id):
+def _roundtrip(ctx, inside):
     """``rpc/client/roundtrip`` of a traced call, write -> reply event,
-    under the call's span `ctx`. Its `span_id` was taken before the
-    request was encoded, because the envelope names it: the remote
-    handler runs inside the roundtrip, so it is the roundtrip's child,
-    and a self-time walk of the stitched trace (fleettrace) books the
-    wire once. Recorded also where the write or the wait fails, so that
-    what the server recorded under it is not orphaned."""
-    if span_id is None:
+    under the call's span `ctx`. `inside` is (trace id, the roundtrip's
+    own span id): the id was taken before the request was encoded,
+    because the envelope names it: the remote handler runs inside the
+    roundtrip, so it is the roundtrip's child, and a self-time walk of
+    the stitched trace (fleettrace) books the wire once. Recorded also
+    where the write or the wait fails, so that what the server recorded
+    under it is not orphaned."""
+    if inside is None:
         yield
         return
     start = time.monotonic()
@@ -72,7 +73,35 @@ def _roundtrip(ctx, span_id):
     finally:
         tracing.TRACER.record("rpc/client/roundtrip", start,
                               time.monotonic(), trace_id=ctx[0],
-                              parent_id=ctx[1], span_id=span_id)
+                              parent_id=ctx[1], span_id=inside[1])
+
+
+def _leaf(name, ctx=None, **tags):
+    """A working leaf of a call: the stage `name` over its own timer."""
+    return tracing.stage(name, metrics.timer(name), ctx=ctx, tags=tags)
+
+
+def _no_leaf(name, ctx=None, **tags):
+    """The same of a trace-plane call: nothing (codec.TRACE_PLANE_METHODS)."""
+    return tracing.NOOP_SPAN
+
+
+def _book_reply(clock, slot, t_woke, inside) -> None:
+    """``reply_time`` of an answered call: from the reply line in the
+    reader thread's hand (its stamp, `_read_loop`) to the caller awake:
+    the reply's json.loads, the slot, `event.set`, the thread handoff.
+    The caller observes it, so the timer is complete when `call`
+    returns. For a traced call a span under the roundtrip, the
+    json.loads its child ``rpc/client/decode`` on the reader's thread."""
+    t_line, t_loaded, tid = slot["reply_marks"]
+    metrics.timer(clock + "reply_time").observe(t_woke - t_line)
+    if inside is not None:
+        reply_id = tracing.TRACER.record(
+            clock + "reply_time", t_line, t_woke, trace_id=inside[0],
+            parent_id=inside[1])
+        tracing.TRACER.record("rpc/client/decode", t_line, t_loaded,
+                              trace_id=inside[0], parent_id=reply_id,
+                              tid=tid)
 
 
 class RPCClient:
@@ -122,81 +151,114 @@ class RPCClient:
 
     # -- request/response --------------------------------------------------
 
-    def call(self, method: str, *params):
-        rid = next(self._ids)
-        event = threading.Event()
-        slot: dict = {"event": event}
-        with self._pending_lock:
-            self._pending[rid] = slot
+    def call(self, method: str, *params, encode=None):
+        """One request, its reply awaited. `encode` is the caller's
+        codec: a callable that returns the call's leading params, run
+        inside the call's span as its first stage (`encode_time`), so
+        that a traced request holds its codec under its own trace id."""
         # cross-process trace propagation: the caller's active span
         # context rides the request as a `trace` envelope field, and the
         # server adopts it as its handler span's trace/parent — one
         # trace id from a router's route span down into the replica's
         # dispatch spans. Extra envelope keys are legal JSON-RPC.
-        # Trace-plane methods get NO span and NO envelope: a span per
-        # shipped batch re-enters the export buffer it ships (see
-        # codec.TRACE_PLANE_METHODS). A traced call splits into leaves
-        # under its span: `rpc/client/encode` (json.dumps; a caller's
-        # codec work is its own span of that name, RpcReplicaBackend),
-        # `rpc/client/roundtrip` (write -> reply event) and, inside it
-        # from the reader thread, `rpc/client/decode` (the reply's
-        # json.loads). The envelope carries the ROUNDTRIP's span id:
-        # the server's spans are its children, beside the decode.
-        # Tracer only: nobody reads a client's registry.
-        span_cm = (contextlib.nullcontext()
-                   if method in codec.TRACE_PLANE_METHODS
-                   else tracing.span(f"rpc/client/{method}"))
-        with span_cm as client_span:
-            request = {"jsonrpc": "2.0", "id": rid, "method": method,
-                       "params": list(params)}
-            ctx = (tracing.current_context()
-                   if client_span is not None else None)
-            roundtrip_id = None
-            if ctx is not None:
-                roundtrip_id = tracing.TRACER.new_trace_id()
-                request["trace"] = {"trace_id": ctx[0],
-                                    "span_id": roundtrip_id}
-                slot["decode_ctx"] = (ctx[0], roundtrip_id)
-            # no span for an untraced call: tracing off, or the trace
-            # plane, which must stay invisible
-            with (tracing.span("rpc/client/encode") if ctx is not None
-                  else tracing.NOOP_SPAN):
-                payload = (json.dumps(request) + "\n").encode()
-            with _roundtrip(ctx, roundtrip_id):
-                try:
-                    with self._write_lock:
+        # Trace-plane methods get NO span, NO envelope and no clock: a
+        # span per shipped batch re-enters the export buffer it ships
+        # (see codec.TRACE_PLANE_METHODS).
+        #
+        # The caller's half of a request is timed where it happens, as
+        # the server's half is (`rpc/server.py` `_book`), under
+        # ``rpc/client/<m>/``, `<m>` the method without ``shard_``. The
+        # working leaves are stages (a timer always, a span of the same
+        # name when traced): `encode_time` (the caller's codec, the
+        # mirror of the server's `decode_time`), `dumps_time`
+        # (json.dumps and the encode to bytes), `send_time` (write lock
+        # taken -> flushed) and `reply_time` (reply line in the reader's
+        # hand -> this thread awake; the reply's json.loads,
+        # `rpc/client/decode`, is its traced child). `wait_time`
+        # (flushed -> awake) is parked, so a timer only: its span is
+        # `rpc/client/roundtrip`, which encloses send and wait. The
+        # envelope carries the ROUNDTRIP's span id: the server's spans
+        # are its children, beside send and reply.
+        visible = method not in codec.TRACE_PLANE_METHODS
+        clock = f"rpc/client/{method.replace('shard_', '', 1)}/"
+        leaf = _leaf if visible else _no_leaf
+        with (tracing.span(f"rpc/client/{method}") if visible
+              else contextlib.nullcontext()) as client_span:
+            if encode is not None:
+                with leaf(clock + "encode_time"):
+                    params = (*encode(), *params)
+            # The slot is registered before the request's big buffers
+            # are made, and the payload is dropped as soon as it is
+            # flushed, so that it is freed BEFORE the request's strings:
+            # the order the heap saw before the caller's clocks came. At
+            # a frame of megabytes that order decides how much of the
+            # buffers the heap hands back and faults in again every
+            # call: 2,072 minor faults a 4.29 MB call so, 2,423 with the
+            # payload freed last, 3,464 with the slot made after
+            # `json.dumps` (+10 ms a period request on the chip's host;
+            # PERF.md section 6, PR 37).
+            rid = next(self._ids)
+            event = threading.Event()
+            slot: dict = {"event": event}
+            with self._pending_lock:
+                self._pending[rid] = slot
+            try:
+                request = {"jsonrpc": "2.0", "id": rid, "method": method,
+                           "params": list(params)}
+                ctx = (tracing.current_context()
+                       if client_span is not None else None)
+                # (trace id, the roundtrip's span id): where send, reply and
+                # the server's spans hang
+                inside = None
+                if ctx is not None:
+                    inside = (ctx[0], tracing.TRACER.new_trace_id())
+                    request["trace"] = {"trace_id": inside[0],
+                                        "span_id": inside[1]}
+                with leaf(clock + "dumps_time"):
+                    payload = (json.dumps(request) + "\n").encode()
+                with _roundtrip(ctx, inside):
+                    with self._write_lock, leaf(clock + "send_time", inside,
+                                                bytes=len(payload)):
                         self._file.write(payload)
                         self._file.flush()
-                except (OSError, ValueError):
-                    # dead socket (the server was killed/restarted): the
-                    # reply will never come — reclaim the pending slot
-                    # instead of leaking it, and let the caller's
-                    # transport-error handling (e.g. RpcReplicaBackend's
-                    # redial) classify the failure
-                    with self._pending_lock:
-                        self._pending.pop(rid, None)
-                    raise
-                if not event.wait(self._timeout):
-                    with self._pending_lock:
-                        self._pending.pop(rid, None)
-                    raise TimeoutError(f"rpc call {method} timed out")
-            if "trace" in slot and client_span is not None:
-                # the server's handler trace id: equal to ours once the
-                # server stitches, the REMOTE id against an older server
-                # — either way caller logs correlate to replica traces
-                client_span.tag(remote_trace=slot["trace"])
-            ctx = slot.get("trace_ctx")
-            if isinstance(ctx, dict) and client_span is not None:
-                # newer servers also return the handler SPAN id: the
-                # exact remote span this call produced, unambiguous
-                # even when retries/hedges reuse one trace id
-                client_span.tag(remote_span=ctx.get("span_id"))
-            if "error" in slot:
-                err = slot["error"]
-                if err.get("data") == "SMCRevert":
-                    raise SMCRevert(err.get("message", ""))
-                raise RPCError(err.get("code", -1), err.get("message", ""))
-            return slot.get("result")
+                    del payload     # see the slot's comment
+                    t_sent = time.monotonic()
+                    answered = event.wait(self._timeout)
+                    t_woke = time.monotonic()
+                    if visible:
+                        metrics.timer(clock + "wait_time").observe(
+                            t_woke - t_sent)
+                    if not answered:
+                        raise TimeoutError(f"rpc call {method} timed out")
+                if visible and "reply_marks" in slot:
+                    _book_reply(clock, slot, t_woke, inside)
+                if "trace" in slot and client_span is not None:
+                    # the server's handler trace id: equal to ours once the
+                    # server stitches, the REMOTE id against an older server
+                    # — either way caller logs correlate to replica traces
+                    client_span.tag(remote_trace=slot["trace"])
+                remote = slot.get("trace_ctx")
+                if isinstance(remote, dict) and client_span is not None:
+                    # newer servers also return the handler SPAN id: the
+                    # exact remote span this call produced, unambiguous
+                    # even when retries/hedges reuse one trace id
+                    client_span.tag(remote_span=remote.get("span_id"))
+                if "error" in slot:
+                    err = slot["error"]
+                    if err.get("data") == "SMCRevert":
+                        raise SMCRevert(err.get("message", ""))
+                    raise RPCError(err.get("code", -1),
+                                   err.get("message", ""))
+                return slot.get("result")
+            finally:
+                # answered: the reader took the slot. Not answered (the
+                # request would not serialise, the socket is dead, the wait
+                # timed out): the reply will never be read, so reclaim the
+                # slot instead of leaking it; the caller's transport-error
+                # handling (e.g. RpcReplicaBackend's redial) classifies the
+                # failure
+                with self._pending_lock:
+                    self._pending.pop(rid, None)
 
     def subscribe_heads(self, callback: Callable) -> Callable[[], None]:
         # registration is caller-thread territory while the dispatcher
@@ -221,16 +283,15 @@ class RPCClient:
 
     def _read_loop(self) -> None:
         try:
+            tid = threading.get_ident()
             for raw in self._file:
-                # the reply's decode is a span of a traced call only:
-                # with the tracer off, one attribute read
-                timed = tracing.TRACER.enabled
-                t_decode = time.monotonic() if timed else 0.0
+                # where a reply's `reply_time` starts (`_book_reply`)
+                t_line = time.monotonic()
                 try:
                     msg = json.loads(raw)
                 except json.JSONDecodeError:
                     continue
-                t_decoded = time.monotonic() if timed else 0.0
+                t_loaded = time.monotonic()
                 method = msg.get("method")
                 if method == "shard_subscription":
                     self._notifications.put(
@@ -243,11 +304,7 @@ class RPCClient:
                 with self._pending_lock:
                     slot = self._pending.pop(rid, None)
                 if slot is not None:
-                    if timed and "decode_ctx" in slot:
-                        trace_id, parent_id = slot["decode_ctx"]
-                        tracing.TRACER.record(
-                            "rpc/client/decode", t_decode, t_decoded,
-                            trace_id=trace_id, parent_id=parent_id)
+                    slot["reply_marks"] = (t_line, t_loaded, tid)
                     if "trace" in msg:
                         # the handler-span trace id the server returns
                         # on the envelope — surfaced as the caller

@@ -53,8 +53,9 @@ CONN_CONCURRENCY = int(os.environ.get(
 
 class _Marks:
     """One request's clock readings on its way through the server: the
-    request line read off the socket, the JSON parse, the handler's
-    return. `stem` is the method without ``shard_`` once a handler was
+    frame's first bytes in the buffer, the request line read off the
+    socket (`frame_bytes` long), the JSON parse, the handler's return.
+    `stem` is the method without ``shard_`` once a handler was
     found for it (None for a bad frame, an unknown method, the built-in
     subscribe / p2p methods and the trace plane: none of those is
     booked). For a traced request, `span_id` is the id its
@@ -62,11 +63,13 @@ class _Marks:
     span that names it as parent; `trace_id` / `parent_id` place that
     span in the caller's trace (or make it the root of its own)."""
 
-    __slots__ = ("t_read", "t_parse", "t_parsed", "t_handled", "stem",
-                 "trace_id", "span_id", "parent_id")
+    __slots__ = ("t_first", "t_read", "frame_bytes", "t_parse", "t_parsed",
+                 "t_handled", "stem", "trace_id", "span_id", "parent_id")
 
-    def __init__(self, t_read: float):
+    def __init__(self, t_first: float, t_read: float, frame_bytes: int):
+        self.t_first = t_first
         self.t_read = t_read
+        self.frame_bytes = frame_bytes
         self.t_parse = self.t_parsed = self.t_handled = t_read
         self.stem: Optional[str] = None
         self.trace_id: Optional[int] = None
@@ -237,9 +240,9 @@ class RPCServer:
         idle_lock = threading.Lock()
         idle = 0    # workers waiting for a request, under idle_lock
 
-        def serve_one(raw: bytes, t_read: float) -> None:
+        def serve_one(raw: bytes, t_first: float, t_read: float) -> None:
             nonlocal idle
-            marks = _Marks(t_read)
+            marks = _Marks(t_first, t_read, len(raw))
             try:
                 try:
                     response = self._dispatch(raw, handler, write_lock,
@@ -264,12 +267,22 @@ class RPCServer:
                 self._book(marks, time.monotonic())
 
         def work() -> None:
-            for raw, t_read in iter(todo.get, None):
-                serve_one(raw, t_read)
+            for raw, t_first, t_read in iter(todo.get, None):
+                serve_one(raw, t_first, t_read)
                 raw = None  # a waiting worker pins no frame
 
+        rfile = handler.rfile
         try:
-            for raw in handler.rfile:
+            # ``rpc/<m>/recv_time`` (`_book`): block for the frame's
+            # FIRST bytes, stamp, then read the line. The peek is the
+            # read `readline` would have made, no further system call;
+            # a pipelined frame is in the buffer already and reads
+            # about 0. Annotated under a fixed name, as the parse is:
+            # the method is inside the frame.
+            while rfile.peek(1):
+                t_first = time.monotonic()
+                with tracing.annotation("rpc/recv_time"):
+                    raw = rfile.readline()
                 t_read = time.monotonic()
                 # json.loads takes the line's whitespace; no copy of a
                 # frame of megabytes to strip it
@@ -290,7 +303,7 @@ class RPCServer:
                                               name="rpc-conn-worker")
                     workers.append(worker)
                     worker.start()
-                todo.put((raw, t_read))
+                todo.put((raw, t_first, t_read))
         except (OSError, ValueError):
             pass
         finally:
@@ -316,7 +329,14 @@ class RPCServer:
         flushed (or its peer was found gone): ``rpc/<m>/server_time``
         (request line read -> response flushed: slot wait, thread start,
         parse, handler, response) and its part ``rpc/<m>/parse_time``,
-        always. For a traced request ``server_time`` is also the span
+        always; BEFORE it and no part of it ``rpc/<m>/recv_time`` (the
+        frame's first bytes in the buffer -> its line read: what the
+        wire and the buffered reads of a frame of megabytes cost this
+        side), with the line's length in the counter
+        ``rpc/<m>/frame_bytes``. For a request traced by its caller
+        ``recv_time`` is a span under the caller's roundtrip, beside
+        ``server_time``; with an untraced caller there is nothing to
+        hang it under. For a traced request ``server_time`` is also the span
         that encloses the handler span and the leaves around it:
         ``admit`` (read -> parse), ``parse_time``, ``respond`` (handler
         returned -> flushed). The four children do not overlap, so a
@@ -325,10 +345,17 @@ class RPCServer:
         stem = marks.stem
         if stem is None:
             return
+        metrics.timer(f"rpc/{stem}/recv_time").observe(
+            marks.t_read - marks.t_first)
+        metrics.counter(f"rpc/{stem}/frame_bytes").inc(marks.frame_bytes)
         metrics.timer(f"rpc/{stem}/parse_time").observe(
             marks.t_parsed - marks.t_parse)
         if marks.span_id is not None:
             record = tracing.TRACER.record
+            if marks.parent_id is not None:
+                record(f"rpc/{stem}/recv_time", marks.t_first, marks.t_read,
+                       trace_id=marks.trace_id, parent_id=marks.parent_id,
+                       tags={"bytes": marks.frame_bytes})
             record(f"rpc/{stem}/server_time", marks.t_read, t_flushed,
                    trace_id=marks.trace_id, parent_id=marks.parent_id,
                    span_id=marks.span_id)

@@ -286,35 +286,41 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         if n == 0:
             return []
         dt = DeviceTimer("ecrecover")
-        sigs, valid, host_rows = [], [], []
-        for i, sig in enumerate(sigs65):
-            sig = bytes(sig)
-            if len(sig) == 65 and sig[64] in (0, 1):
-                sigs.append(ecdsa.Signature.from_bytes65(sig))
-                valid.append(True)
-            else:
-                if len(sig) == 65 and sig[64] in (2, 3):
-                    # rare r+n overflow recids: scalar host fallback keeps
-                    # exact RecoverPubkey parity
-                    host_rows.append(i)
-                sigs.append(ecdsa.Signature(r=1, s=1, v=0))  # placeholder
-                valid.append(False)
         bucket = self._bucket(n)
         fresh = self._note_shape("ecrecover", bucket)
         pad = bucket - n
-        sigs.extend([ecdsa.Signature(r=1, s=1, v=0)] * pad)
-        valid.extend([False] * pad)
-        e = self._sec.hashes_to_limbs(
-            [bytes(d) for d in digests] + [b"\x00" * 32] * pad)
-        r, s, v = self._sec.sigs_to_limbs(sigs)
-        dt.dispatched()
+        # the three stages of every device operation (`das_verify_samples`)
+        with tracing.stage("sig/host_marshal_time", _T_HOST_MARSHAL):
+            sigs, valid, host_rows = [], [], []
+            for i, sig in enumerate(sigs65):
+                sig = bytes(sig)
+                if len(sig) == 65 and sig[64] in (0, 1):
+                    sigs.append(ecdsa.Signature.from_bytes65(sig))
+                    valid.append(True)
+                else:
+                    if len(sig) == 65 and sig[64] in (2, 3):
+                        # rare r+n overflow recids: scalar host fallback
+                        # keeps exact RecoverPubkey parity
+                        host_rows.append(i)
+                    sigs.append(ecdsa.Signature(r=1, s=1, v=0))  # placeholder
+                    valid.append(False)
+            sigs.extend([ecdsa.Signature(r=1, s=1, v=0)] * pad)
+            valid.extend([False] * pad)
+            e = self._sec.hashes_to_limbs(
+                [bytes(d) for d in digests] + [b"\x00" * 32] * pad)
+            r, s, v = self._sec.sigs_to_limbs(sigs)
+        with tracing.stage("sig/transfer_time", _T_TRANSFER):
+            args = tuple(jnp.asarray(p)
+                         for p in (e, r, s, v, np.asarray(valid)))
+        dt.dispatched()  # marshal (incl. transfer staging) closes here
+        launch = tracing.stage("sig/launch_time", _T_LAUNCH,
+                               ctx=dt.span_ctx)
         # compile_span: a fresh shape's launch wall (trace + XLA compile
         # + enqueue) lands in the devscope compile ledger; on hits this
         # is one branch
-        with self._compiles.compile_span("ecrecover", (bucket,), fresh):
-            qx, qy, ok = self._recover(
-                jnp.asarray(e), jnp.asarray(r), jnp.asarray(s),
-                jnp.asarray(v), jnp.asarray(np.asarray(valid)))
+        with self._compiles.compile_span("ecrecover", (bucket,),
+                                         fresh), launch:
+            qx, qy, ok = self._recover(*args)
         # the checked pull on `ok` is the dispatch barrier (block-vs-pull
         # self-checked); limbs_to_pubkeys then pulls the sibling buffers
         # of the SAME computation, so the device phase closes only after
@@ -346,18 +352,25 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         bucket = self._bucket(n)
         fresh = self._note_shape("bls_aggregate", bucket)
         pad = bucket - n
-        hashes = [bls.hash_to_g1(bytes(m)) for m in messages] + [None] * pad
-        hx, hy, hok = self._bn.g1_to_limbs(hashes)
-        sx, sy, sok = self._bn.g1_to_limbs(list(agg_sigs) + [None] * pad)
-        pkx, pky, pok = self._bn.g2_to_limbs(list(agg_pks) + [None] * pad)
-        # infinity signature/key is an outright rejection (scalar parity)
-        valid = hok & sok & pok
-        dt.dispatched()
-        with self._compiles.compile_span("bls_aggregate", (bucket,), fresh):
-            out = self._bls(
-                jnp.asarray(hx), jnp.asarray(hy), jnp.asarray(sx),
-                jnp.asarray(sy), jnp.asarray(pkx), jnp.asarray(pky),
-                jnp.asarray(valid))
+        with tracing.stage("sig/host_marshal_time", _T_HOST_MARSHAL):
+            hashes = ([bls.hash_to_g1(bytes(m)) for m in messages]
+                      + [None] * pad)
+            hx, hy, hok = self._bn.g1_to_limbs(hashes)
+            sx, sy, sok = self._bn.g1_to_limbs(list(agg_sigs) + [None] * pad)
+            pkx, pky, pok = self._bn.g2_to_limbs(
+                list(agg_pks) + [None] * pad)
+            # infinity signature/key is an outright rejection (scalar
+            # parity)
+            valid = hok & sok & pok
+        with tracing.stage("sig/transfer_time", _T_TRANSFER):
+            args = tuple(jnp.asarray(p)
+                         for p in (hx, hy, sx, sy, pkx, pky, valid))
+        dt.dispatched()  # marshal (incl. transfer staging) closes here
+        launch = tracing.stage("sig/launch_time", _T_LAUNCH,
+                               ctx=dt.span_ctx)
+        with self._compiles.compile_span("bls_aggregate", (bucket,),
+                                         fresh), launch:
+            out = self._bls(*args)
         res = [bool(b) for b in dt.pull(out)[:n]]
         dt.done()
         dt.record_span("jax/bls_aggregate_dispatch", rows=n, bucket=bucket,
@@ -466,10 +479,14 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         bucket = lay.mesh_bucket(n) if lay.is_mesh else self._bucket(n)
         shape = (bucket, lay.n_devices) if lay.is_mesh else (bucket,)
         fresh = self._note_shape("das_poly_verify", *shape)
-        st = poly_proofs.marshal_multiproofs(commitments, index_rows,
-                                             eval_rows, proofs, ns, bucket)
+        with tracing.stage("sig/host_marshal_time", _T_HOST_MARSHAL):
+            st = poly_proofs.marshal_multiproofs(
+                commitments, index_rows, eval_rows, proofs, ns, bucket)
         planes = (st["px"], st["py"], st["ax"], st["ay"], st["zx"],
                   st["zy"], st["valid"])
+        ship = lay.place if lay.is_mesh else jnp.asarray
+        with tracing.stage("sig/transfer_time", _T_TRANSFER):
+            args = tuple(ship(p) for p in planes)
         proof_bytes = sum(int(p.nbytes) for p in planes)
         # same wire-ledger contract as the sample path: the marshalled
         # pairing planes ARE this dispatch's host->device bytes
@@ -481,10 +498,12 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         self._m_wire_bytes.inc(proof_bytes)
         tracing.tag_current_add(wire_bytes=proof_bytes,
                                 sample_wire_bytes=proof_bytes)
-        ship = lay.place if lay.is_mesh else jnp.asarray
-        dt.dispatched()
-        with self._compiles.compile_span("das_poly_verify", shape, fresh):
-            out = self._bls(*(ship(p) for p in planes))
+        dt.dispatched()  # marshal (incl. transfer staging) closes here
+        launch = tracing.stage("sig/launch_time", _T_LAUNCH,
+                               ctx=dt.span_ctx)
+        with self._compiles.compile_span("das_poly_verify", shape,
+                                         fresh), launch:
+            out = self._bls(*args)
         if lay.is_mesh:
             self.last_mesh = {
                 "op": "das_verify_multiproofs",

@@ -64,20 +64,22 @@ class stage:
     """``with tracing.stage("sig/transfer_time", timer):`` (see the
     module docstring). `seconds` holds the reading afterwards. `ctx` is
     a ``(trace_id, span_id)`` to parent the span under in place of the
-    context's current span (`Tracer.start`)."""
+    context's current span (`Tracer.start`); `tags` go on the span."""
 
-    __slots__ = ("name", "timer", "seconds", "_ctx", "_t0", "_span",
-                 "_note")
+    __slots__ = ("name", "timer", "seconds", "_ctx", "_tags", "_t0",
+                 "_span", "_note")
 
     def __init__(self, name: str, timer: metrics.Timer,
-                 ctx: Optional[Tuple[int, int]] = None):
+                 ctx: Optional[Tuple[int, int]] = None,
+                 tags: Optional[dict] = None):
         self.name = name
         self.timer = timer
         self.seconds = 0.0
         self._ctx = ctx
+        self._tags = tags
 
     def __enter__(self) -> "stage":
-        self._span = TRACER.start(self.name, ctx=self._ctx)
+        self._span = TRACER.start(self.name, self._tags, ctx=self._ctx)
         self._note = annotation(self.name)
         self._note.__enter__()
         self._t0 = time.monotonic()
