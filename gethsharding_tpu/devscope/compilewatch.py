@@ -10,8 +10,9 @@ that gap:
 
 - **Per-(op, shape) compile ledger.** The sig backend brackets every
   FIRST dispatch of a new (op, shape) with ``compile_span``; the wall
-  time of that launch (trace + XLA compile + enqueue) lands here as
-  that shape's compile cost. ``devscope/compile/{count,total_s}`` run
+  time of that launch (trace + XLA compile + enqueue, or a load from
+  the executable store: ``source``) lands here as that shape's
+  compile cost. ``devscope/compile/{count,total_s}`` run
   as registry rows; per-shape detail rides ``describe()`` → the
   /status ``devscope`` section. One listener (``after_compile``)
   hears of each compile that succeeded: a serving process settles its
@@ -91,6 +92,7 @@ class CompileWatch:
         self._in_storm = False
         self.total_s = 0.0
         self.compiles = 0
+        self.from_store = 0  # of them, loaded from the executable store
         self.storms = 0
         # called as after_compile(op, shape) once a compile_span's body
         # has succeeded, on the thread that compiled and outside the
@@ -143,8 +145,13 @@ class CompileWatch:
                             window_s=self._storm_window_s,
                             shapes_per_s=round(rate, 3))
 
-    def note_compile(self, op: str, shape: tuple, wall_s: float) -> None:
-        """Book one compile's wall time against its (op, shape)."""
+    def note_compile(self, op: str, shape: tuple, wall_s: float,
+                     source: str = "traced",
+                     load_s: Optional[float] = None) -> None:
+        """Book one compile's wall time against its (op, shape), and
+        where its executable came from: ``traced`` (traced, lowered,
+        compiled or read from JAX's compile cache) or ``store`` (loaded
+        by shape from the executable store, in `load_s` of the wall)."""
         with self._lock:
             key = (op, tuple(shape))
             slot = self._shapes.get(key)
@@ -153,7 +160,12 @@ class CompileWatch:
             if slot is not None:
                 slot["compiles"] += 1
                 slot["wall_s"] += wall_s
+                slot["source"] = source
+                if load_s is not None:
+                    slot["load_s"] = slot.get("load_s", 0.0) + load_s
             self.compiles += 1
+            if source == "store":
+                self.from_store += 1
             self.total_s += wall_s
             total = self.total_s
         self._m_compiles.inc()
@@ -163,18 +175,23 @@ class CompileWatch:
     def compile_span(self, op: str, shape: tuple, fresh: bool):
         """Bracket a kernel launch: on a fresh shape the body's wall
         time (trace + compile + enqueue) is booked as the compile cost;
-        on a cache hit this is one branch and a yield. A launch that
-        raised is booked too, but `after_compile` hears only of one
-        that succeeded, and what it raises is logged, not passed on:
-        the dispatch has its verdict by then."""
+        on a cache hit this is one branch and a yield. A fresh shape's
+        body is handed the booking, ``{"source": "traced"}``: one that
+        loaded its executable from the store writes ``source="store"``
+        and ``load_s`` into it. A launch that raised is booked too, but
+        `after_compile` hears only of one that succeeded, and what it
+        raises is logged, not passed on: the dispatch has its verdict
+        by then."""
         if not fresh:
-            yield
+            yield None
             return
+        booking = {"source": "traced"}
         t0 = time.perf_counter()
         try:
-            yield
+            yield booking
         finally:
-            self.note_compile(op, shape, time.perf_counter() - t0)
+            self.note_compile(op, shape, time.perf_counter() - t0,
+                              **booking)
         after_compile = self.after_compile
         if after_compile is None:
             return
@@ -212,6 +229,7 @@ class CompileWatch:
                 self._shapes.items(), key=lambda kv: -kv[1]["wall_s"])
             out = {
                 "compiles": self.compiles,
+                "from_store": self.from_store,
                 "total_s": round(self.total_s, 4),
                 "unique_shapes": len(self._shapes),
                 "storms": self.storms,
@@ -222,7 +240,10 @@ class CompileWatch:
                 "top_shapes": [
                     {"op": key[0], "shape": list(key[1]),
                      "compiles": slot["compiles"],
-                     "wall_s": round(slot["wall_s"], 4)}
+                     "wall_s": round(slot["wall_s"], 4),
+                     "source": slot.get("source"),
+                     **({"load_s": round(slot["load_s"], 4)}
+                        if "load_s" in slot else {})}
                     for key, slot in shapes[:top]],
             }
         return out

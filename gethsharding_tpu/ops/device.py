@@ -14,7 +14,18 @@ ONE place so no caller can drift from another:
   set JAX already keeps its cache there and this module sets no other
   directory; where it is not, every process of the program uses the one
   fixed ``<checkout>/.jax_cache``. Entries are found by path, so the
-  directory never depends on the host, the pid or the time.
+  directory never depends on the host, the pid or the time. Inside it,
+  ``executables/`` holds the programs themselves, found by shape with
+  nothing traced (`sigbackend/execstore.py`).
+
+- the allocator: a process that holds a TPU has some 180 threads, and a
+  thread that is not the main one allocates from an arena of glibc's
+  that hands memory back to the kernel at every large `free` and faults
+  it in again at the next `malloc`. Deserializing a pairing kernel's
+  253 MB executable (a compile-cache read, a load from the executable
+  store) on a dispatch thread took 65-66 s that way and 12-13 s on the
+  main thread or with the arenas told to keep what is freed (PERF.md,
+  PR 38). `device_record` tells them, off the CPU.
 
 JAX stays a lazy import: control planes import this module freely.
 """
@@ -37,6 +48,13 @@ def compile_cache_dir() -> str:
     """The compile-cache directory in force for this process."""
     return os.environ.get(CACHE_ENV) or str(
         Path(__file__).resolve().parents[2] / ".jax_cache")
+
+
+def executable_store_dir() -> str:
+    """Where the serialized executables lie (`sigbackend/execstore.py`):
+    ``executables/`` inside the compile cache's directory, so that what
+    keeps the one keeps the other."""
+    return os.path.join(compile_cache_dir(), "executables")
 
 
 def configure_compile_cache() -> str:
@@ -63,6 +81,31 @@ def cpu_declared() -> bool:
     named = ",".join(filter(None, (os.environ.get(PLATFORMS_ENV, ""),
                                    jax.config.jax_platforms or "")))
     return "cpu" in [p.strip().lower() for p in named.split(",")]
+
+
+# mallopt(3)'s parameters and the values the chip run was made with:
+# nothing below 1 GiB is mmapped by itself, no heap is trimmed or given
+# up, an arena grows in steps of 256 MiB
+_MALLOPT = ((-3, 1 << 30),         # M_MMAP_THRESHOLD
+            (-1, (1 << 31) - 1),   # M_TRIM_THRESHOLD
+            (-2, 1 << 28))         # M_TOP_PAD
+
+
+def keep_freed_memory() -> bool:
+    """Tell glibc's allocator to keep what the process frees, in every
+    thread's arena (module docstring). The process's resident size then
+    stays at its high-water mark, which a (de)serialized executable
+    sets: about 1 GiB. False where the C library has no `mallopt`."""
+    import ctypes
+
+    try:
+        mallopt = ctypes.CDLL(None).mallopt
+    except (OSError, AttributeError):
+        return False
+    mallopt.argtypes = (ctypes.c_int, ctypes.c_int)
+    mallopt.restype = ctypes.c_int
+    # a list, so that one refusal does not keep the others from being set
+    return all([mallopt(param, value) == 1 for param, value in _MALLOPT])
 
 
 _resolved: Optional[dict] = None
@@ -95,6 +138,8 @@ def device_record() -> dict:
                 "(one jax process per host today; libtpu gives the first "
                 "process every chip). Set JAX_PLATFORMS=cpu to run on the "
                 "CPU on purpose.")
+        if record["platform"] != "cpu":
+            keep_freed_memory()
         _resolved = record
     return _resolved
 

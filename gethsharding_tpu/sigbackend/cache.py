@@ -527,10 +527,10 @@ class ResidentPkCache:
         """Launch a jitted program of the line-table path. A shape this
         process has not launched before is a compile like any other:
         counted by `_note_shape`, booked and settled by
-        `compile_span`."""
+        `compile_span`, held by shape by `_run`."""
         fresh = self._note_shape(op, *shape)
-        with self._compiles.compile_span(op, shape, fresh):
-            return fn(*args)
+        with self._compiles.compile_span(op, shape, fresh) as booking:
+            return self._run(op, shape, fn, args, booking)
 
     def _precompute_lines(self, planes, width: int, *shape_tail):
         """The precompute over padded miss planes already on their

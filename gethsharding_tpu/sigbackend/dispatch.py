@@ -5,8 +5,10 @@ three submodules: `marshal` builds the host limb planes, `layout`
 decides where they land (single device, or the 1-D shard mesh), and
 `cache` (mixed in) keeps the recurring pk planes device-resident.
 This module owns what remains: the jitted kernels, the compile-cache
-bookkeeping (`_note_shape` + `compile_span`), the `DeviceTimer`
-attribution of every dispatch, and the per-dispatch wire ledger.
+bookkeeping (`_note_shape` + `compile_span`), the executables held by
+(op, shape) with the store behind them (`_run`, `execstore.py`), the
+`DeviceTimer` attribution of every dispatch, and the per-dispatch wire
+ledger.
 
 The mesh committee path (`_committee_submit_mesh`) is the tentpole:
 the whole period audit runs as ONE pjit'd step — a `shard_map` whose
@@ -37,6 +39,7 @@ from gethsharding_tpu.sigbackend import SigBackend, VerdictFuture
 from gethsharding_tpu.sigbackend import layout as layout_mod
 from gethsharding_tpu.sigbackend import marshal
 from gethsharding_tpu.sigbackend.cache import ResidentPkCache
+from gethsharding_tpu.sigbackend.execstore import ExecutableStore
 from gethsharding_tpu.sigbackend.marshal import bucket_size
 
 # the host stages of the committee and the DAS sample dispatches, the
@@ -52,7 +55,7 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
 
     name = "jax"
 
-    def __init__(self, mesh_devices=None):
+    def __init__(self, mesh_devices=None, exec_store=None):
         import jax  # lazy: only sig-verifying processes touch the backend
         import jax.numpy as jnp
 
@@ -174,6 +177,17 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         # branches on `self._layout.is_mesh`, nothing else.
         self._layout = layout_mod.DeviceLayout(
             layout_mod.mesh_devices_requested(mesh_devices))
+        # the single-device programs' executables, held by (op, shape)
+        # with a disk behind them (execstore.py): a fresh shape loads
+        # its program by shape and traces nothing. Engaged by what the
+        # device record says (never on the CPU), or by the store a test
+        # hands in; the mesh keeps `_mesh_exec`, in-process only.
+        if self._layout.is_mesh:
+            exec_store = None
+        elif exec_store is None:
+            exec_store = ExecutableStore.for_device(self.device_record)
+        self._exec_store = exec_store
+        self._held: dict = {}
         if self._layout.is_mesh:
             # per-device cache shards + their devscope census owners
             self._init_mesh_shards(self._layout)
@@ -268,6 +282,41 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         compiles.saw(op, shape, fresh)
         return fresh
 
+    # None on the CPU, on a mesh and on partially-built test instances:
+    # `_run` is then the jitted call it always was
+    _exec_store = None
+
+    def _run(self, op: str, shape: tuple, fn, args, booking):
+        """Launch the jitted `fn` at (op, shape), inside the caller's
+        `compile_span` (whose `booking` is None but on a fresh shape).
+        With a store, through the executable held for (op, shape): the
+        first launch loads it by shape, or lowers and compiles once and
+        stores it, and every later one calls what is held. A loaded
+        executable that refuses its first call costs a trace, never a
+        request."""
+        store = self._exec_store
+        if store is None:
+            return fn(*args)
+        key = (op,) + tuple(shape)
+        exe = self._held.get(key)
+        if exe is not None:
+            return exe(*args)
+        # `booking` may be None here: a second thread at a shape whose
+        # first launch has not come back yet finds (or makes) its own
+        booking = {} if booking is None else booking
+        exe = store.executable(op, shape, fn, args, booking)
+        try:
+            out = exe(*args)
+        except Exception:  # noqa: BLE001 - only a loaded one is retried
+            if booking.get("source") != "store":
+                raise
+            store.refused(op, shape, args)
+            booking.update(source="traced")
+            exe = store.trace(op, shape, fn, args)
+            out = exe(*args)
+        self._held[key] = exe
+        return out
+
     # the module-level bucket_size, kept as a staticmethod so kernel
     # call sites read as "this backend's padding policy"
     _bucket = staticmethod(bucket_size)
@@ -319,8 +368,9 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         # + enqueue) lands in the devscope compile ledger; on hits this
         # is one branch
         with self._compiles.compile_span("ecrecover", (bucket,),
-                                         fresh), launch:
-            qx, qy, ok = self._recover(*args)
+                                         fresh) as booking, launch:
+            qx, qy, ok = self._run("ecrecover", (bucket,), self._recover,
+                                   args, booking)
         # the checked pull on `ok` is the dispatch barrier (block-vs-pull
         # self-checked); limbs_to_pubkeys then pulls the sibling buffers
         # of the SAME computation, so the device phase closes only after
@@ -369,8 +419,9 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         launch = tracing.stage("sig/launch_time", _T_LAUNCH,
                                ctx=dt.span_ctx)
         with self._compiles.compile_span("bls_aggregate", (bucket,),
-                                         fresh), launch:
-            out = self._bls(*args)
+                                         fresh) as booking, launch:
+            out = self._run("bls_aggregate", (bucket,), self._bls, args,
+                            booking)
         res = [bool(b) for b in dt.pull(out)[:n]]
         dt.done()
         dt.record_span("jax/bls_aggregate_dispatch", rows=n, bucket=bucket,
@@ -437,8 +488,9 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         launch = tracing.stage("sig/launch_time", _T_LAUNCH,
                                ctx=dt.span_ctx)
         with self._compiles.compile_span("das_verify", (bucket,),
-                                         fresh), launch:
-            out = das_proofs.batch_verifier()(*args)
+                                         fresh) as booking, launch:
+            out = self._run("das_verify", (bucket,),
+                            das_proofs.batch_verifier(), args, booking)
         res = [bool(b) for b in dt.pull(out)[:n]]
         dt.done()
         dt.record_span("jax/das_verify_dispatch", rows=n, bucket=bucket,
@@ -502,8 +554,9 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         launch = tracing.stage("sig/launch_time", _T_LAUNCH,
                                ctx=dt.span_ctx)
         with self._compiles.compile_span("das_poly_verify", shape,
-                                         fresh), launch:
-            out = self._bls(*args)
+                                         fresh) as booking, launch:
+            out = self._run("das_poly_verify", shape, self._bls, args,
+                            booking)
         if lay.is_mesh:
             self.last_mesh = {
                 "op": "das_verify_multiproofs",
@@ -572,10 +625,11 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
             op, fn = "bls_committee", (
                 self._bls_committee_u16 if self._wire_u16
                 else self._bls_committee)
-        with self._compiles.compile_span(
-                op, (st["bucket"], st["width"], self._wire),
-                st["fresh"]), launch:
-            out = fn(*args)  # async dispatch: returns pre-execution
+        shape = (st["bucket"], st["width"], self._wire)
+        with self._compiles.compile_span(op, shape,
+                                         st["fresh"]) as booking, launch:
+            # async dispatch: returns pre-execution
+            out = self._run(op, shape, fn, args, booking)
         # finalize must close over SCALARS, not the marshal dict: `st`
         # pins every host limb plane (MBs per dispatch) until result(),
         # and an overlapped K-period pipeline holds K of them at once

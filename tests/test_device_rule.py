@@ -190,6 +190,107 @@ def test_this_process_uses_the_one_cache():
     assert jax.config.jax_compilation_cache_dir == device.compile_cache_dir()
 
 
+# == the allocator ==========================================================
+
+_CHURN = """
+import resource, threading
+from gethsharding_tpu.ops import device
+
+def churn(out):
+    before = resource.getrusage(resource.RUSAGE_THREAD).ru_minflt
+    for _ in range(12):
+        block = bytearray(40 << 20)   # over glibc's largest own threshold
+        block[::4096] = b"x" * len(block[::4096])
+        del block
+    out.append(resource.getrusage(resource.RUSAGE_THREAD).ru_minflt - before)
+
+faults = []
+for tuned in (False, True):
+    if tuned:
+        assert device.keep_freed_memory() is True
+    thread = threading.Thread(target=churn, args=(faults,))
+    thread.start()
+    thread.join()
+print(*faults)
+"""
+
+
+def test_a_thread_keeps_what_it_frees_once_told_to():
+    """What the TPU host pays five times over (PERF.md, PR 38): a thread
+    that is not the main one gives a large block back to the kernel at
+    every `free` and faults it in again. In a child: the setting is the
+    process's."""
+    proc = _run(_CHURN, {"JAX_PLATFORMS": "cpu"})
+    assert proc.returncode == 0, proc.stderr
+    plain, kept = map(int, proc.stdout.split())
+    assert plain > 100_000 and kept < plain / 5, (plain, kept)
+
+
+@pytest.mark.parametrize("platform, told", [("cpu", 0), ("tpu", 1)])
+def test_the_allocator_is_told_off_the_cpu_and_only_there(
+        platform, told, monkeypatch):
+    import jax
+
+    class Device:
+        device_kind = "a device"
+
+    Device.platform = platform
+    calls = []
+    monkeypatch.setattr(device, "_resolved", None)
+    monkeypatch.setattr(jax, "devices", lambda *a: [Device()])
+    monkeypatch.setattr(device, "keep_freed_memory",
+                        lambda: calls.append(1))
+    assert device.device_record()["platform"] == platform
+    assert len(calls) == told
+
+
+# == the executable store ===================================================
+
+
+def test_the_store_lies_inside_the_compile_cache():
+    assert device.executable_store_dir() == os.path.join(
+        device.compile_cache_dir(), "executables")
+
+
+@pytest.mark.parametrize("platform, engaged", [("cpu", False),
+                                               ("tpu", True),
+                                               ("gpu", True)])
+def test_the_store_engages_by_what_the_device_record_says(
+        platform, engaged, monkeypatch, tmp_path):
+    from gethsharding_tpu.sigbackend.execstore import ExecutableStore
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    store = ExecutableStore.for_device({"platform": platform})
+    assert (store is not None) == engaged
+    if engaged:
+        assert store.root == str(tmp_path / "executables")
+        assert os.path.dirname(store.dir) == store.root
+
+
+@pytest.mark.parametrize("variable", [
+    "GETHSHARDING_EXEC_STORE", "GETHSHARDING_TPU_EXEC_STORE",
+    "GETHSHARDING_EXECUTABLE_STORE", "GETHSHARDING_STORE"])
+def test_on_the_cpu_the_store_is_off_and_no_variable_turns_it_on(
+        variable, monkeypatch):
+    from gethsharding_tpu.sigbackend import JaxSigBackend
+
+    monkeypatch.setenv(variable, "1")
+    backend = JaxSigBackend()
+    assert backend.device_record["platform"] == "cpu"
+    assert backend._exec_store is None
+
+
+def test_a_mesh_backend_takes_no_store(tmp_path):
+    """The mesh keeps `_mesh_exec`, in-process only: a store handed to
+    a mesh layout is not used."""
+    from gethsharding_tpu.sigbackend import JaxSigBackend
+    from gethsharding_tpu.sigbackend.execstore import ExecutableStore
+
+    backend = JaxSigBackend(mesh_devices=2,
+                            exec_store=ExecutableStore(str(tmp_path)))
+    assert backend._layout.is_mesh and backend._exec_store is None
+
+
 # == chip_smoke.py ==========================================================
 
 

@@ -396,6 +396,60 @@ def test_compile_span_books_wall_per_shape():
     assert top["compiles"] == 1
 
 
+@pytest.mark.parametrize("source", ["store", "traced"])
+def test_compile_span_books_where_the_executable_came_from(source):
+    """A fresh shape's body is handed the booking: a load from the
+    executable store writes `source="store"` and its seconds into it,
+    a trace writes nothing and is booked as `traced`."""
+    watch, _ = _seeded_watch()
+    with watch.compile_span("bls_committee", (112, 144, "i32"),
+                            True) as booking:
+        assert booking == {"source": "traced"}
+        if source == "store":
+            booking.update(source="store", load_s=0.25)
+    with watch.compile_span("bls_committee", (112, 144, "i32"),
+                            False) as booking:
+        assert booking is None  # a hit books nothing
+    desc = watch.describe()
+    assert desc["compiles"] == 1
+    assert desc["from_store"] == (1 if source == "store" else 0)
+    top = desc["top_shapes"][0]
+    assert top["source"] == source
+    assert top.get("load_s") == (0.25 if source == "store" else None)
+
+
+def test_a_launch_through_the_store_is_booked_under_its_source(tmp_path):
+    """The backend's own bracket: a miss is `traced`, the same shape in
+    a second backend is `store` with its load time inside the wall, and
+    `jax/compile_cache/misses` still counts a shape once a backend."""
+    import jax
+    import jax.numpy as jnp
+
+    from gethsharding_tpu.sigbackend import JaxSigBackend
+    from gethsharding_tpu.sigbackend.execstore import ExecutableStore
+
+    op = "test_store_booking_op"
+    fn = jax.jit(lambda x: (x + 1, x * 2))
+    args = (jnp.arange(6, dtype=jnp.int32),)
+    miss, hit = (metrics.counter(f"jax/compile_cache/{name}")
+                 for name in ("misses", "hits"))
+    seen = []
+    watch, _ = _seeded_watch()  # not the process's: its window is shared
+    for _ in range(2):
+        backend = JaxSigBackend(
+            exec_store=ExecutableStore(str(tmp_path / "exe")))
+        backend._compiles = watch
+        n_miss, n_hit = miss.value, hit.value
+        for _ in range(3):
+            backend._counted_launch(op, (6,), fn, *args)
+        assert (miss.value - n_miss, hit.value - n_hit) == (1, 2)
+        slot = watch.describe()["top_shapes"][0]
+        seen.append((slot["source"], slot["compiles"]))
+    assert seen == [("traced", 1), ("store", 2)]
+    assert 0 < slot["load_s"] <= slot["wall_s"]
+    assert watch.describe()["from_store"] == 1
+
+
 def test_note_shape_feeds_process_compile_watch():
     """The sigbackend per-shape cache feeds the process COMPILES
     singleton (storm window + per-shape ledger) on fresh shapes."""
