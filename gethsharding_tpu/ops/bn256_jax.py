@@ -1316,6 +1316,113 @@ def bls_verify_committee_precomp_batch(hx, hy, sigx, sigy, sig_mask,
     return bls_committee_precomp_finalexp(f, ok)
 
 
+# == Fixed-base MSM over the SRS powers: the multiproof check ==============
+# A multiproof row (das/pcs.py) checks e(C − [r(τ)]₁, G2)·e(−π, [z_S(τ)]₂)
+# == 1, where [r(τ)]₁ = Σ c_k·[τ^k]₁ and [z_S(τ)]₂ = Σ z_k·[τ^k]₂ are MSMs
+# over the SRS powers with the row's interpolation and vanishing
+# coefficients. The bases never change, so each power gets a table of its
+# windowed multiples, built ONCE per SRS on the device: entry (k, j, d) =
+# d·2^(w·j)·[τ^k]. A scalar's MSM term is then W = 256/w gathered entries
+# (one per w-bit window), and the row's MSM is one tree sum of the
+# gathered points with the committee kernel's complete projective adders.
+# No scalar multiplication runs anywhere; a zero digit gathers the
+# identity (0:1:0), so zero coefficients and padded terms cost nothing
+# but their slot in the sum.
+
+MSM_WINDOW = 4                    # bits a digit: 16 entries a window
+MSM_WINDOWS = 256 // MSM_WINDOW   # digits a scalar (N < 2^254)
+
+
+def _fixed_base_table(xs, ys, one, add_fn):
+    """Windowed multiples of K affine bases, on the device.
+
+    xs/ys: (K, *coord) affine limbs; `one` the coordinate's 1. Returns
+    (X, Y, Z) each (K·W·2^w, *coord): entry (k·W + j)·2^w + d holds
+    d·2^(w·j)·base_k in projective form, d = 0 the identity. A window's
+    multiples 1..2^w come from w batched adds (1 → 2 → 4 → ... by adding
+    the top multiple to all below it), and 2^w·base is the next window's
+    base."""
+    size = 1 << MSM_WINDOW
+    one = jnp.broadcast_to(jnp.asarray(one), xs.shape)
+    zero = jnp.zeros_like(xs)
+
+    def step(base, _):
+        mult = tuple(c[None] for c in base)           # multiples 1..h
+        while mult[0].shape[0] < size:
+            top = tuple(c[-1:] for c in mult)
+            more = add_fn(mult, tuple(jnp.broadcast_to(t, m.shape)
+                                      for t, m in zip(top, mult)))
+            mult = tuple(jnp.concatenate([m, n]) for m, n in zip(mult, more))
+        ident = (zero[None], one[None], zero[None])
+        row = tuple(jnp.concatenate([i, m[:-1]]) for i, m in zip(ident, mult))
+        return tuple(m[-1] for m in mult), row
+
+    base = (xs, ys, one)
+    _, rows = lax.scan(step, base, None, length=MSM_WINDOWS)
+    # (W, 2^w, K, *coord) -> (K, W, 2^w, *coord) -> flat entries
+    return tuple(
+        jnp.moveaxis(r, 2, 0).reshape((-1,) + xs.shape[1:]) for r in rows)
+
+
+def das_poly_tables(g1x, g1y, g2x, g2y):
+    """The SRS's fixed-base tables, (X, Y, Z) stacked on axis 1:
+    g1 (K1·W·2^w, 3, 22) and g2 (K2·W·2^w, 3, 2, 22) from the affine
+    powers [τ^k]₁ (K1, 22) and [τ^k]₂ (K2, 2, 22)."""
+    t1 = _fixed_base_table(g1x, g1y, FP.one, _g1_proj_add)
+    t2 = _fixed_base_table(g2x, g2y, FP2_ONE, _g2_proj_add)
+    return jnp.stack(t1, axis=1), jnp.stack(t2, axis=1)
+
+
+def fixed_base_msm(table, digits, add_fn):
+    """Σ_k scalar_k·base_k per row from a `das_poly_tables` table.
+
+    table: (K·W·2^w, 3, *coord); digits: (B, S, W) little-endian w-bit
+    digits of S scalars a row, S ≤ K. Returns the projective (X, Y, Z)
+    sums, each (B, *coord)."""
+    rows, terms, windows = digits.shape
+    slot = (np.arange(terms)[:, None] * windows
+            + np.arange(windows)[None, :]) << MSM_WINDOW
+    idx = jnp.asarray(slot, jnp.int32) + digits.astype(jnp.int32)
+    pts = jnp.take(table, idx.reshape(rows, terms * windows), axis=0)
+    axis = -1 - (table.ndim - 2)                       # the term axis
+    return _tree_reduce((pts[:, :, 0], pts[:, :, 1], pts[:, :, 2]), axis,
+                        add_fn)
+
+
+def das_poly_verify_batch(cx, cy, c_inf, px, py, p_inf, r_digits, z_digits,
+                          valid, g1_table, g2_table):
+    """Batched multiproof check, MSMs included: per row
+    e(C − R, G2_GEN)·e(−π, Z) == 1 with R = [r(τ)]₁, Z = [z_S(τ)]₂ summed
+    on the device from the resident SRS tables.
+
+    cx/cy: (B, 22) commitment limbs, c_inf (B,) its infinity flag;
+    px/py, p_inf: the proof π likewise; r_digits (B, S, W) and
+    z_digits (B, S+1, W): the interpolation and vanishing coefficients'
+    digits (zero-padded); valid (B,) the host's shape and decode checks.
+
+    Infinity follows the scalar `pcs.verify_multi` exactly: its pairing
+    skips a pair with a point at infinity, and a pair of non-infinite
+    G1 x G2 points never pairs to 1. So a row whose A = C − R is at
+    infinity holds iff its second pair is skipped too (π or Z at
+    infinity), a row with only the second pair skipped fails, and the
+    others take the pairing's verdict. Returns (B,) bool."""
+    with jax.named_scope("das/poly_msm_g1"):
+        rX, rY, rZ = fixed_base_msm(g1_table, r_digits, _g1_proj_add)
+    with jax.named_scope("das/poly_msm_g2"):
+        zX, zY, zZ = fixed_base_msm(g2_table, z_digits, _g2_proj_add)
+    m = c_inf[..., None]
+    one = jnp.broadcast_to(jnp.asarray(FP.one), cx.shape)
+    c = (jnp.where(m, 0, cx), jnp.where(m, one, cy), jnp.where(m, 0, one))
+    aX, aY, aZ = _g1_proj_add(c, (rX, FP.neg(rY), rZ))
+    a_inf = FP.is_zero(aZ)
+    skip = p_inf | fp2_is_zero(zZ)
+    with jax.named_scope("bls/miller"):
+        f = _bls_miller_opt((aX, aY, aZ), px, py, (zX, zY, zZ))
+    with jax.named_scope("bls/final_exp"):
+        one_f = pairing_is_one(f)
+    return jnp.where(a_inf | skip, a_inf & skip, one_f) & valid
+
+
 # == host-side converters ==================================================
 
 
@@ -1347,6 +1454,22 @@ def g2_to_limbs(points: Sequence[ref.G2Point]):
             ys.append(np.stack([int_to_limbs(y.a), int_to_limbs(y.b)]))
             ok.append(True)
     return (np.stack(xs), np.stack(ys), np.asarray(ok))
+
+
+def msm_digits(rows, terms: int) -> np.ndarray:
+    """Scalar rows -> the (B, terms, W) uint8 digit plane of
+    `fixed_base_msm`: row b's scalar k as W little-endian w-bit digits,
+    zero-padded to `terms`; a None row is all zeros (the identity)."""
+    raw = bytearray(len(rows) * terms * 32)
+    for b, row in enumerate(rows):
+        for k, value in enumerate(row or ()):
+            at = (b * terms + k) * 32
+            raw[at:at + 32] = (value % N).to_bytes(32, "little")
+    bits = np.unpackbits(np.frombuffer(bytes(raw), np.uint8),
+                         bitorder="little")
+    bits = bits.reshape(len(rows), terms, MSM_WINDOWS, MSM_WINDOW)
+    weights = (1 << np.arange(MSM_WINDOW)).astype(np.uint8)
+    return (bits * weights).sum(axis=-1, dtype=np.uint8)
 
 
 _COORD_BYTES = pointrows.COORD_BYTES

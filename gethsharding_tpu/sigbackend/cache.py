@@ -172,9 +172,7 @@ class ResidentPkCache:
         zero = sum(int(b.nbytes)
                    for row in self._pk_zero_rows.copy().values()
                    for b in row)
-        gen = getattr(self, "_gen_lines_dev", None)
-        if gen is not None:
-            zero += int(gen.nbytes)
+        zero += sum(int(b.nbytes) for b in self._fixed_tables())
         with self._pk_dev_lock:
             return (self._pk_dev_bytes + self._pk_batch_memo_nbytes
                     + self._pk_line_memo_nbytes + zero)
@@ -199,9 +197,20 @@ class ResidentPkCache:
         # without the dev lock, and a mid-iteration insert would raise
         for row in self._pk_zero_rows.copy().values():
             out.extend(row)
+        out.extend(self._fixed_tables())
+        return out
+
+    def _fixed_tables(self) -> list:
+        """The resident tables of fixed points, outside the LRU: the
+        generator line table and the SRS's fixed-base MSM tables, once
+        built (single-device layouts)."""
+        out = []
         gen = getattr(self, "_gen_lines_dev", None)
         if gen is not None:
             out.append(gen)
+        srs = getattr(self, "_srs_tabs", None)
+        if srs is not None and not getattr(self, "_mesh_shards", None):
+            out.extend(srs[1])
         return out
 
     # -- pubkey-row limb cache (host) --------------------------------------

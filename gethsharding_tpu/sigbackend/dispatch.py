@@ -143,10 +143,20 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         # operations, so a bucket cut into lane blocks pays each block's
         # cost again (PERF.md section 6, PR 31)
         self._precomp_full = jax.jit(_precomp_full)
+        # the multiproof check with both MSMs on the device, and the
+        # builder of the SRS's fixed-base tables it reads (`_srs_tables`)
+        self._das_poly = jax.jit(bn256_jax.das_poly_verify_batch)
+        self._das_poly_tables = jax.jit(bn256_jax.das_poly_tables)
         # the backend is a process-wide singleton shared by every actor
         # thread (get_backend caches instances): all cache structures
         # are lock-guarded (cache.py)
         self._init_pk_caches()
+        import threading
+
+        self._srs_lock = threading.Lock()
+        # the multiproof rows whose two MSMs the device summed (a
+        # malformed row is decided on the host)
+        self._m_msm_rows = metrics.counter("das/poly/device_msm_rows")
         self._m_wire_bytes = metrics.counter("jax/wire/bytes")
         # the G2 part of it: pk planes shipped cold (0 on a warm audit)
         self._m_g2_bytes = metrics.counter("jax/wire/g2_bytes")
@@ -163,8 +173,6 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         # (op, bucket-shape) first-sightings makes recompile storms —
         # e.g. unbucketed traffic widening the shape set — visible as
         # counters and span tags instead of mystery latency spikes.
-        import threading
-
         self._shape_seen: set = set()
         self._shape_lock = threading.Lock()
         self._m_shape_hit = metrics.counter("jax/compile_cache/hits")
@@ -501,21 +509,24 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
 
     def das_verify_multiproofs(self, commitments, index_rows, eval_rows,
                                proofs, ns):
-        """One batched two-pair pairing dispatch for the whole
-        multiproof batch: per row the host folds the interpolation and
-        vanishing MSMs into (A, π, Z) limb planes
-        (das/poly_proofs.marshal_multiproofs) and the device checks
-        e(A, G2_GEN)·e(−π, Z) == 1 through the SAME jitted kernel the
-        aggregate-vote path uses — no new kernel, no new compile
-        shapes beyond the bucket. Verdicts are bit-identical to the
-        scalar PCS reference because every malformed-row rejection and
-        every degenerate (infinity-point) row is resolved into the
-        planes at marshal time.
+        """One dispatch for the whole multiproof batch, both MSMs
+        included: the host checks shapes, decodes C and π and writes
+        each row's interpolation and vanishing coefficients as digit
+        planes (das/poly_proofs.marshal_multiproofs); the device sums
+        [r(τ)]₁ and [z_S(τ)]₂ from the SRS's resident fixed-base tables
+        (`_srs_tables`), folds A = C − [r(τ)]₁ and checks
+        e(A, G2_GEN)·e(−π, Z) == 1 with the committee kernel's
+        projective pairing (ops/bn256_jax.das_poly_verify_batch).
+        Verdicts are bit-identical to the scalar PCS reference: every
+        malformed row is `valid=False` at marshal time, and the rows
+        with a point at infinity are decided on the device by the scalar
+        pairing's rule.
 
-        On a mesh layout the planes ship pre-sharded along the leading
-        (row) axis and the SAME jitted kernel partitions over them —
-        per-row work, so ZERO collectives; `last_mesh` records the
-        sharded execution for the non-vacuity checks."""
+        On a mesh layout the row planes ship pre-sharded along the
+        leading (row) axis, the tables replicated, and the SAME program
+        partitions over them — per-row work, so ZERO collectives;
+        `last_mesh` records the sharded execution for the non-vacuity
+        checks."""
         from gethsharding_tpu.das import poly_proofs
 
         jnp = self._jnp
@@ -529,25 +540,29 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         # NamedSharding split is even; padded rows are marshalled
         # rejections exactly like single-device padding
         bucket = lay.mesh_bucket(n) if lay.is_mesh else self._bucket(n)
-        shape = (bucket, lay.n_devices) if lay.is_mesh else (bucket,)
-        fresh = self._note_shape("das_poly_verify", *shape)
+        tables = self._srs_tables()
         with tracing.stage("sig/host_marshal_time", _T_HOST_MARSHAL):
             st = poly_proofs.marshal_multiproofs(
                 commitments, index_rows, eval_rows, proofs, ns, bucket)
-        planes = (st["px"], st["py"], st["ax"], st["ay"], st["zx"],
-                  st["zy"], st["valid"])
+        terms = st["terms"]
+        shape = ((bucket, terms, lay.n_devices) if lay.is_mesh
+                 else (bucket, terms))
+        fresh = self._note_shape("das_poly_verify", *shape)
+        planes = (st["cx"], st["cy"], st["c_inf"], st["px"], st["py"],
+                  st["p_inf"], st["r_digits"], st["z_digits"], st["valid"])
         ship = lay.place if lay.is_mesh else jnp.asarray
         with tracing.stage("sig/transfer_time", _T_TRANSFER):
-            args = tuple(ship(p) for p in planes)
+            args = tuple(ship(p) for p in planes) + tables
         proof_bytes = sum(int(p.nbytes) for p in planes)
-        # same wire-ledger contract as the sample path: the marshalled
-        # pairing planes ARE this dispatch's host->device bytes
+        # same wire-ledger contract as the sample path: the row planes
+        # ARE this dispatch's host->device bytes (the tables went once)
         self.last_wire = {"op": "das_verify_multiproofs",
                           "wire_bytes": proof_bytes,
                           "sample_wire_bytes": proof_bytes,
                           "rows": n, "bucket": bucket, "wire": self._wire}
         RECORDER.record_wire("das_verify_multiproofs", self.last_wire)
         self._m_wire_bytes.inc(proof_bytes)
+        self._m_msm_rows.inc(st["msm_rows"])
         tracing.tag_current_add(wire_bytes=proof_bytes,
                                 sample_wire_bytes=proof_bytes)
         dt.dispatched()  # marshal (incl. transfer staging) closes here
@@ -555,7 +570,7 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
                                ctx=dt.span_ctx)
         with self._compiles.compile_span("das_poly_verify", shape,
                                          fresh) as booking, launch:
-            out = self._run("das_poly_verify", shape, self._bls, args,
+            out = self._run("das_poly_verify", shape, self._das_poly, args,
                             booking)
         if lay.is_mesh:
             self.last_mesh = {
@@ -567,10 +582,54 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
             }
         res = [bool(b) for b in dt.pull(out)[:n]]
         dt.done()
+        # the points both MSMs sum, padded rows and terms included
+        windows = st["r_digits"].shape[-1]
         dt.record_span("jax/das_poly_verify_dispatch", rows=n,
                        bucket=bucket, compile="miss" if fresh else "hit",
-                       sample_wire_bytes=proof_bytes)
+                       sample_wire_bytes=proof_bytes,
+                       msm_terms=bucket * (2 * terms + 1) * windows,
+                       device_msm_rows=st["msm_rows"])
         return res
+
+    # (SRS key, (g1 table, g2 table)) once the first multiproof dispatch
+    # built them; None before, and on partially-built test instances
+    _srs_tabs = None
+
+    def _srs_tables(self) -> tuple:
+        """The fixed-base tables of the dev SRS (`pcs.dev_srs()`), built
+        ON THE DEVICE by one program the first time a multiproof batch
+        comes and held as arguments, never baked into an executable:
+        G1 over the first MAX_MULTIPROOF_INDICES powers, G2 over all
+        MAX_MULTIPROOF_INDICES + 1, so every set size up to the cap
+        reads the same tables. Rebuilt only when the SRS changes (its
+        seed or size). On a mesh, replicated to every device."""
+        from gethsharding_tpu.das import pcs
+
+        srs = pcs.dev_srs()
+        key = (srs.seed, len(srs.g1_powers))
+        with self._srs_lock:
+            held = self._srs_tabs
+            if held is not None and held[0] == key:
+                return held[1]
+            jnp, bn = self._jnp, self._bn
+            k1 = min(pcs.MAX_MULTIPROOF_INDICES, len(srs.g1_powers))
+            g1x, g1y, _ = bn.g1_to_limbs(srs.g1_powers[:k1])
+            g2x, g2y, _ = bn.g2_to_limbs(srs.g2_powers)
+            args = tuple(jnp.asarray(p) for p in (g1x, g1y, g2x, g2y))
+            shape = (k1, len(srs.g2_powers), bn.MSM_WINDOW)
+            fresh = self._note_shape("das_poly_tables", *shape)
+            with self._compiles.compile_span("das_poly_tables", shape,
+                                             fresh) as booking:
+                tables = self._run("das_poly_tables", shape,
+                                   self._das_poly_tables, args, booking)
+            if self._layout.is_mesh:
+                from jax.sharding import NamedSharding, PartitionSpec
+
+                every = NamedSharding(self._layout.mesh, PartitionSpec())
+                tables = tuple(self._jax.device_put(t, every)
+                               for t in tables)
+            self._srs_tabs = (key, tables)
+            return tables
 
     # -- the staged committee path -----------------------------------------
     # marshal (host limbs + cache resolution) -> transfer (host->device)

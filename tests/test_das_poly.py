@@ -259,3 +259,31 @@ def test_poly_proof_bytes_are_constant_and_5x_smaller():
         assert row["merkle_proof_bytes"] == row["k"] * 8 * 32
         assert row["poly_proof_bytes"] == 64
         assert 0.0 < row["p_detect"] <= 1.0
+
+
+# -- the host's coefficients (ISSUE 39) --------------------------------------
+
+
+@pytest.mark.parametrize("m", [1, 2, 16, 64])
+def test_row_coeffs_equal_the_reference_coefficients(m):
+    from gethsharding_tpu.das.poly_proofs import row_coeffs
+
+    rng = random.Random(3900 + m)
+    xs = rng.sample(range(255), m)
+    ys = [rng.randrange(N) for _ in range(m)]
+    ys[0] = 0                       # a zero evaluation adds nothing
+    r, z = row_coeffs(xs, ys)
+    assert r == pcs.lagrange_coeffs(xs, ys)
+    assert z == pcs.vanishing_coeffs(xs)
+
+
+def test_marshal_keeps_malformed_rows_off_the_msm_count():
+    from gethsharding_tpu.das.poly_proofs import marshal_multiproofs
+
+    cols = [list(col) for col in _poly_rows()]
+    st = marshal_multiproofs(*cols, 16)
+    assert st["msm_rows"] == sum(st["valid"]) == 7
+    assert st["terms"] == 4         # the widest well-shaped row: 4 indices
+    assert st["r_digits"].shape[:2] == (16, 4)
+    assert st["z_digits"].shape[:2] == (16, 5)
+    assert not st["r_digits"][~st["valid"]].any()

@@ -11,7 +11,9 @@ Since ISSUE 37 the caller's half of the request has stage clocks too
 (`rpc/client/<m>/...`, read under `caller/` in `RpcReplicaBackend.metrics`),
 the server times the frame's receive, and the three device operations no
 cell drives (`shard_ecrecover`, `shard_verifyAggregates`,
-`shard_dasPolyVerify`) are cases of the registry's tests.
+`shard_dasPolyVerify`) are cases of the registry's tests. Since ISSUE 39
+the multiproof's coefficients have a stage of their own inside the host
+marshal, and a counter holds the rows whose MSMs the device summed.
 
 Counts and containment only: no time measured here means anything.
 """
@@ -384,6 +386,38 @@ def test_each_whole_covers_its_parts(request, method, fixture, whole):
     # a snapshot rounds a mean to the microsecond
     assert parts <= _delta(snap, _named(whole, method), "total") \
         + 1e-5 * len(WHOLES[whole])
+
+
+# == the multiproof's host coefficients and device MSMs (ISSUE 39) ==========
+
+
+def test_poly_coeffs_time_nests_in_host_marshal_time(dasPolyVerify_untraced):
+    snap = dasPolyVerify_untraced
+    assert _delta(snap, "sig/poly_coeffs_time") == 1
+    # a snapshot rounds a mean to the microsecond
+    assert 0 < _delta(snap, "sig/poly_coeffs_time", "total") \
+        <= _delta(snap, "sig/host_marshal_time", "total") + 1e-5
+
+
+def test_device_msm_rows_counts_the_rows_of_a_dispatch(
+        dasPolyVerify_untraced):
+    snap = dasPolyVerify_untraced
+    assert _delta(snap, "serving/das_poly_verify/dispatches") == 1
+    assert _delta(snap, "das/poly/device_msm_rows") == 1
+
+
+def test_device_msm_rows_is_zero_for_an_all_malformed_dispatch(
+        served, dasPolyVerify_untraced):
+    """An off-curve commitment fails its decode on the host: the row
+    is a False and no MSM of it runs on the device (the shape is the
+    honest row's, so nothing compiles)."""
+    _, (_, *rest), _ = served.plain["dasPolyVerify"]
+    before = served.client.metrics()
+    assert served.client.das_verify_multiproofs([b"\x07" * 64],
+                                                *rest) == [False]
+    snap = {"before": before, "after": served.client.metrics()}
+    assert _delta(snap, "serving/das_poly_verify/dispatches") == 1
+    assert _delta(snap, "das/poly/device_msm_rows") == 0
 
 
 # == the caller's half (ISSUE 37) ===========================================
@@ -882,12 +916,16 @@ with open(os.path.join(REPO, "BENCHMARK.json")) as _src:
 def test_every_per_layer_metric_reads_a_number_from_two_snapshots(
         request, name):
     # what only a line-table miss writes is read over the keyed request,
-    # what only `shard_dasVerify` writes over a request of that method
-    das = name.startswith("das_")
+    # what only `shard_dasVerify` or `shard_dasPolyVerify` writes over a
+    # request of that method
+    poly = name.startswith("das_poly_")
+    das = name.startswith("das_") and not poly
     snap = request.getfixturevalue(
         "keyed" if name.startswith("line_") else
+        "dasPolyVerify_untraced" if poly else
         "das_untraced" if das else "untraced")
-    op = METHODS["dasVerify" if das else "verifyCommittees"]["op"]
+    op = (PLAIN["dasPolyVerify"] if poly
+          else METHODS["dasVerify" if das else "verifyCommittees"]["op"])
     bench = os.path.join(REPO, "benchmark")
     if bench not in sys.path:
         sys.path.insert(0, bench)
@@ -907,6 +945,8 @@ def test_every_per_layer_metric_reads_a_number_from_two_snapshots(
         assert value == 2.0         # both rows of the request
     if name == "das_chunk_bytes":
         assert value == 4 * 4096    # bucket 4, a 4,096-byte chunk a row
+    if name == "das_poly_device_msm_rows":
+        assert value == 1.0         # the request's one row
 
 
 # == the kernels' names =====================================================
