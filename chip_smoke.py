@@ -12,17 +12,15 @@ compared with the scalar reference (`PythonSigBackend`).
 
 Legs, one chip-holding child process at a time:
 
-  A  served path at module defaults: the period twice with row keys
-     (cold, then warm) and once without (recompute path), then one small
-     request per other kernel family (ecrecover, aggregate verify, DAS
-     sample verify, DAS multiproof verify)
+  A  served path at module defaults (the pairing check in the Pallas
+     Miller and final-exp kernels, the platform's choice): the period
+     twice with row keys (cold, then warm) and once without (recompute
+     path), then one small request per other kernel family (ecrecover,
+     aggregate verify, DAS sample verify, DAS multiproof verify)
   B  every Pallas kernel in gethsharding_tpu/ops/ compiled
      (interpret=False) at the audit's block shape against its XLA twin
-  C  the period without row keys under LIMB_FORM=exact FINALEXP=mega
-     MILLER=mega, the configuration a chip last ranked first: the
-     Miller and final-exp kernels on the served path at full size.
-     (The keyed pair under these knobs passed once on the chip, PR 21;
-     its two further cold compiles do not fit the run's 1200 s.)
+  C  the period without row keys under LIMB_FORM=exact: the 22-limb
+     form around the same kernels on the served path at full size
   D  leg A's period requests on a four-device mesh (--mesh-devices 4),
      when the first child reported >= 4 devices
 
@@ -57,9 +55,7 @@ REPO = os.path.dirname(os.path.abspath(__file__))
 SHARDS, COMMITTEE, QUORUM = 100, 135, 90
 PERIOD = 1
 SCALAR_SAMPLE_ROWS = 4   # period rows recomputed by the scalar reference
-MEGA_ENV = {"GETHSHARDING_TPU_LIMB_FORM": "exact",
-            "GETHSHARDING_TPU_FINALEXP": "mega",
-            "GETHSHARDING_TPU_MILLER": "mega"}
+MEGA_ENV = {"GETHSHARDING_TPU_LIMB_FORM": "exact"}
 
 # set once in main(); prefixes every line so a rehearsal can never be
 # read as a chip run
@@ -558,8 +554,9 @@ def kernel_child(rehearsal: bool) -> int:
             want, got)
         return bool(np.asarray(same).all())
 
+    # the twins are the XLA forms the platform would not choose here
     miller_twin = jax.jit(
-        lambda s, hx, hy, p: k._bls_miller_opt(s, hx, hy, p))
+        lambda s, hx, hy, p: k._bls_miller_opt(s, hx, hy, p, pallas=False))
 
     with ThreadPoolExecutor(max_workers=8) as pool:
         def timed(fn):
@@ -582,7 +579,8 @@ def kernel_child(rehearsal: bool) -> int:
 
         def finalexp():
             f = f_twin.result()
-            want = [bool(b) for b in np.asarray(jax.jit(k.pairing_is_one)(f))]
+            want = [bool(b) for b in np.asarray(jax.jit(
+                functools.partial(k.pairing_is_one, pallas=False))(f))]
             got = [bool(b) for b in np.asarray(
                 mega.finalexp_is_one(f, interpret=interpret))]
             return want == got == [True, False]

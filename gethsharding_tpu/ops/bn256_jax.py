@@ -26,6 +26,10 @@ programs over the 12-bit-limb field engine (`ops/limb.py`):
   tower inversion, then the standard hard-part addition chain
   (Devegili–Scott–Dahab) over f^u powers and Frobenius maps, run as a
   register-machine `lax.scan` so each fp12 primitive compiles once.
+- On every platform but the CPU the projective Miller walk and the final
+  exponentiation run as the two Pallas kernels of
+  `ops/pallas_finalexp.py` (`pairing_in_pallas`); the XLA forms here
+  are the CPU's and the mesh's.
 
 Everything is shape-static, integer-only, and differential-tested against
 the scalar `gethsharding_tpu.crypto.bn256` (tests/test_bn256_jax.py).
@@ -62,32 +66,11 @@ FP = ModArith(P)
 # products needs a multiple of p ≥ 2^547.
 _PAD530 = FP.pad_mult(2 * _limb.LAZY_BITS + 1)  # ≥ two subtracted products
 
-# GETHSHARDING_TPU_FINALEXP=mega routes the ENTIRE fraction-stacked final
-# exponentiation (easy part, x^u ladders, hard part — ~250 sequential
-# fp12 ops) through the single-dispatch Pallas mega-kernel
-# (ops/pallas_finalexp.py): one kernel launch, VMEM-resident register
-# file, zero HBM round-trips between steps. The kernel's arithmetic is
-# self-contained wide/relaxed, so the knob composes with either limb
-# form.
-FINALEXP = os.environ.get("GETHSHARDING_TPU_FINALEXP", "xla")
-if FINALEXP not in ("xla", "mega"):
-    raise ValueError(f"GETHSHARDING_TPU_FINALEXP must be 'xla' or 'mega', "
-                     f"got {FINALEXP!r}")
-
-# GETHSHARDING_TPU_MILLER=mega routes the PROJECTIVE shared-accumulator
-# Miller walk (the BLS committee-verify hot path) through its own
-# single-launch Pallas register machine (ops/pallas_finalexp.miller_f).
-# With both knobs mega, the whole post-aggregation pairing check runs in
-# TWO kernel launches.
-MILLER = os.environ.get("GETHSHARDING_TPU_MILLER", "xla")
-if MILLER not in ("xla", "mega"):
-    raise ValueError(f"GETHSHARDING_TPU_MILLER must be 'xla' or 'mega', "
-                     f"got {MILLER!r}")
-
 # GETHSHARDING_TPU_AGG=mega routes the masked committee tree reductions
 # through the single-launch aggregation kernels (ops/pallas_finalexp.
-# aggregate_proj) — with all three mega knobs the audit dispatch is 4
-# kernel launches total (G1 agg, G2 agg, Miller, final exp).
+# aggregate_proj) off the CPU — beside the pairing's two kernels
+# (`pairing_in_pallas`) the audit dispatch is then 4 kernel launches
+# (G1 agg, G2 agg, Miller, final exp).
 AGG = os.environ.get("GETHSHARDING_TPU_AGG", "xla")
 if AGG not in ("xla", "mega"):
     raise ValueError(f"GETHSHARDING_TPU_AGG must be 'xla' or 'mega', "
@@ -666,9 +649,22 @@ def fp12_eq(x, y):
     return jnp.all(FP.canon(x) == FP.canon(y), axis=(-1, -2, -3))
 
 
-def pairing_is_one(f):
-    """is_one(final_exponentiation(f)) without any field inversion."""
-    if FINALEXP == "mega" and _limb._pallas_wanted():
+def pairing_in_pallas(pallas=None) -> bool:
+    """Does a pairing check run in the Pallas kernels of
+    ops/pallas_finalexp.py (`miller_f`, `finalexp_is_one`: each a
+    register machine in ONE launch, where XLA runs ~90 Miller steps and
+    ~250 fp12 operations as chains of fusions)? `pallas` is the caller's
+    trace-time choice: None takes the platform's choice (every platform
+    but the CPU, `limb._pallas_wanted`), False the XLA path, which a
+    mesh step passes because a `pallas_call` inside `shard_map` fails at
+    trace."""
+    return _limb._pallas_wanted() if pallas is None else bool(pallas)
+
+
+def pairing_is_one(f, pallas=None):
+    """is_one(final_exponentiation(f)) without any field inversion;
+    `pallas` as in `pairing_in_pallas`."""
+    if pairing_in_pallas(pallas):
         from gethsharding_tpu.ops.pallas_finalexp import finalexp_is_one
 
         return finalexp_is_one(f)
@@ -829,7 +825,7 @@ def _jadd_step(X1, Y1, Z1, cand, px, py):
     return line, X3, Y3, Z3
 
 
-def _bls_miller_opt(sig, hx, hy, pk):
+def _bls_miller_opt(sig, hx, hy, pk, pallas=None):
     """Shared-accumulator optimal-ate Miller product for the BLS check.
 
     Pair 0: (sig, G2_GEN) via precomputed static lines evaluated at sig.
@@ -843,13 +839,14 @@ def _bls_miller_opt(sig, hx, hy, pk):
     full-Jacobian chord steps. Every extra scale lives in Fp2* and dies
     in the final exponentiation. Affine callers pass z = None — a
     TRACE-TIME specialization that keeps the cheaper mixed-addition
-    steps and constant generator-line terms of the affine form.
+    steps and constant generator-line terms of the affine form. The
+    projective walk runs in `pallas_finalexp.miller_f` wherever
+    `pairing_in_pallas(pallas)`; the affine one always here.
     """
     sx, sy, sz = sig
     pkx, pky, pkz = pk
     affine = pkz is None
-    if (MILLER == "mega" and not affine and sz is not None
-            and _limb._pallas_wanted()):
+    if not affine and sz is not None and pairing_in_pallas(pallas):
         from gethsharding_tpu.ops.pallas_finalexp import miller_f
 
         return miller_f(sig, hx, hy, pk)
@@ -1084,7 +1081,8 @@ def aggregate_g2_proj(xs, ys, mask):
     return _tree_reduce((px, py, pz), -3, _g2_proj_add)
 
 
-def bls_verify_aggregate_batch(hx, hy, sx, sy, pkx, pky, valid):
+def bls_verify_aggregate_batch(hx, hy, sx, sy, pkx, pky, valid,
+                               pallas=None):
     """Batched BLS aggregate-vote verification (BASELINE.md config 2/3).
 
     For each batch element b: e(sig_b, G2_GEN) == e(H_b, aggpk_b), checked
@@ -1094,14 +1092,17 @@ def bls_verify_aggregate_batch(hx, hy, sx, sy, pkx, pky, valid):
     pkx/pky: (..., 2, 22) G2 limbs (aggregate public key);
     valid: (...,) bool — invalid rows (infinity/malformed, rejected
     host-side) return False.
+    `pallas`: the final exponentiation's kernel, as in
+    `pairing_in_pallas` (the affine walk is XLA's).
     Returns (...,) bool.
     """
     f = _bls_miller_opt((sx, sy, None), hx, hy, (pkx, pky, None))
-    return pairing_is_one(f) & valid
+    return pairing_is_one(f, pallas) & valid
 
 
 def bls_aggregate_verify_committee_batch(hx, hy, sigx, sigy, sig_mask,
-                                         pkx, pky, pk_mask, valid):
+                                         pkx, pky, pk_mask, valid,
+                                         pallas=None):
     """Aggregate AND verify per-shard committee votes in one dispatch.
 
     The full notary hot-loop kernel: per batch row (= shard), sum the
@@ -1115,6 +1116,8 @@ def bls_aggregate_verify_committee_batch(hx, hy, sigx, sigy, sig_mask,
     voter pubkeys with pk_mask (B, C); any C >= 1 (pad rows masked).
     Identity aggregates (empty committee or adversarial cancellation)
     are rejected, matching the scalar `bls_verify_aggregate`.
+    `pallas`: the Miller walk's and the final exponentiation's kernels,
+    as in `pairing_in_pallas`.
     Returns (B,) bool.
     """
     # the scopes are metadata only: they name the four stages in the
@@ -1126,9 +1129,9 @@ def bls_aggregate_verify_committee_batch(hx, hy, sigx, sigy, sig_mask,
         pX, pY, pZ = aggregate_g2_proj(pkx, pky, pk_mask)
     inf = FP.is_zero(sZ) | fp2_is_zero(pZ)
     with jax.named_scope("bls/miller"):
-        f = _bls_miller_opt((sX, sY, sZ), hx, hy, (pX, pY, pZ))
+        f = _bls_miller_opt((sX, sY, sZ), hx, hy, (pX, pY, pZ), pallas)
     with jax.named_scope("bls/final_exp"):
-        one = pairing_is_one(f)
+        one = pairing_is_one(f, pallas)
     return one & valid & ~inf
 
 
@@ -1296,24 +1299,26 @@ def bls_committee_precomp_miller(hx, hy, sigx, sigy, sig_mask,
     return f, ok
 
 
-def bls_committee_precomp_finalexp(f, ok):
+def bls_committee_precomp_finalexp(f, ok, pallas=None):
     """Finalexp stage of the precomp committee audit."""
-    return pairing_is_one(f) & ok
+    return pairing_is_one(f, pallas) & ok
 
 
 def bls_verify_committee_precomp_batch(hx, hy, sigx, sigy, sig_mask,
                                        table, pk_inf, valid,
-                                       gen_lines=None):
+                                       gen_lines=None, pallas=None):
     """Precomp twin of `bls_aggregate_verify_committee_batch`: the G2
     aggregation and the fixed-argument point arithmetic were paid once
     in `precompute_g2_lines`; this consumes the resident table. Verdicts
     are bit-identical to the recompute kernel for the same committee
-    content (same primitives, same operands, same order).
+    content (same primitives, same operands, same order). `pallas`: the
+    final exponentiation's kernel, as in `pairing_in_pallas` (the
+    table-fed walk is XLA's).
     Returns (B,) bool."""
     f, ok = bls_committee_precomp_miller(hx, hy, sigx, sigy, sig_mask,
                                          table, pk_inf, valid,
                                          gen_lines=gen_lines)
-    return bls_committee_precomp_finalexp(f, ok)
+    return bls_committee_precomp_finalexp(f, ok, pallas)
 
 
 # == Fixed-base MSM over the SRS powers: the multiproof check ==============
@@ -1390,7 +1395,7 @@ def fixed_base_msm(table, digits, add_fn):
 
 
 def das_poly_verify_batch(cx, cy, c_inf, px, py, p_inf, r_digits, z_digits,
-                          valid, g1_table, g2_table):
+                          valid, g1_table, g2_table, pallas=None):
     """Batched multiproof check, MSMs included: per row
     e(C − R, G2_GEN)·e(−π, Z) == 1 with R = [r(τ)]₁, Z = [z_S(τ)]₂ summed
     on the device from the resident SRS tables.
@@ -1405,7 +1410,8 @@ def das_poly_verify_batch(cx, cy, c_inf, px, py, p_inf, r_digits, z_digits,
     G1 x G2 points never pairs to 1. So a row whose A = C − R is at
     infinity holds iff its second pair is skipped too (π or Z at
     infinity), a row with only the second pair skipped fails, and the
-    others take the pairing's verdict. Returns (B,) bool."""
+    others take the pairing's verdict. `pallas`: the pairing's
+    kernels, as in `pairing_in_pallas`. Returns (B,) bool."""
     with jax.named_scope("das/poly_msm_g1"):
         rX, rY, rZ = fixed_base_msm(g1_table, r_digits, _g1_proj_add)
     with jax.named_scope("das/poly_msm_g2"):
@@ -1417,9 +1423,9 @@ def das_poly_verify_batch(cx, cy, c_inf, px, py, p_inf, r_digits, z_digits,
     a_inf = FP.is_zero(aZ)
     skip = p_inf | fp2_is_zero(zZ)
     with jax.named_scope("bls/miller"):
-        f = _bls_miller_opt((aX, aY, aZ), px, py, (zX, zY, zZ))
+        f = _bls_miller_opt((aX, aY, aZ), px, py, (zX, zY, zZ), pallas)
     with jax.named_scope("bls/final_exp"):
-        one_f = pairing_is_one(f)
+        one_f = pairing_is_one(f, pallas)
     return jnp.where(a_inf | skip, a_inf & skip, one_f) & valid
 
 

@@ -48,8 +48,9 @@ LIMB_MASK = (1 << LIMB_BITS) - 1
 #   normalize — minimizes sequential depth (TPU latency).
 # - "exact": 22-limb operands, value < 2^264, three exact carries per
 #   normalize — minimizes schoolbook width (+29% fewer product FLOPs);
-#   the form the Pallas mega kernels (ops/pallas_finalexp.py) were last
-#   measured under (PERF.md section 6, PR 30).
+#   the form the Pallas kernels (ops/pallas_finalexp.py) were first
+#   measured beside (PERF.md section 6, PR 30). The kernels compute in
+#   25 limbs whatever the form, so it moves only the XLA work left.
 LIMB_FORM = os.environ.get("GETHSHARDING_TPU_LIMB_FORM", "wide")
 if LIMB_FORM == "wide":
     NLIMBS = 25    # operand width: 300 bits of capacity
@@ -225,11 +226,10 @@ def _carry_scan(z: jnp.ndarray):
 
 
 def _pallas_wanted() -> bool:
-    """Do the requested Pallas kernels run in this process? On every
-    platform but the CPU (whose interpreter path is for tests), yes —
-    and a failure to resolve the backend or to compile the kernel
-    propagates: a knob that asked for a kernel never gets the XLA path
-    in its place."""
+    """Do the Pallas kernels run in this process? On every platform but
+    the CPU (whose interpreter path is for tests), yes — and a failure
+    to resolve the backend or to compile the kernel propagates: a
+    program that chose a kernel never gets the XLA path in its place."""
     return jax.default_backend() != "cpu"
 
 

@@ -33,9 +33,10 @@ stack is hand-written assembly (`gfp_amd64.s:39-129`) — the reference's
 answer to the same problem (fuse the whole field stack below the
 dispatch boundary), re-expressed for a systolic/vector machine.
 
-Opt-in: GETHSHARDING_TPU_FINALEXP=mega routes `bn256_jax.pairing_is_one`
-through `finalexp_is_one`.
-Differential tests run the kernel in interpreter mode on CPU against the
+`bn256_jax.pairing_is_one` runs `finalexp_is_one`, and the projective
+`bn256_jax._bls_miller_opt` runs `miller_f`, on every platform but the
+CPU unless the caller passes `pallas=False` (`bn256_jax.pairing_in_pallas`:
+the mesh does). Differential tests run the kernel in interpreter mode on CPU against the
 XLA path (tests/test_pallas_finalexp.py), and `run_program_xla` executes
 the same instruction stream with the same helpers as plain XLA ops so
 program-logic bugs and Pallas-mechanics bugs isolate cleanly.
@@ -913,7 +914,8 @@ def miller_f(sig, hx, hy, pk, *, interpret: bool = False):
 # as a STATIC 8-level loop inside one kernel — each level's adds process
 # every surviving pair in full-tile ops, so the whole 135-slot committee
 # reduction is ONE launch per group instead of ~25 XLA dispatch levels.
-# With FINALEXP/MILLER/AGG all mega, the audit dispatch is 4 launches.
+# With GETHSHARDING_TPU_AGG=mega beside the Miller and final-exp
+# kernels, the audit dispatch is 4 launches.
 
 AGG_LANES = 64  # smaller lane block: level-0 conv temporaries dominate VMEM
 # The in-kernel tree is fully unrolled, so its VMEM stack, its Mosaic

@@ -65,8 +65,11 @@ def _tally(ok, counted, quorum: int, mesh: Optional[Mesh]) -> PeriodOutputs:
 
 
 def _step(inp: PeriodInputs, quorum: int, mesh: Optional[Mesh]):
+    # the platform's pairing kernels on one device; XLA's under
+    # shard_map, where a `pallas_call` fails at trace
     ok = bn.bls_verify_aggregate_batch(
-        inp.hx, inp.hy, inp.sx, inp.sy, inp.pkx, inp.pky, inp.has_header)
+        inp.hx, inp.hy, inp.sx, inp.sy, inp.pkx, inp.pky, inp.has_header,
+        pallas=None if mesh is None else False)
     return _tally(ok, jnp.where(ok, inp.vote_count, 0), quorum, mesh)
 
 
@@ -174,7 +177,8 @@ def _committee_step(inp: CommitteePeriodInputs, quorum: int,
                     mesh: Optional[Mesh]):
     ok = bn.bls_aggregate_verify_committee_batch(
         inp.hx, inp.hy, inp.sigx, inp.sigy, inp.sig_mask,
-        inp.pkx, inp.pky, inp.pk_mask, inp.has_header)
+        inp.pkx, inp.pky, inp.pk_mask, inp.has_header,
+        pallas=None if mesh is None else False)   # as in `_step`
     # the vote count IS the filled signature slots — the device holds the
     # ground truth, so a stale/forged host-side count cannot inflate the
     # quorum

@@ -141,10 +141,12 @@ def _step(inp: StressInputs, pool_addr, blockhash, period, sample_size,
         quorum_size=quorum_size, sample_shard=shard_ids + base)
 
     # 3. committee BLS aggregation + verification (masked projective tree
-    # sum, then one shared-accumulator Miller product per local shard)
+    # sum, then one shared-accumulator Miller product per local shard;
+    # XLA's pairing under shard_map, where a `pallas_call` fails at trace)
     agg_ok = bn.bls_aggregate_verify_committee_batch(
         inp.hx, inp.hy, inp.sigx, inp.sigy, inp.sig_mask,
-        inp.pkx, inp.pky, inp.pk_mask, inp.agg_valid)
+        inp.pkx, inp.pky, inp.pk_mask, inp.agg_valid,
+        pallas=None if axis is None else False)
 
     # 4. collation replay (batched recovery + ordered transitions)
     tflat = lambda x: x.reshape((s_local * t,) + x.shape[2:])

@@ -146,6 +146,11 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         # the multiproof check with both MSMs on the device, and the
         # builder of the SRS's fixed-base tables it reads (`_srs_tables`)
         self._das_poly = jax.jit(bn256_jax.das_poly_verify_batch)
+        # the same program partitioned over a mesh keeps the XLA
+        # pairing, like the mesh's committee steps
+        self._das_poly_mesh = jax.jit(
+            lambda *planes: bn256_jax.das_poly_verify_batch(
+                *planes, pallas=False))
         self._das_poly_tables = jax.jit(bn256_jax.das_poly_tables)
         # the backend is a process-wide singleton shared by every actor
         # thread (get_backend caches instances): all cache structures
@@ -157,6 +162,10 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         # the multiproof rows whose two MSMs the device summed (a
         # malformed row is decided on the host)
         self._m_msm_rows = metrics.counter("das/poly/device_msm_rows")
+        # the real rows of a dispatch whose program runs its pairing
+        # check in the Pallas kernels (bn256_jax.pairing_in_pallas): 0
+        # on the CPU and on a mesh
+        self._m_pallas_rows = metrics.counter("sig/pairing/pallas_rows")
         self._m_wire_bytes = metrics.counter("jax/wire/bytes")
         # the G2 part of it: pk planes shipped cold (0 on a warm audit)
         self._m_g2_bytes = metrics.counter("jax/wire/g2_bytes")
@@ -219,12 +228,14 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
                 # the ONE pjit'd audit step: each device verifies its
                 # slab of committees (astype is a no-op on the i32
                 # wire), then the vote total — the ONLY cross-device
-                # value — is psum'd. Everything else stays local.
+                # value — is psum'd. Everything else stays local. The
+                # pairing is XLA's here and in the twin below: a
+                # `pallas_call` inside `shard_map` fails at trace
                 i32 = jnp.int32
                 ok = bn256_jax.bls_aggregate_verify_committee_batch(
                     hx.astype(i32), hy.astype(i32), sx.astype(i32),
                     sy.astype(i32), sm, px.astype(i32),
-                    py.astype(i32), pm, hok)
+                    py.astype(i32), pm, hok, pallas=False)
                 votes = jax.lax.psum(jnp.sum(ok.astype(i32)), axis_names)
                 return ok, votes
 
@@ -241,7 +252,8 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
                 i32 = jnp.int32
                 ok = bn256_jax.bls_verify_committee_precomp_batch(
                     hx.astype(i32), hy.astype(i32), sx.astype(i32),
-                    sy.astype(i32), sm, tab, inf, hok, gen_lines=gen)
+                    sy.astype(i32), sm, tab, inf, hok, gen_lines=gen,
+                    pallas=False)
                 votes = jax.lax.psum(jnp.sum(ok.astype(i32)), axis_names)
                 return ok, votes
 
@@ -423,6 +435,8 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         with tracing.stage("sig/transfer_time", _T_TRANSFER):
             args = tuple(jnp.asarray(p)
                          for p in (hx, hy, sx, sy, pkx, pky, valid))
+        if self._bn.pairing_in_pallas():
+            self._m_pallas_rows.inc(n)
         dt.dispatched()  # marshal (incl. transfer staging) closes here
         launch = tracing.stage("sig/launch_time", _T_LAUNCH,
                                ctx=dt.span_ctx)
@@ -563,6 +577,8 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         RECORDER.record_wire("das_verify_multiproofs", self.last_wire)
         self._m_wire_bytes.inc(proof_bytes)
         self._m_msm_rows.inc(st["msm_rows"])
+        if not lay.is_mesh and self._bn.pairing_in_pallas():
+            self._m_pallas_rows.inc(n)
         tracing.tag_current_add(wire_bytes=proof_bytes,
                                 sample_wire_bytes=proof_bytes)
         dt.dispatched()  # marshal (incl. transfer staging) closes here
@@ -570,8 +586,9 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
                                ctx=dt.span_ctx)
         with self._compiles.compile_span("das_poly_verify", shape,
                                          fresh) as booking, launch:
-            out = self._run("das_poly_verify", shape, self._das_poly, args,
-                            booking)
+            out = self._run("das_poly_verify", shape,
+                            self._das_poly_mesh if lay.is_mesh
+                            else self._das_poly, args, booking)
         if lay.is_mesh:
             self.last_mesh = {
                 "op": "das_verify_multiproofs",
@@ -668,6 +685,8 @@ class JaxSigBackend(ResidentPkCache, SigBackend):
         self._m_wire_bytes.inc(wire["wire_bytes"])
         self._m_g2_bytes.inc(wire["g2_wire_bytes"])
         self._m_pk_hit_bytes.inc(wire["pk_hit_bytes"])
+        if self._bn.pairing_in_pallas():
+            self._m_pallas_rows.inc(n)
         # stamp the enclosing caller span (the notary's notary/audit);
         # SUMMED, so a multi-dispatch span reports total bytes
         tracing.tag_current_add(wire_bytes=wire["wire_bytes"],

@@ -117,9 +117,9 @@ def test_health_and_banner_carry_the_record():
 
 
 def test_requested_pallas_kernel_never_gets_the_xla_path(monkeypatch):
-    """The selector, not the chip: off the CPU a requested Pallas kernel
-    runs or raises — a failure to resolve the backend or to compile the
-    kernel propagates instead of selecting the XLA path."""
+    """The selector, not the chip: off the CPU the pairing check's
+    Pallas kernels run or raise — a failure to resolve the backend or to
+    compile the kernel propagates instead of selecting the XLA path."""
     import jax
     import jax.numpy as jnp
 
@@ -141,7 +141,7 @@ def test_requested_pallas_kernel_never_gets_the_xla_path(monkeypatch):
         raise NotImplementedError("Mosaic refused the kernel")
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(bn256_jax, "FINALEXP", "mega")
+    assert bn256_jax.pairing_in_pallas() is True
     monkeypatch.setattr(pallas_finalexp, "finalexp_is_one", refused)
     with pytest.raises(NotImplementedError, match="Mosaic refused"):
         bn256_jax.pairing_is_one(

@@ -550,6 +550,13 @@ def test_bulk_hedge_gated_on_slo_budget(monkeypatch):
     """Keyed bulk_audit planes hedge only while the class's SLO budget
     says the duplicate is free; a starved budget holds the hedge (and
     counts the hold). Default (0) keeps bulk hedging off entirely."""
+    import importlib
+
+    # a fresh process tracker, restored afterwards: bulk budget that an
+    # earlier test of the same process burnt would hold the first hedge
+    tracker_mod = importlib.import_module("gethsharding_tpu.slo.tracker")
+    monkeypatch.setattr(tracker_mod, "TRACKER", tracker_mod.SLOTracker(
+        registry=metrics.Registry()))
     registry = _registry()
     replica = Replica("r0", PythonSigBackend(), probe=None,
                       registry=registry)

@@ -1,7 +1,7 @@
-"""The mega switches with the 22-limb form, combined.
+"""The 22-limb form, end to end.
 
 Runs the committee-verify kernel end to end (good + tampered rows) in a
-subprocess with the switches set — they are read at import, so a fresh
+subprocess with the form set — it is read at import, so a fresh
 interpreter is the only honest way to exercise the configuration as
 `chip_smoke.py` leg C deploys it."""
 
@@ -39,11 +39,9 @@ assert [bool(v) for v in np.asarray(out)] == [True, False], out
 print("combo-ok")
 """
 
-# mega finalexp on the CPU exercises the switch's wiring and the XLA
-# path under the 22-limb form (the kernels themselves are
-# interpret-tested in test_pallas_finalexp)
-MEGA = {"GETHSHARDING_TPU_LIMB_FORM": "exact",
-        "GETHSHARDING_TPU_FINALEXP": "mega"}
+# the CPU runs the XLA pairing under the 22-limb form (the Pallas
+# kernels a chip chooses are interpret-tested in test_pallas_finalexp)
+MEGA = {"GETHSHARDING_TPU_LIMB_FORM": "exact"}
 
 
 @slow

@@ -228,17 +228,17 @@ def test_mul_lowers_without_product_sized_reshape():
 # == one kernel path ========================================================
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-# the import-time switches that outlived PR 30: the Pallas mega kernels
-# and the 22-limb form they were measured under (PERF.md section 6)
+# the import-time switches left: the 22-limb form and the Pallas
+# aggregation (PERF.md section 6); the platform chooses the pairing's
+# kernels (`bn256_jax.pairing_in_pallas`)
 _SWITCHES_LEFT = {
     "gethsharding_tpu/ops/limb.py": {"GETHSHARDING_TPU_LIMB_FORM"},
-    "gethsharding_tpu/ops/bn256_jax.py": {
-        "GETHSHARDING_TPU_FINALEXP", "GETHSHARDING_TPU_MILLER",
-        "GETHSHARDING_TPU_AGG"},
+    "gethsharding_tpu/ops/bn256_jax.py": {"GETHSHARDING_TPU_AGG"},
 }
-# the seven that went, each with the code only it reached
+# the nine that went, each with the code only it reached, or the
+# choice the platform makes now
 _SWITCHES_GONE = ("CARRY", "PALLAS", "NORM", "CONV", "PAIRCONV",
-                  "PAIR_UNROLL", "SCAN_UNROLL")
+                  "PAIR_UNROLL", "SCAN_UNROLL", "FINALEXP", "MILLER")
 
 
 def _env_reads(tree: ast.AST) -> list:
@@ -260,9 +260,9 @@ def _env_reads(tree: ast.AST) -> list:
 
 
 def test_kernel_modules_read_no_other_switch():
-    """`ops/limb.py` and `ops/bn256_jax.py` read the four variables of
-    the mega path and nothing else, and a value the seven removed
-    switches would have refused at import changes nothing."""
+    """`ops/limb.py` and `ops/bn256_jax.py` read the two variables left
+    and nothing else, and a value the nine removed switches would have
+    refused at import changes nothing."""
     for rel, allowed in _SWITCHES_LEFT.items():
         with open(os.path.join(_REPO, rel)) as src:
             reads = _env_reads(ast.parse(src.read()))
